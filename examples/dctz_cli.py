@@ -29,6 +29,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import entropy, images, metrics
 
 
@@ -224,4 +225,5 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     sys.exit(main())

@@ -24,6 +24,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import images
 from repro.serve.admission import RejectedError, TenantTier
 from repro.serve.service import CodecService, ServiceConfig
@@ -123,4 +124,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -9,6 +9,7 @@ Tables 3-4 (PSNR) and the fused-kernel codec path.
 
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.core import codec, images, metrics
 from repro.kernels.fused_codec import fused_codec
 
@@ -45,4 +46,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -10,6 +10,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.configs import registry as R
 from repro.models import registry as M
 from repro.serve import engine, kv_compress
@@ -77,4 +78,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

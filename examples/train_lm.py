@@ -16,6 +16,7 @@ import tempfile
 
 import jax
 
+from repro import compile_cache
 from repro.configs import registry as R
 from repro.data.synth import DataConfig, make_batch_fn
 from repro.optim.adamw import AdamWConfig
@@ -79,4 +80,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

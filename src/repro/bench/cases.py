@@ -580,13 +580,13 @@ def packing_identity_violations(seed: int = 0, trials: int = 25) -> list:
             bad.append(f"{name}: staged reference bytes mismatch")
             continue
         if pb.pack_bits(fields, widths, backend="pallas",
-                        interpret=True) != want:
+                        interpret=None) != want:
             bad.append(f"{name}: Pallas kernel bytes mismatch")
 
     # whole-stream check: the routed packer must frame identical DCTZ
     # containers under every table policy
     c = codec.compress(images.lena_like(32, 32), QUALITY)
-    packer = pb.make_packer(backend="pallas", interpret=True)
+    packer = pb.make_packer(backend="pallas", interpret=None)
     for tables in ("auto", "embedded", "shared"):
         want = entropy.encode_qcoeffs(c.qcoeffs, QUALITY, "exact",
                                       (32, 32), tables=tables)
@@ -641,7 +641,7 @@ def unpack_identity_violations(seed: int = 0, trials: int = 25) -> list:
         ("staged_tiled", lambda p, n, d, a: uref.unpack_bits_ref(
             p, n, d, a, tile_bits=64)),
         ("pallas", lambda p, n, d, a: ub.unpack_bits(
-            p, n, d, a, backend="pallas", interpret=True)),
+            p, n, d, a, backend="pallas", interpret=None)),
     ]
     bad = []
     for name, dc, ac in cases:
@@ -668,7 +668,7 @@ def unpack_identity_violations(seed: int = 0, trials: int = 25) -> list:
     # whole-stream check: the routed unpacker must reproduce the
     # default decode of DCTZ containers under every table policy
     c = codec.compress(images.lena_like(32, 32), QUALITY)
-    unpacker = ub.make_unpacker(backend="pallas", interpret=True)
+    unpacker = ub.make_unpacker(backend="pallas", interpret=None)
     for tables in ("auto", "embedded", "shared"):
         stream = entropy.encode_qcoeffs(c.qcoeffs, QUALITY, "exact",
                                         (32, 32), tables=tables)
@@ -717,9 +717,9 @@ def symbolize_identity_violations(seed: int = 0, trials: int = 25) -> list:
     backends = [
         ("staged", lambda d, a: sref.symbolize_ref(d, a)),
         ("pallas", lambda d, a: sy.symbolize(d, a, backend="pallas",
-                                             interpret=True)),
+                                             interpret=None)),
     ]
-    preps = [(bname, sy.make_symbolizer(bname, interpret=True))
+    preps = [(bname, sy.make_symbolizer(bname, interpret=None))
              for bname in ("numpy", "pallas")]
     bad = []
     for name, dc, ac in cases:
@@ -820,7 +820,7 @@ def entropy_decode_points(sizes, warmup: int, iters: int) -> list:
                            ac_t, warmup=warmup, iters=iters)
         t_pallas = measure(
             lambda: ub.unpack_bits(payload, n_blocks, dc_t, ac_t,
-                                   backend="pallas", interpret=True),
+                                   backend="pallas", interpret=None),
             warmup=min(warmup, 1), iters=max(iters // 2, 2))
         records.append(BenchRecord(
             label=f"entropy_decode_{size}",
@@ -1535,7 +1535,7 @@ def framework_micro(ctx: RunContext) -> list:
     # --- gradient DCT compression roundtrip
     g = jax.random.normal(jax.random.key(0), (4 * 1024 * 1024,))
     fn = jax.jit(functools.partial(grad_dct.roundtrip, keep=16,
-                                   interpret=True))
+                                   interpret=None))
     t_g = measure(fn, g, warmup=1, iters=3)
     cg = grad_dct.encode(g, keep=16)
     mb = g.size * 4 / 1e6
@@ -1624,9 +1624,10 @@ def roofline_points(size: int, entropy_size: int, warmup: int,
     FLOP and byte counts from XLA cost analysis of the kernel's jnp
     reference at the same shape (analytic byte counts for the two
     bit-stream kernels, whose FLOP content is ~0), and achieved
-    GFLOP/s / GB/s against the documented per-chip peaks
-    (:data:`repro.launch.mesh.HW` — TPU v5e terms, so off-TPU fractions
-    read as a pipeline proof, not an efficiency claim).
+    GFLOP/s / GB/s against the documented peaks of the chip the run is
+    on (:func:`repro.launch.mesh.chip_peaks`, keyed by device kind; a
+    TPU without an entry raises).  Off the TPU there is no peak to
+    divide by, so the peak fractions and the bound are left out.
 
     Shared by the registry case and ``benchmarks/roofline.py``.
     """
@@ -1639,8 +1640,10 @@ def roofline_points(size: int, entropy_size: int, warmup: int,
     from repro.kernels.dct8x8 import ref as d_ref
     from repro.kernels.fused_codec import ops as f_ops
     from repro.kernels.fused_codec import ref as f_ref
-    from repro.launch.mesh import HW
+    from repro.launch import mesh
 
+    peaks = (mesh.chip_peaks() if jax.devices()[0].platform == "tpu"
+             else None)
     img = jnp.asarray(images.lena_like(size, size), jnp.float32)
     f32 = img.size * 4
 
@@ -1651,23 +1654,27 @@ def roofline_points(size: int, entropy_size: int, warmup: int,
         sec = t.median_us / 1e6
         achieved_flops = flops / sec
         achieved_bw = nbytes / sec
-        # Ridge point: intensity above flops_peak/bw_peak is compute-bound.
         intensity = flops / nbytes if nbytes else float("inf")
-        ridge = HW["peak_flops_bf16"] / HW["hbm_bw"]
+        metrics = {
+            "flops": flops,
+            "bytes_accessed": nbytes,
+            "achieved_gflop_s": achieved_flops / 1e9,
+            "achieved_gb_s": achieved_bw / 1e9,
+            "intensity_flop_per_byte": intensity,
+        }
+        if peaks is not None:
+            # Ridge point: intensity above flops_peak/bw_peak is
+            # compute-bound.
+            ridge = peaks["peak_flops_bf16"] / peaks["hbm_bw"]
+            metrics.update(
+                frac_peak_flops=achieved_flops / peaks["peak_flops_bf16"],
+                frac_peak_bw=achieved_bw / peaks["hbm_bw"],
+                compute_bound=float(intensity > ridge))
         points.append(BenchRecord(
             label=kernel,
             params={"kernel": kernel, **params},
             timings_us={"routed": t.to_json()},
-            metrics={
-                "flops": flops,
-                "bytes_accessed": nbytes,
-                "achieved_gflop_s": achieved_flops / 1e9,
-                "achieved_gb_s": achieved_bw / 1e9,
-                "frac_peak_flops": achieved_flops / HW["peak_flops_bf16"],
-                "frac_peak_bw": achieved_bw / HW["hbm_bw"],
-                "intensity_flop_per_byte": intensity,
-                "compute_bound": float(intensity > ridge),
-            }))
+            metrics=metrics))
 
     fl, by = kernel_cost_terms(d_ref.dct8x8_ref, img)
     add("dct8x8", lambda: d_ops.dct8x8(img), fl, by,

@@ -327,9 +327,9 @@ def _roofline_table(result) -> str:
              "artifact applies) against FLOP/byte counts from XLA's "
              "lowered cost analysis of the jnp reference at the same "
              "shape (analytic byte counts for the two bit-stream "
-             "kernels).  Peaks are the documented TPU v5e per-chip "
-             "terms (`repro.launch.mesh.HW`), so off-TPU the fractions "
-             "prove the pipeline, not efficiency.", "",
+             "kernels).  Peaks are the documented per-chip terms of "
+             "the device the run was on (`repro.launch.mesh.PEAKS`); "
+             "off the TPU the peak columns read \"not measured\".", "",
              "| kernel | shape | time (ms) | GFLOP/s | GB/s "
              "| % peak FLOPs | % peak BW | FLOP/byte | bound |",
              "|---|---|---|---|---|---|---|---|---|"]
@@ -339,14 +339,19 @@ def _roofline_table(result) -> str:
             shape = f"{r.params['height']}x{r.params['width']}"
         else:
             shape = f"{r.params['payload_bits']} bits"
-        bound = "compute" if m["compute_bound"] else "memory"
+        if "compute_bound" in m:
+            bound = "compute" if m["compute_bound"] else "memory"
+            fracs = (f"{m['frac_peak_flops'] * 100:.4f}% "
+                     f"| {m['frac_peak_bw'] * 100:.4f}%")
+        else:
+            bound = "not measured"
+            fracs = "not measured | not measured"
         lines.append(
             f"| {r.params['kernel']} | {shape} "
             f"| {_ms(r.timings_us['routed'])} "
             f"| {m['achieved_gflop_s']:.2f} "
             f"| {m['achieved_gb_s']:.2f} "
-            f"| {m['frac_peak_flops'] * 100:.4f}% "
-            f"| {m['frac_peak_bw'] * 100:.4f}% "
+            f"| {fracs} "
             f"| {m['intensity_flop_per_byte']:.2f} "
             f"| {bound} |")
     return "\n".join(lines)

@@ -12,16 +12,26 @@ but map differently onto TPU hardware (see DESIGN.md §2):
 
 * separable:  ``Y = C @ X @ C.T`` per 8x8 block (two small matmuls),
 * kron:       ``vec(Y) = (C ⊗ C) @ vec(X)`` — one (nblocks, 64) @ (64, 64)
-              matmul, which is the MXU-friendly form used by the Pallas
-              kernels.
+              matmul, the staged codec path's form.
+
+Every matmul pins ``Precision.HIGHEST``: on TPU the default f32 dot is a
+single bf16 pass, which would move quantised levels (and so bytes and
+PSNR) away from what the same code computes on the CPU.
+
+The Pallas kernels use :func:`dct8_terms` / :func:`idct8_terms`, the same
+8-point transform written over the 8 sample phases of a tile, so it runs
+on the vector unit with no relayout of the 8x8 blocks.
 """
 
 from __future__ import annotations
 
 import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 @functools.lru_cache(maxsize=None)
@@ -49,12 +59,40 @@ def kron_dct_matrix(n: int = 8, dtype=jnp.float32) -> jnp.ndarray:
     return jnp.asarray(np.kron(c, c), dtype=dtype)
 
 
+def dct8_terms(xs) -> list:
+    """Orthonormal 8-point DCT-II over a list of 8 equally-shaped arrays.
+
+    ``xs[j]`` holds sample ``j`` of every transform at once; output
+    ``k`` is ``sum_j C[k, j] * xs[j]``, summed in ``j`` order.
+    """
+    c = _dct_matrix_np(8).astype(np.float32)
+    out = []
+    for k in range(8):
+        acc = float(c[k, 0]) * xs[0]
+        for j in range(1, 8):
+            acc = acc + float(c[k, j]) * xs[j]
+        out.append(acc)
+    return out
+
+
+def idct8_terms(ys) -> list:
+    """Inverse of :func:`dct8_terms` (``C`` is orthonormal: ``C^T``)."""
+    c = _dct_matrix_np(8).astype(np.float32)
+    out = []
+    for j in range(8):
+        acc = float(c[0, j]) * ys[0]
+        for k in range(1, 8):
+            acc = acc + float(c[k, j]) * ys[k]
+        out.append(acc)
+    return out
+
+
 def dct1d(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
     """Orthonormal DCT-II along ``axis``."""
     n = x.shape[axis]
     c = dct_matrix(n, x.dtype)
     x = jnp.moveaxis(x, axis, -1)
-    y = x @ c.T
+    y = jnp.matmul(x, c.T, precision=_HIGHEST)
     return jnp.moveaxis(y, -1, axis)
 
 
@@ -63,7 +101,7 @@ def idct1d(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
     n = x.shape[axis]
     c = dct_matrix(n, x.dtype)
     x = jnp.moveaxis(x, axis, -1)
-    y = x @ c
+    y = jnp.matmul(x, c, precision=_HIGHEST)
     return jnp.moveaxis(y, -1, axis)
 
 
@@ -116,7 +154,7 @@ def blockwise_dct2d_kron(img: jnp.ndarray, block: int = 8) -> jnp.ndarray:
     blocks = to_blocks(img, block)
     *lead, hb, wb, b, _ = blocks.shape
     flat = blocks.reshape(*lead, hb, wb, b * b)
-    out = flat @ t.T
+    out = jnp.matmul(flat, t.T, precision=_HIGHEST)
     return out.reshape(*lead, hb, wb, b, b)
 
 
@@ -125,5 +163,5 @@ def blockwise_idct2d_kron(coeffs: jnp.ndarray) -> jnp.ndarray:
     *lead, hb, wb, b, _ = coeffs.shape
     t = kron_dct_matrix(b, coeffs.dtype)
     flat = coeffs.reshape(*lead, hb, wb, b * b)
-    out = flat @ t
+    out = jnp.matmul(flat, t, precision=_HIGHEST)
     return from_blocks(out.reshape(*lead, hb, wb, b, b))

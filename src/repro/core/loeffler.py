@@ -42,22 +42,18 @@ def exact_rotate(u: jnp.ndarray, v: jnp.ndarray, theta: float):
 RotateFn = Callable[[jnp.ndarray, jnp.ndarray, float], tuple]
 
 
-def loeffler_dct8(x: jnp.ndarray, axis: int = -1,
-                  rotate_fn: RotateFn = exact_rotate,
-                  quantize_fn=None) -> jnp.ndarray:
-    """Orthonormal 8-point DCT-II along ``axis`` via the Loeffler graph.
+def dct8_terms(xs, rotate_fn: RotateFn = exact_rotate,
+               quantize_fn=None) -> list:
+    """Loeffler 8-point DCT-II over a list of 8 equally-shaped arrays.
 
-    ``rotate_fn(u, v, theta)`` implements the plane rotation; pass
-    ``cordic.cordic_rotate`` to obtain the paper's Cordic-based variant.
-    ``quantize_fn`` (optional) is applied to every stage output, emulating
-    the fixed-point register grid of the short-word-length hardware the
-    Cordic-Loeffler design targets (see core.cordic.fixed_quantizer).
+    ``xs[i]`` holds input sample ``i`` of every transform at once; the
+    result lists output coefficient ``k`` the same way.  This is the
+    graph itself: :func:`loeffler_dct8` stacks it along an array axis,
+    and the Pallas kernels apply it to the 8 row (or column) phases of
+    a VMEM tile, so both run the same operations in the same order.
     """
     q = quantize_fn if quantize_fn is not None else (lambda t: t)
-    x = jnp.moveaxis(x, axis, 0)
-    if x.shape[0] != 8:
-        raise ValueError(f"loeffler_dct8 needs length-8 axis, got {x.shape}")
-    x0, x1, x2, x3, x4, x5, x6, x7 = [x[i] for i in range(8)]
+    x0, x1, x2, x3, x4, x5, x6, x7 = xs
 
     # ---- stage 1: butterflies ------------------------------------------
     a0 = q(x0 + x7)
@@ -101,15 +97,12 @@ def loeffler_dct8(x: jnp.ndarray, axis: int = -1,
     out[7] = q((c7 - c4) * _INV_2SQRT2)
     out[3] = q(c5 * 0.5)
     out[5] = q(c6 * 0.5)
-
-    y = jnp.stack(out, axis=0)
-    return jnp.moveaxis(y, 0, axis)
+    return out
 
 
-def loeffler_idct8(y: jnp.ndarray, axis: int = -1,
-                   rotate_fn: RotateFn = exact_rotate,
-                   quantize_fn=None) -> jnp.ndarray:
-    """Inverse (DCT-III) via the transposed flow graph.
+def idct8_terms(ys, rotate_fn: RotateFn = exact_rotate,
+                quantize_fn=None) -> list:
+    """Inverse (DCT-III) of :func:`dct8_terms` via the transposed graph.
 
     For the exact rotation the graph is orthonormal so the inverse is the
     exact transpose; we implement the transpose explicitly (stages reversed,
@@ -118,10 +111,7 @@ def loeffler_idct8(y: jnp.ndarray, axis: int = -1,
     the IDCT kernel is also CORDIC-based.
     """
     q = quantize_fn if quantize_fn is not None else (lambda t: t)
-    y = jnp.moveaxis(y, axis, 0)
-    if y.shape[0] != 8:
-        raise ValueError(f"loeffler_idct8 needs length-8 axis, got {y.shape}")
-    Y0, Y1, Y2, Y3, Y4, Y5, Y6, Y7 = [y[i] for i in range(8)]
+    Y0, Y1, Y2, Y3, Y4, Y5, Y6, Y7 = ys
 
     # transpose of stage 4
     y0 = q(Y0 * _INV_2SQRT2)
@@ -162,8 +152,37 @@ def loeffler_idct8(y: jnp.ndarray, axis: int = -1,
     x3 = q(a3 + d3)
     x4 = q(a3 - d3)
 
-    x = jnp.stack([x0, x1, x2, x3, x4, x5, x6, x7], axis=0)
-    return jnp.moveaxis(x, 0, axis)
+    return [x0, x1, x2, x3, x4, x5, x6, x7]
+
+
+def _along(fn, x: jnp.ndarray, axis: int, rotate_fn, quantize_fn):
+    x = jnp.moveaxis(x, axis, 0)
+    if x.shape[0] != 8:
+        raise ValueError(f"Loeffler graph needs a length-8 axis, got "
+                         f"{x.shape}")
+    out = fn([x[i] for i in range(8)], rotate_fn, quantize_fn)
+    return jnp.moveaxis(jnp.stack(out, axis=0), 0, axis)
+
+
+def loeffler_dct8(x: jnp.ndarray, axis: int = -1,
+                  rotate_fn: RotateFn = exact_rotate,
+                  quantize_fn=None) -> jnp.ndarray:
+    """Orthonormal 8-point DCT-II along ``axis`` via the Loeffler graph.
+
+    ``rotate_fn(u, v, theta)`` implements the plane rotation; pass
+    ``cordic.cordic_rotate`` to obtain the paper's Cordic-based variant.
+    ``quantize_fn`` (optional) is applied to every stage output, emulating
+    the fixed-point register grid of the short-word-length hardware the
+    Cordic-Loeffler design targets (see core.cordic.fixed_quantizer).
+    """
+    return _along(dct8_terms, x, axis, rotate_fn, quantize_fn)
+
+
+def loeffler_idct8(y: jnp.ndarray, axis: int = -1,
+                   rotate_fn: RotateFn = exact_rotate,
+                   quantize_fn=None) -> jnp.ndarray:
+    """Inverse (DCT-III) of :func:`loeffler_dct8` along ``axis``."""
+    return _along(idct8_terms, y, axis, rotate_fn, quantize_fn)
 
 
 def loeffler_dct2d_8x8(blocks: jnp.ndarray,

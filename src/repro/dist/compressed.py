@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import dct
-from repro.dist import compat
 from repro.optim.grad_compress import GradCompressConfig
 
 BLOCK = 64
@@ -95,9 +94,9 @@ def make_cross_axis_grad_sync(mesh, specs: dict, cfg: GradCompressConfig):
         return out_g, out_e
 
     spec_tree = {path: specs[path] for path in specs}
-    sm = compat.shard_map(body, mesh,
-                          in_specs=(spec_tree, spec_tree),
-                          out_specs=(spec_tree, spec_tree))
+    sm = jax.shard_map(body, mesh=mesh,
+                       in_specs=(spec_tree, spec_tree),
+                       out_specs=(spec_tree, spec_tree), check_vma=False)
 
     def sync(grads: dict, ef: dict):
         return sm(grads, ef)

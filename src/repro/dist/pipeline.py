@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import compat
 
 
 def split_stages(params: dict, n_stages: int) -> dict:
@@ -63,9 +62,9 @@ def gpipe(block_fn, *, n_stages: int, n_micro: int, mesh,
 
     def run(stage_params, x_micro):
         in_param_specs = jax.tree.map(lambda _: P(stage_axis), stage_params)
-        sm = compat.shard_map(body, mesh,
-                              in_specs=(in_param_specs, P()),
-                              out_specs=P())
+        sm = jax.shard_map(body, mesh=mesh,
+                           in_specs=(in_param_specs, P()),
+                           out_specs=P(), check_vma=False)
         return sm(stage_params, x_micro)
 
     return run

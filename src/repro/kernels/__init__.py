@@ -3,7 +3,7 @@
 Each subpackage follows the kernel.py (pl.pallas_call + BlockSpec) /
 ops.py (jit wrapper) / ref.py (pure-jnp oracle) layout:
 
-  dct8x8          blockwise 2-D DCT/IDCT via the MXU Kronecker matmul
+  dct8x8          blockwise 2-D DCT/IDCT (separable, over row phases)
   cordic_loeffler paper-faithful Cordic-based Loeffler DCT (VPU shift-add)
   fused_codec     DCT->quant->dequant->IDCT in one HBM round-trip
   grad_dct        DCT-domain gradient compression (encode/decode)

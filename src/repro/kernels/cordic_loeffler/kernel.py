@@ -3,13 +3,15 @@
 The kernel body runs the Loeffler flow graph (4 serial stages, parallel
 inside each stage — exactly the structure the paper describes) with CORDIC
 micro-rotations, vectorised across all blocks of the VMEM tile: the
-"parallel inside a stage" dimension maps to VPU lanes, and every shift-add
-micro-rotation is a fused multiply-add by a power-of-two constant.
+"parallel inside a stage" dimension maps to VPU lanes (the graph runs on
+the tile's 8 row phases, :func:`repro.kernels.common.blockwise_2d`), and
+every shift-add micro-rotation is a multiply-add by a power-of-two
+constant.
 
 This is the TPU-native rendering of the paper's CUDA kernel.  It is kept as
-the paper-faithful *baseline*; the MXU Kronecker-matmul kernel (dct8x8 /
-fused_codec) is the beyond-paper optimised path — see DESIGN.md §2 for why
-the CORDIC trade inverts on TPU.
+the paper-faithful *baseline*; the exact-transform kernels (dct8x8 /
+fused_codec) are the beyond-paper path — see DESIGN.md §2 for why the
+CORDIC trade inverts on TPU.
 """
 
 from __future__ import annotations
@@ -21,24 +23,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core import cordic, loeffler
+from repro.kernels import common
 
 
 def _make_kernel(config: cordic.CordicConfig, inverse: bool):
     rot = cordic.make_cordic_rotate(config)
     qfn = cordic.fixed_quantizer(config)
 
+    def fwd(xs):
+        return loeffler.dct8_terms(xs, rot, qfn)
+
+    def inv(ys):
+        return loeffler.idct8_terms(ys, rot, qfn)
+
     def kernel(x_ref, o_ref):
         x = x_ref[...]
-        th, tw = x.shape
-        blocks = x.reshape(th // 8, 8, tw // 8, 8)
-        blocks = blocks.transpose(0, 2, 1, 3)  # (nbh, nbw, 8, 8)
         if inverse:
-            out = loeffler.loeffler_idct2d_8x8(blocks, rotate_fn=rot,
-                                               quantize_fn=qfn)
+            out = common.blockwise_2d(x, inv, vertical_first=True)
         else:
-            out = loeffler.loeffler_dct2d_8x8(blocks, rotate_fn=rot,
-                                              quantize_fn=qfn)
-        o_ref[...] = out.transpose(0, 2, 1, 3).reshape(th, tw)
+            out = common.blockwise_2d(x, fwd)
+        o_ref[...] = out
 
     return kernel
 

@@ -19,8 +19,7 @@ def _run(img: jnp.ndarray, config: cordic.CordicConfig, inverse: bool,
     ph, pw = padded.shape[-2:]
     if tile is None:
         tile = tuning.tile_for("cordic_loeffler", max(ph, pw))
-    th = common.pick_tile(ph, tile)
-    tw = common.pick_tile(pw, tile)
+    th, tw = common.tile_shape(ph, pw, tile)
 
     fn = lambda x: kernel.cordic_loeffler_pallas(
         x, tile_h=th, tile_w=tw, config=config, inverse=inverse,

@@ -1,15 +1,20 @@
-"""Pallas TPU kernel: blockwise 8x8 2-D DCT / IDCT via the Kronecker matmul.
+"""Pallas TPU kernel: blockwise 8x8 2-D DCT / IDCT.
 
 TPU adaptation of the paper's CUDA DCT kernel (DESIGN.md §2).  The CUDA
 version assigns one thread block per 8x8 pixel block with shared-memory
 staging; here each *grid cell* owns a (TH, TW) VMEM tile holding
-(TH/8)·(TW/8) pixel blocks, and the whole tile's transform is a single
-(nblocks, 64) @ (64, 64) matmul against the Kronecker operator
-T = kron(C8, C8) — an MXU-shaped contraction instead of 8-wide butterflies.
+(TH/8)·(TW/8) pixel blocks, and transforms all of them at once with the
+separable 8-point DCT written over the tile's 8 row phases
+(:func:`repro.kernels.common.blockwise_2d`): every output coefficient
+is an 8-term multiply-add chain on the vector unit, so a block's result
+is the same in every tile.
 
-VMEM budget at the default 256x256 f32 tile: 256 KiB in + 256 KiB out +
-16 KiB operator ≈ 0.5 MiB, comfortably inside the ~16 MiB/core VMEM of
-TPU v5e, leaving room for double buffering.
+VMEM at the default 256x256 f32 tile: 256 KiB in + 256 KiB out, each
+double-buffered, plus the per-phase temporaries of the transform; the
+compile rehearsal in ``tests/test_tpu_compile.py`` checks the whole
+kernel fits a v5e core.  Compiled for a v5e at 512x512,
+``memory_analysis()`` gives 1 MiB of argument, 1 MiB of output and no
+HBM temporaries.
 
 Layout: both input and output use the *in-place block-planar* convention —
 the coefficient block of image block (i, j) lives at pixels
@@ -25,56 +30,39 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _tile_to_rows(x: jnp.ndarray) -> jnp.ndarray:
-    """(TH, TW) tile -> (nblocks, 64) rows of vec(8x8 block)."""
-    th, tw = x.shape
-    b = x.reshape(th // 8, 8, tw // 8, 8)
-    return b.transpose(0, 2, 1, 3).reshape(-1, 64)
+from repro.core import dct
+from repro.kernels import common
 
 
-def _rows_to_tile(rows: jnp.ndarray, th: int, tw: int) -> jnp.ndarray:
-    """(nblocks, 64) -> (TH, TW) tile (inverse of _tile_to_rows)."""
-    b = rows.reshape(th // 8, tw // 8, 8, 8)
-    return b.transpose(0, 2, 1, 3).reshape(th, tw)
+def _make_kernel(inverse: bool):
+    def kernel(x_ref, o_ref):
+        x = x_ref[...].astype(jnp.float32)
+        if inverse:
+            out = common.blockwise_2d(x, dct.idct8_terms,
+                                      vertical_first=True)
+        else:
+            out = common.blockwise_2d(x, dct.dct8_terms)
+        o_ref[...] = out.astype(o_ref.dtype)
 
-
-def _dct_kernel(x_ref, t_ref, o_ref):
-    x = x_ref[...]
-    t = t_ref[...]
-    th, tw = x.shape
-    rows = _tile_to_rows(x)
-    o_ref[...] = _rows_to_tile(rows @ t.T, th, tw)
-
-
-def _idct_kernel(y_ref, t_ref, o_ref):
-    y = y_ref[...]
-    t = t_ref[...]
-    th, tw = y.shape
-    rows = _tile_to_rows(y)
-    # T is orthonormal: inverse = T^T, i.e. rows @ T
-    o_ref[...] = _rows_to_tile(rows @ t, th, tw)
+    return kernel
 
 
 @functools.partial(jax.jit, static_argnames=("tile_h", "tile_w", "inverse",
                                              "interpret"))
-def dct8x8_pallas(img: jnp.ndarray, t: jnp.ndarray, *, tile_h: int,
-                  tile_w: int, inverse: bool = False,
+def dct8x8_pallas(img: jnp.ndarray, *, tile_h: int, tile_w: int,
+                  inverse: bool = False,
                   interpret: bool = True) -> jnp.ndarray:
     """Blockwise 2-D (I)DCT of a (H, W) image, block-planar layout.
 
-    H % tile_h == 0, W % tile_w == 0, tiles multiples of 8 (ops.py enforces).
+    H % tile_h == 0 and W % tile_w == 0; tile_h a multiple of 8, tile_w
+    a multiple of 128 or W (ops.py enforces via ``common.tile_shape``).
     """
     h, w = img.shape
-    kernel = _idct_kernel if inverse else _dct_kernel
     return pl.pallas_call(
-        kernel,
+        _make_kernel(inverse),
         out_shape=jax.ShapeDtypeStruct((h, w), img.dtype),
         grid=(h // tile_h, w // tile_w),
-        in_specs=[
-            pl.BlockSpec((tile_h, tile_w), lambda i, j: (i, j)),
-            pl.BlockSpec((64, 64), lambda i, j: (0, 0)),
-        ],
+        in_specs=[pl.BlockSpec((tile_h, tile_w), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((tile_h, tile_w), lambda i, j: (i, j)),
         interpret=interpret,
-    )(img, t)
+    )(img)

@@ -10,7 +10,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core import dct
 from repro.kernels import common, tuning
 from repro.kernels.dct8x8 import kernel
 
@@ -24,11 +23,9 @@ def _run(img: jnp.ndarray, inverse: bool, tile: int | None,
     ph, pw = padded.shape[-2:]
     if tile is None:
         tile = tuning.tile_for("dct8x8", max(ph, pw))
-    th = common.pick_tile(ph, tile)
-    tw = common.pick_tile(pw, tile)
-    t = dct.kron_dct_matrix(8, padded.dtype)
+    th, tw = common.tile_shape(ph, pw, tile)
 
-    fn = lambda x: kernel.dct8x8_pallas(x, t, tile_h=th, tile_w=tw,
+    fn = lambda x: kernel.dct8x8_pallas(x, tile_h=th, tile_w=tw,
                                         inverse=inverse, interpret=interpret)
     for _ in range(img.ndim - 2):
         fn = jax.vmap(fn)

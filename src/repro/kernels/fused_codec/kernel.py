@@ -7,6 +7,13 @@ whole codec into one kernel cuts HBM traffic ~3x: the tile is read once,
 transformed, quantised, reconstructed in VMEM, and written once (plus the
 quantised coefficients as a second output for entropy coding / telemetry).
 
+Both transforms run on the tile's 8 row phases
+(:func:`repro.kernels.common.blockwise_2d`): "exact" is the separable
+8-point DCT of :func:`repro.core.dct.dct8_terms`, "cordic" the
+Loeffler graph with CORDIC rotations of
+:func:`repro.core.loeffler.dct8_terms` — the very operations the staged
+codec runs, so "cordic" levels match it exactly.
+
 This is the main beyond-paper kernel-level optimisation (DESIGN.md §2);
 benchmarks/bench_table1 reports unfused vs fused.
 """
@@ -19,74 +26,59 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core import cordic, loeffler
-from repro.kernels.dct8x8.kernel import _rows_to_tile, _tile_to_rows
+from repro.core import cordic, dct, loeffler
+from repro.kernels import common
+
+
+def _transforms(transform: str, config: cordic.CordicConfig):
+    """(forward, inverse) 8-point transforms over lists of phases."""
+    if transform == "exact":
+        return dct.dct8_terms, dct.idct8_terms
+    if transform == "cordic":
+        rot = cordic.make_cordic_rotate(config)
+        qfn = cordic.fixed_quantizer(config)
+        return (lambda xs: loeffler.dct8_terms(xs, rot, qfn),
+                lambda ys: loeffler.idct8_terms(ys, rot, qfn))
+    raise ValueError(f"unknown transform {transform!r}")
 
 
 def _make_kernel(transform: str, config: cordic.CordicConfig):
-    """transform: 'exact' (MXU Kronecker matmul) or 'cordic' (flow graph)."""
+    """transform: 'exact' (separable DCT) or 'cordic' (flow graph)."""
+    fwd, inv = _transforms(transform, config)
 
-    def kernel(x_ref, t_ref, q_ref, rec_ref, qc_ref):
+    def kernel(x_ref, q_ref, rec_ref, qc_ref):
         x = x_ref[...].astype(jnp.float32) - 128.0  # JPEG level shift
-        t = t_ref[...]
-        qvec = q_ref[...]          # (1, 64) quant steps, row-major block order
-        th, tw = x.shape
-
-        if transform == "exact":
-            rows = _tile_to_rows(x)              # (nb, 64)
-            coef = rows @ t.T                    # MXU contraction
-            qc = jnp.round(coef / qvec)          # quantise
-            deq = qc * qvec                      # dequantise
-            rec = _rows_to_tile(deq @ t, th, tw)  # inverse (T orthonormal)
-        elif transform == "cordic":
-            rot = cordic.make_cordic_rotate(config)
-            qfn = cordic.fixed_quantizer(config)
-            blocks = x.reshape(th // 8, 8, tw // 8, 8).transpose(0, 2, 1, 3)
-            coef = loeffler.loeffler_dct2d_8x8(blocks, rotate_fn=rot,
-                                               quantize_fn=qfn)
-            qtab = qvec.reshape(8, 8)
-            qc4 = jnp.round(coef / qtab)
-            deq = qc4 * qtab
-            rec4 = loeffler.loeffler_idct2d_8x8(deq, rotate_fn=rot,
-                                                quantize_fn=qfn)
-            rec = rec4.transpose(0, 2, 1, 3).reshape(th, tw)
-            qc = qc4.transpose(0, 2, 1, 3).reshape(th, tw)
-        else:
-            raise ValueError(f"unknown transform {transform!r}")
-
+        q = q_ref[...]             # quant step of every tile position
+        coef = common.blockwise_2d(x, fwd)
+        qc = jnp.round(coef / q)                     # quantise
+        rec = common.blockwise_2d(qc * q, inv, vertical_first=True)
         rec_ref[...] = jnp.clip(jnp.round(rec + 128.0), 0.0, 255.0)
-        if transform == "exact":
-            qc_ref[...] = _rows_to_tile(qc, th, tw).astype(jnp.int32)
-        else:
-            qc_ref[...] = qc.astype(jnp.int32)
+        qc_ref[...] = qc.astype(jnp.int32)
 
     return kernel
 
 
 @functools.partial(jax.jit, static_argnames=("tile_h", "tile_w", "transform",
                                              "config", "interpret"))
-def fused_codec_pallas(img: jnp.ndarray, t: jnp.ndarray, qvec: jnp.ndarray, *,
+def fused_codec_pallas(img: jnp.ndarray, qtile: jnp.ndarray, *,
                        tile_h: int, tile_w: int, transform: str = "exact",
                        config: cordic.CordicConfig = cordic.PAPER_CONFIG,
                        interpret: bool = True):
     """One-pass codec roundtrip of a (H, W) image.
 
-    Returns (reconstructed f32 in [0,255], quantised coeffs int32
-    block-planar).
+    ``qtile`` is the (tile_h, tile_w) f32 quant-step table tiled over
+    the tile's 8x8 blocks.  Returns (reconstructed f32 in [0,255],
+    quantised coeffs int32 block-planar).
     """
     h, w = img.shape
+    tile = pl.BlockSpec((tile_h, tile_w), lambda i, j: (i, j))
     rec, qc = pl.pallas_call(
         _make_kernel(transform, config),
         out_shape=(jax.ShapeDtypeStruct((h, w), jnp.float32),
                    jax.ShapeDtypeStruct((h, w), jnp.int32)),
         grid=(h // tile_h, w // tile_w),
-        in_specs=[
-            pl.BlockSpec((tile_h, tile_w), lambda i, j: (i, j)),
-            pl.BlockSpec((64, 64), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, 64), lambda i, j: (0, 0)),
-        ],
-        out_specs=(pl.BlockSpec((tile_h, tile_w), lambda i, j: (i, j)),
-                   pl.BlockSpec((tile_h, tile_w), lambda i, j: (i, j))),
+        in_specs=[tile, pl.BlockSpec((tile_h, tile_w), lambda i, j: (0, 0))],
+        out_specs=(tile, tile),
         interpret=interpret,
-    )(img, t, qvec)
+    )(img, qtile)
     return rec, qc

@@ -5,7 +5,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core import cordic, dct, quant
+from repro.core import cordic, quant
 from repro.kernels import common, tuning
 from repro.kernels.fused_codec import kernel
 
@@ -28,13 +28,11 @@ def fused_codec(img: jnp.ndarray, *, quality: int = 50,
     ph, pw = padded.shape[-2:]
     if tile is None:
         tile = tuning.tile_for("fused_codec", max(ph, pw))
-    th = common.pick_tile(ph, tile)
-    tw = common.pick_tile(pw, tile)
-    t = dct.kron_dct_matrix(8)
-    qvec = quant.qtable(quality).reshape(1, 64)
+    th, tw = common.tile_shape(ph, pw, tile)
+    qtile = jnp.tile(quant.qtable(quality), (th // 8, tw // 8))
 
     fn = lambda x: kernel.fused_codec_pallas(
-        x, t, qvec, tile_h=th, tile_w=tw, transform=transform, config=config,
+        x, qtile, tile_h=th, tile_w=tw, transform=transform, config=config,
         interpret=interpret)
     for _ in range(img.ndim - 2):
         fn = jax.vmap(fn)

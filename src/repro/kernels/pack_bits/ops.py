@@ -13,22 +13,26 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import tuning
 from repro.kernels.pack_bits import kernel, ref
 
 TILE_BITS = 1024                    # default output bits per kernel program
-WINDOW = TILE_BITS + 16             # fields gathered per tile (>= T+15)
-WINDOW_MARGIN = 16                  # window = tile_bits + this margin
+WINDOW_MARGIN = 128                 # window = tile_bits + this margin
+WINDOW = TILE_BITS + WINDOW_MARGIN  # fields per window block (>= T+16)
 
-# Above this many kept fields the stream falls back to the NumPy
-# reference: the kernel holds the three (m_pad, 1) int32 field arrays
-# unblocked in VMEM, and pow2 padding doubles the worst case, so the
-# cap must keep 3 * 4 B * 2 * MAX_DEVICE_FIELDS comfortably under the
-# ~16 MiB of a TPU core (2**18 fields -> at most 6 MiB of inputs).
-# 2**18 16-bit fields is a ~512 KB payload, beyond typical per-image
-# streams; blocking the field arrays would lift the cap if ever needed.
+# Above this many kept fields the stream packs with the NumPy
+# reference.  VMEM does not bound it: the kernel streams two
+# (3, window) int32 blocks per tile (a few tens of KiB at any M), and
+# the field stack lives in HBM, 12 B per field after pow2 bucketing of
+# the block count: compiled for a TPU v5e at this cap (294,912 padded
+# fields at the default tile), ``memory_analysis()`` gives 4,734,976 B
+# of arguments, 2,097,152 B of output and no temporaries.  The cap is
+# the device route's tested range, which also caps the symbolize chain
+# (``symbolize.MAX_DEVICE_BLOCKS``); raising it is ROADMAP A5.  2**18
+# 16-bit fields is a ~512 KB payload.
 MAX_DEVICE_FIELDS = 1 << 18
 
 BACKENDS = ("pallas", "numpy")
@@ -59,10 +63,10 @@ def pack_bits(codes, lengths, *, backend: str = "auto",
         lengths: (M,) field widths in [0, 16].
         backend: "auto" (Pallas on TPU, NumPy elsewhere), "pallas", or
             "numpy".
-        tile_bits: output bits per kernel program (pow2, byte multiple);
+        tile_bits: output bits per kernel program (pow2, >= 64);
             ``None`` routes through the tuned-tile artifact
             (:func:`repro.kernels.tuning.tile_for`, falling back to
-            :data:`TILE_BITS`).  Ignored by "numpy".  The gather window
+            :data:`TILE_BITS`).  Ignored by "numpy".  The field window
             is always ``tile_bits + WINDOW_MARGIN``.
         interpret: Pallas interpret-mode override (None = interpret
             exactly when no TPU is present); ignored by "numpy".
@@ -98,14 +102,55 @@ def _pow2(n: int) -> int:
     return p
 
 
+@functools.partial(jax.jit, static_argnames=("n_tiles", "tile_bits",
+                                             "window"))
+def _window_blocks(ends, *, n_tiles: int, tile_bits: int, window: int):
+    """Per tile, the window block holding its first overlapping field."""
+    starts = jnp.arange(n_tiles, dtype=jnp.int32) * tile_bits
+    first = jnp.searchsorted(ends, starts, side="right")
+    last = ends.shape[0] // window - 2
+    return jnp.minimum(first // window, last).astype(jnp.int32)
+
+
+def field_blocks(m: int, tile_bits: int) -> int:
+    """Padded field count for ``m`` kept fields: a pow2 number (>= 2)
+    of ``tile_bits + WINDOW_MARGIN``-field blocks, so a streaming
+    workload compiles a bounded set of shapes."""
+    window = tile_bits + WINDOW_MARGIN
+    return _pow2(-(-m // window) + 1) * window
+
+
+def pack_fields_device(fields, total: int, tile_bits: int,
+                       interpret: bool) -> bytes:
+    """Device scatter-pack of a prepared ``(3, M)`` field stack.
+
+    ``fields`` rows are codes, widths and starts (kept fields first in
+    stream order, zero-width padding after them starting at ``total``),
+    ``M = field_blocks(...)``.  Shared by :func:`pack_bits` and the
+    symbolize chain, which builds the stack on device.
+    """
+    window = tile_bits + WINDOW_MARGIN
+    n_tiles = _pow2(-(-total // tile_bits))
+    blocks = _window_blocks(fields[1] + fields[2], n_tiles=n_tiles,
+                            tile_bits=tile_bits, window=window)
+    out = kernel.pack_bits_pallas(fields, blocks, tile_bits=tile_bits,
+                                  window=window, interpret=interpret)
+    nbytes = (total + 7) // 8
+    by = np.asarray(out).reshape(-1)[:nbytes].astype(np.uint8)
+    pad = (-total) % 8
+    if pad:                         # writer convention: 1-padded tail
+        by[-1] |= (1 << pad) - 1
+    return by.tobytes()
+
+
 def _pack_bits_device(codes, lengths, interpret: bool | None,
                       tile_bits: int | None = None) -> bytes:
     """Host orchestration of the device scatter-pack.
 
-    Stages 1–2 (filter + prefix-sum offsets, plus the per-tile
-    ``searchsorted`` window starts) are O(M) NumPy; stage 3 runs on the
-    device.  Field count and tile count are bucketed to powers of two
-    so a streaming workload sees a bounded set of compiled shapes.
+    Stages 1–2 (filter + prefix-sum offsets) are O(M) NumPy; the
+    per-tile window blocks and stage 3 run on the device.  Field and
+    tile counts are bucketed to powers of two so a streaming workload
+    sees a bounded set of compiled shapes.
     """
     from repro.kernels import common
     if interpret is None:
@@ -118,24 +163,8 @@ def _pack_bits_device(codes, lengths, interpret: bool | None,
         return ref.scatter_pack_ref(c, ln, s, total).tobytes()
     if tile_bits is None:
         tile_bits = tuning.tile_for("pack_bits", total)
-    window = tile_bits + WINDOW_MARGIN
-    n_tiles = _pow2(-(-total // tile_bits))
-    m_pad = _pow2(m + window)
-    first = np.searchsorted(s + ln, np.arange(n_tiles, dtype=np.int64)
-                            * tile_bits, side="right")
-    first = np.minimum(first, m_pad - window).astype(np.int32)
-
-    def col(arr):
-        out = np.zeros((m_pad, 1), np.int32)
-        out[:m, 0] = arr
-        return out
-
-    out = kernel.pack_bits_pallas(col(c), col(ln), col(s), first,
-                                  tile_bits=tile_bits, window=window,
-                                  interpret=interpret)
-    nbytes = (total + 7) // 8
-    by = np.asarray(out).astype(np.uint8).reshape(-1)[:nbytes].copy()
-    pad = (-total) % 8
-    if pad:                         # writer convention: 1-padded tail
-        by[-1] |= (1 << pad) - 1
-    return by.tobytes()
+    fields = np.zeros((3, field_blocks(m, tile_bits)), np.int32)
+    fields[0, :m], fields[1, :m], fields[2, :m] = c, ln, s
+    fields[2, m:] = total
+    return pack_fields_device(jnp.asarray(fields), total, tile_bits,
+                              interpret)

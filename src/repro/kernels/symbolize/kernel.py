@@ -128,9 +128,9 @@ def _make_kernel(tile_blocks: int):
         dc_sym_h = jnp.where(valid_row, dc_cat, -1)        # (t, 1)
         dc_step = (dc_sym_h == bins).astype(jnp.int32).sum(
             axis=0, keepdims=True)                         # (1, 256)
-        ac_sym_h = jnp.where(nz, coef_sym, -1).reshape(-1, 1)
-        ac_step = (ac_sym_h == bins).astype(jnp.int32).sum(
-            axis=0, keepdims=True)
+        ac_sym_h = jnp.where(nz, coef_sym, -1)[:, :, None]  # (t, 63, 1)
+        ac_step = (ac_sym_h == bins[None]).astype(jnp.int32).sum(
+            axis=1).sum(axis=0, keepdims=True)
         zrl_sum = jnp.where(nz, zrl, 0).sum()
         eob_sum = eob.astype(jnp.int32).sum()
         ac_step = (ac_step

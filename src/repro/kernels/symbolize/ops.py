@@ -26,17 +26,17 @@ import numpy as np
 
 from repro.core.entropy import huffman, rle
 from repro.kernels import tuning
-from repro.kernels.pack_bits import kernel as pack_kernel
 from repro.kernels.pack_bits import ops as pack_ops
 from repro.kernels.symbolize import kernel, ref
 
 TILE_BLOCKS = 64                    # default blocks per kernel program
 
 # Above this many blocks the stream falls back to the staged NumPy
-# reference: the chained payload stage holds the three flattened
-# (2 * 64 * n_pad,) field arrays unblocked in VMEM like pack_bits does,
-# so the same MAX_DEVICE_FIELDS budget divided by the 128 fields a
-# block can emit caps the device-resident block count.
+# reference: the chained payload stage packs the (2 * 64 * n_pad,)
+# dense field slots through pack_bits, so its MAX_DEVICE_FIELDS cap
+# divided by the 128 fields a block can emit caps the device-resident
+# block count (2048 blocks: 256x256 images stay on device, 512x512 do
+# not — ROADMAP A5).
 MAX_DEVICE_BLOCKS = pack_ops.MAX_DEVICE_FIELDS // (2 * ref.SLOTS)
 
 # The kernel computes magnitude categories as 15 threshold compares in
@@ -203,12 +203,6 @@ def _fields_device(syms, amps, lens, total, dc_code, dc_len,
     return f2, w2, ends - w2, ends[-1], bad
 
 
-@jax.jit
-def _first_device(ends, n_tiles_arr, tile_bits, window):
-    first = jnp.searchsorted(ends, n_tiles_arr * tile_bits, side="right")
-    return jnp.minimum(first, ends.shape[0] - window).astype(jnp.int32)
-
-
 class _PallasPrepared:
     """Device-resident preparation: histograms now, device pack later.
 
@@ -244,30 +238,16 @@ class _PallasPrepared:
         if total == 0:
             return b""
         tile_bits = tuning.tile_for("pack_bits", total)
-        window = tile_bits + pack_ops.WINDOW_MARGIN
-        n_tiles = _pow2(-(-total // tile_bits))
         m = int(f.shape[0])
-        m_pad = _pow2(m + window)
-        if m_pad > m:
-            pad = m_pad - m
-            f = jnp.concatenate([f, jnp.zeros((pad,), f.dtype)])
-            w = jnp.concatenate([w, jnp.zeros((pad,), w.dtype)])
-            # padding starts sit at the payload end (zero width), so
-            # the `ends` array stays sorted for searchsorted
-            s = jnp.concatenate([s, jnp.broadcast_to(total_bits, (pad,))])
-        first = _first_device(s + w, jnp.arange(n_tiles, dtype=jnp.int32),
-                              tile_bits, window)
-        col = lambda a: a.reshape(-1, 1).astype(jnp.int32)
-        out = pack_kernel.pack_bits_pallas(
-            col(f), col(w), col(s), first, tile_bits=tile_bits,
-            window=window, interpret=self._interpret)
-        nbytes = (total + 7) // 8
-        by = np.asarray(jax.device_get(out)).astype(np.uint8)
-        by = by.reshape(-1)[:nbytes].copy()
-        pad_bits = (-total) % 8
-        if pad_bits:                # writer convention: 1-padded tail
-            by[-1] |= (1 << pad_bits) - 1
-        return by.tobytes()
+        pad = pack_ops.field_blocks(m, tile_bits) - m
+        # padding starts sit at the payload end (zero width), so the
+        # field ends stay sorted for the window search
+        fields = jnp.stack([
+            jnp.pad(f, (0, pad)), jnp.pad(w, (0, pad)),
+            jnp.concatenate([s, jnp.broadcast_to(total_bits, (pad,))])
+        ]).astype(jnp.int32)
+        return pack_ops.pack_fields_device(fields, total, tile_bits,
+                                           self._interpret)
 
 
 def make_symbolizer(backend: str = "auto", *,

@@ -1,4 +1,5 @@
-from repro.kernels.unpack_bits.kernel import unpack_bits_pallas  # noqa: F401
+from repro.kernels.unpack_bits.kernel import (stage_tiles,  # noqa: F401
+                                              unit_words_pallas)
 from repro.kernels.unpack_bits.ops import (BACKENDS,  # noqa: F401
                                            make_unpacker, scratch_nbytes,
                                            select_backend, unpack_bits)

@@ -22,12 +22,17 @@ from repro.kernels.unpack_bits import kernel, ref
 TILE_BITS = 2048                    # default bit offsets resolved per program
 WINDOW = TILE_BITS + ref.MARGIN_BITS
 
-# Above this many payload bits the stream falls back to the NumPy
-# reference: the kernel holds the (n_pad, 1) int32 window array
-# unblocked in VMEM and stages three (n_tiles, WINDOW) outputs, and
-# pow2 padding doubles the worst case, so 2**20 bits (~128 KB payload,
-# beyond typical per-image streams) keeps the resident arrays a few MB.
-# Blocking the window array would lift the cap if ever needed.
+# Above this many payload bits the stream decodes with the NumPy
+# reference.  VMEM does not bound it: the unit-word kernel streams
+# (16, 128) int32 blocks of bit windows (8 KiB in, 16 KiB out per
+# program) whatever the payload size.  The staged tile windows —
+# three (n_tiles, window) int32 arrays, pulled to the host for the
+# chain resolution — are what grows, up to 48 B per payload bit after
+# pow2 tile bucketing.  Compiled for a TPU v5e at 2**20 bits (a
+# ~128 KB payload; 1024 tiles of 4096 offsets), ``memory_analysis()``
+# gives the unit-word kernel 8,409,088 B in and 16,810,496 B out, and
+# the staging program 50,332,160 B of outputs plus 303,049,728 B of
+# HBM temporaries for the pointer-doubling levels.
 MAX_DEVICE_BITS = 1 << 20
 
 BACKENDS = ("pallas", "numpy")
@@ -89,9 +94,9 @@ def make_unpacker(backend: str = "auto", interpret: bool | None = None,
     Returns ``None`` when the resolved backend is "numpy" — callers
     then keep their zero-indirection default (the LUT walk inside
     :func:`repro.core.entropy.rle.decode_payload`) — and a routed
-    device-unpacking callable for "pallas".  The returned partial is
-    picklable, so ``decode_batch(executor="process")`` can ship it to
-    spawned workers (which then import jax on first use).
+    device-unpacking callable for "pallas".  ``decode_batch`` refuses
+    to ship a device unpacker to its process pool: a spawned worker
+    cannot open the chip its parent holds.
     """
     if select_backend(backend) == "numpy":
         return None
@@ -143,7 +148,8 @@ def _unpack_device(payload: bytes, n_blocks: int,
                    tile_bits: int | None = None) -> tuple:
     """Host orchestration of the device speculative decode.
 
-    The kernel stages unit/outcome words for every bit offset; chain
+    The kernel stages unit words for every bit offset and an XLA stage
+    cuts them into tile windows with their chain outcomes; chain
     resolution and value emission are the shared O(1)-per-block host
     stage (:func:`repro.kernels.unpack_bits.ref.resolve`).  Tile count
     is bucketed to powers of two so a streaming workload sees a
@@ -166,18 +172,19 @@ def _unpack_device(payload: bytes, n_blocks: int,
     window = tile_bits + ref.MARGIN_BITS
     win = bitio.bit_windows(payload)
     n_tiles = _pow2(-(-(nbits + 1) // tile_bits))
-    n_pad = n_tiles * tile_bits + window
-    win_col = np.full((n_pad, 1), 0xFFFF, np.int32)
-    win_col[:win.size, 0] = win
+    block = kernel.ROWS * kernel.LANES
+    n_pad = -(-(n_tiles * tile_bits + window) // block) * block
+    win_pad = np.full(n_pad, 0xFFFF, np.int32)
+    win_pad[:win.size] = win
     dc_params, dc_syms = table_params(dc_table)
     ac_params, ac_syms = table_params(ac_table)
-    dcw, acw, outc = kernel.unpack_bits_pallas(
-        np.array([nbits], np.int32),
-        np.concatenate([dc_params, ac_params]),
-        win_col, dc_syms.reshape(1, -1), ac_syms.reshape(1, -1),
-        n_tiles=n_tiles, tile_bits=tile_bits, window=window,
-        interpret=interpret)
-    dcw, acw, outc = (np.asarray(a) for a in (dcw, acw, outc))
+    params = np.concatenate([np.array([nbits], np.int32), dc_params,
+                             ac_params, dc_syms, ac_syms])
+    dcw, acw = kernel.unit_words_pallas(
+        params, win_pad.reshape(-1, kernel.LANES), interpret=interpret)
+    dcw, acw, outc = jax.device_get(kernel.stage_tiles(
+        dcw.reshape(-1), acw.reshape(-1), n_tiles=n_tiles,
+        tile_bits=tile_bits, window=window))
 
     def get_tile(t):
         return dcw[t], acw[t], outc[t]

@@ -12,12 +12,8 @@ import jax
 
 
 def _mk(shape: tuple, axes: tuple):
-    # jax < 0.5 has neither jax.sharding.AxisType nor the axis_types kwarg
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -38,10 +34,33 @@ def make_data_mesh(n_devices: int | None = None):
     return _mk((n,), ("data",))
 
 
-# TPU v5e hardware model used by the roofline analysis (benchmarks/roofline).
-HW = dict(
-    peak_flops_bf16=197e12,     # per chip
-    hbm_bw=819e9,               # bytes/s per chip
-    ici_bw=50e9,                # bytes/s per link (conservative single-link)
-    hbm_bytes=16e9,             # v5e HBM capacity
-)
+# Per-chip peaks used by the roofline analysis, keyed by
+# ``jax.Device.device_kind``.  Source: Google Cloud TPU documentation,
+# "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s per chip;
+# 1,600 Gbit/s inter-chip interconnect, of which ``ici_bw`` keeps one
+# conservative 50 GB/s link).
+PEAKS = {
+    "TPU v5 lite": dict(
+        peak_flops_bf16=197e12,
+        hbm_bw=819e9,
+        ici_bw=50e9,
+        hbm_bytes=16e9,
+    ),
+}
+
+
+def chip_peaks(device=None) -> dict:
+    """Peak terms of ``device`` (default: the first local device).
+
+    Raises:
+        KeyError: the device kind has no entry in :data:`PEAKS` — a
+            roofline against another chip's peaks would be wrong, so
+            there is no default.
+    """
+    if device is None:
+        device = jax.devices()[0]
+    kind = device.device_kind
+    if kind not in PEAKS:
+        raise KeyError(f"no peak terms for device kind {kind!r}; known: "
+                       f"{sorted(PEAKS)}")
+    return dict(PEAKS[kind])

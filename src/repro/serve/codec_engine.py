@@ -49,7 +49,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import codec, cordic, metrics
-from repro.dist import compat
 from repro.launch import mesh as mesh_lib
 
 SHAPE_BUCKET = 64      # ragged H/W round up to this (multiple of the block)
@@ -213,6 +212,13 @@ def _n_devices() -> int:
     return jax.local_device_count()
 
 
+def _shard_data(body, n_dev: int):
+    """``body`` shard_mapped over the 1-D "data" mesh of ``n_dev`` devices."""
+    return jax.shard_map(body, mesh=mesh_lib.make_data_mesh(n_dev),
+                         in_specs=P("data"), out_specs=P("data"),
+                         check_vma=False)
+
+
 def _pad_rows(n: int, n_dev: int) -> int:
     """Bucketed batch size: next power of two, then up to a device multiple."""
     b = 1
@@ -232,8 +238,7 @@ def _compress_sharded(imgs, transform, quality, cordic_config, n_dev):
                                                  cordic_config)
     if n_dev == 1:
         return body(imgs)
-    return compat.shard_map(body, mesh_lib.make_data_mesh(n_dev),
-                            in_specs=P("data"), out_specs=P("data"))(imgs)
+    return _shard_data(body, n_dev)(imgs)
 
 
 @functools.partial(jax.jit, static_argnames=("transform", "quality",
@@ -243,8 +248,7 @@ def _decompress_sharded(qcoeffs, transform, quality, cordic_config, n_dev):
                                                    cordic_config)
     if n_dev == 1:
         return body(qcoeffs)
-    return compat.shard_map(body, mesh_lib.make_data_mesh(n_dev),
-                            in_specs=P("data"), out_specs=P("data"))(qcoeffs)
+    return _shard_data(body, n_dev)(qcoeffs)
 
 
 @functools.partial(jax.jit, static_argnames=("transform", "quality",
@@ -258,8 +262,7 @@ def _fused_roundtrip_sharded(imgs, transform, quality, cordic_config, n_dev):
         return rec
     if n_dev == 1:
         return body(imgs)
-    return compat.shard_map(body, mesh_lib.make_data_mesh(n_dev),
-                            in_specs=P("data"), out_specs=P("data"))(imgs)
+    return _shard_data(body, n_dev)(imgs)
 
 
 def _run_batched(fn, arr: jnp.ndarray) -> jnp.ndarray:
@@ -558,7 +561,8 @@ def decode_batch(blobs, mode: str = "standard",
             serially (identical output either way).
         workers: pool width for the host edge (None = auto).
         executor: "thread" (default) or "process" (opt-in GIL-free
-            fallback for the Python-bound decode walk).
+            fallback for the Python-bound decode walk; host unpacker
+            only, since child processes cannot share the device).
         unpack_backend: entropy-unpack backend ("auto"/"pallas"/
             "numpy"), see :func:`repro.kernels.unpack_bits.unpack_bits`.
             "auto" keeps the LUT walk off-TPU; "pallas" forces the
@@ -572,6 +576,9 @@ def decode_batch(blobs, mode: str = "standard",
     Raises:
         repro.core.entropy.BitstreamError: any malformed stream (the
         whole call fails; no partial results).
+        ValueError: unknown executor or backend, or
+        ``executor="process"`` with a device unpacker (checked before
+        any worker starts).
     """
     from repro.core import entropy
     from repro.core.entropy import huffman, scan
@@ -580,6 +587,15 @@ def decode_batch(blobs, mode: str = "standard",
         raise ValueError(f"unknown executor {executor!r}; expected "
                          f"'thread' or 'process'")
     unpacker = unpack_bits.make_unpacker(unpack_backend)
+    if executor == "process" and unpacker is not None:
+        # a chip belongs to the process that opened it: spawned workers
+        # would try to open it again and fail or hang
+        raise ValueError(
+            "executor='process' runs the entropy decode in child "
+            "processes, which cannot use the device unpacker "
+            f"(unpack_backend={unpack_backend!r} resolved to "
+            f"{unpack_bits.select_backend(unpack_backend)!r}); use "
+            "executor='thread' or unpack_backend='numpy'")
     decode_one = entropy.decode_zigzag_host if unpacker is None else \
         functools.partial(entropy.decode_zigzag_host, unpacker=unpacker)
     blobs = list(blobs)

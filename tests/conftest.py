@@ -2,70 +2,10 @@
 device by default; multi-device tests spawn subprocesses with their own
 flags (CI additionally exports XLA_FLAGS=--xla_force_host_platform_
 device_count=8 so the ``multidevice`` tests run emulated).
-
-If ``hypothesis`` is unavailable (the hermetic container has no network),
-a deterministic mini-stub is installed: ``@given`` replays a fixed number
-of seeded examples instead of searching.  ``pip install -e .[dev]`` gets
-the real thing.
 """
-
-import random
-import sys
-import types
 
 import numpy as np
 import pytest
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:  # pragma: no cover - exercised only without the dep
-    def _build_hypothesis_stub():
-        mod = types.ModuleType("hypothesis")
-        st = types.ModuleType("hypothesis.strategies")
-
-        class _Strategy:
-            def __init__(self, draw):
-                self.draw = draw
-
-        st.integers = lambda lo, hi: _Strategy(
-            lambda r: r.randint(lo, hi))
-        st.sampled_from = lambda seq: _Strategy(
-            lambda r: seq[r.randrange(len(seq))])
-        st.floats = lambda lo, hi, **kw: _Strategy(
-            lambda r: r.uniform(lo, hi))
-        st.booleans = lambda: _Strategy(lambda r: r.random() < 0.5)
-
-        class settings:  # noqa: N801 - mirrors hypothesis' API
-            def __init__(self, max_examples=10, deadline=None, **kw):
-                self.max_examples = max_examples
-
-            def __call__(self, fn):
-                fn._stub_settings = self
-                return fn
-
-        def given(*strategies):
-            def deco(fn):
-                cfg = getattr(fn, "_stub_settings", None)
-                n = cfg.max_examples if cfg else 10
-
-                def wrapper(*args, **kwargs):
-                    rng = random.Random(0)
-                    for _ in range(n):
-                        drawn = [s.draw(rng) for s in strategies]
-                        fn(*args, *drawn, **kwargs)
-                wrapper.__name__ = fn.__name__
-                wrapper.__doc__ = fn.__doc__
-                wrapper.__module__ = fn.__module__
-                return wrapper
-            return deco
-
-        mod.given = given
-        mod.settings = settings
-        mod.strategies = st
-        sys.modules["hypothesis"] = mod
-        sys.modules["hypothesis.strategies"] = st
-
-    _build_hypothesis_stub()
 
 
 def pytest_configure(config):

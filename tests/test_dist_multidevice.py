@@ -70,7 +70,6 @@ def test_compressed_cross_pod_mean_and_bytes():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import make_mesh
-from repro.dist import compat
 from repro.dist.compressed import compressed_mean_flat, make_cross_axis_grad_sync
 from repro.optim.grad_compress import GradCompressConfig
 
@@ -84,8 +83,8 @@ ef = jnp.zeros((2, n))
 def body(gl, el):
     m, e = compressed_mean_flat(gl[0], el[0], "pod", keep=16)
     return m[None], e[None]
-sm = compat.shard_map(body, mesh, in_specs=(P("pod"), P("pod")),
-                      out_specs=(P("pod"), P("pod")))
+sm = jax.shard_map(body, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                   out_specs=(P("pod"), P("pod")), check_vma=False)
 mean, new_ef = jax.jit(sm)(g, ef)
 true = np.asarray(g).mean(0)
 a = np.asarray(mean[0]); b = np.asarray(mean[1])

@@ -611,6 +611,24 @@ class TestEngineBytePath:
         with pytest.raises(ValueError, match="executor"):
             codec_engine.decode_batch(blobs, executor="fibers")
 
+    def test_decode_batch_process_pool_refuses_device_unpacker(
+            self, monkeypatch):
+        # workers of a process pool cannot open the chip the parent
+        # holds: a device unpacker is refused before anything spawns
+        import multiprocessing
+
+        from repro.serve import codec_engine
+
+        def no_spawn(*a, **kw):
+            raise AssertionError("a process pool was started")
+
+        monkeypatch.setattr(multiprocessing, "get_context", no_spawn)
+        blobs = [encode_image(images.lena_like(24, 24, seed=i), 50)
+                 for i in range(2)]
+        with pytest.raises(ValueError, match="device unpacker"):
+            codec_engine.decode_batch(blobs, executor="process",
+                                      unpack_backend="pallas")
+
     def test_unpack_backend_routing_is_bit_identical(self):
         from repro.serve import codec_engine
         blobs = [encode_image(images.lena_like(48, 56, seed=i), 50)

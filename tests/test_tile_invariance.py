@@ -274,3 +274,25 @@ class TestPickTile:
             for target in CANDIDATES["dct8x8"]:
                 t = common.pick_tile(size, target)
                 assert size % t == 0 and t % 8 == 0 and t <= max(target, 8)
+
+    @pytest.mark.parametrize("width,target,want", [
+        (480, 256, 480),     # no multiple of 128 divides 480: whole width
+        (200, 256, 200),
+        (104, 64, 104),
+        (1024, 256, 256),
+        (1024, 64, 128),     # never below one 128-lane vreg
+        (1024, 1024, 1024),
+    ])
+    def test_lane_tile_is_128_multiple_or_full_width(self, width, target,
+                                                     want):
+        t = common.pick_lane_tile(width, target)
+        assert t == want
+        assert width % t == 0 and (t % 128 == 0 or t == width)
+
+    @pytest.mark.parametrize("h,w", [(512, 480), (200, 200), (104, 104),
+                                     (1024, 1024), (1024, 832)])
+    def test_tile_shape_obeys_tpu_block_rule(self, h, w):
+        for target in CANDIDATES["fused_codec"]:
+            th, tw = common.tile_shape(h, w, target)
+            assert h % th == 0 and th % 8 == 0
+            assert w % tw == 0 and (tw % 128 == 0 or tw == w)

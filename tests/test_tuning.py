@@ -183,21 +183,22 @@ def test_concurrent_lookups_consistent(tuning_path):
 # ---------------------------------------------------------------------------
 
 def test_dct8x8_routes_tuned_tile(tuning_path, monkeypatch):
+    from repro.kernels import common
     from repro.kernels.dct8x8 import kernel, ops
     _write(tuning_path, [_entry("dct8x8", 64, 16)])
     seen = {}
     real = kernel.dct8x8_pallas
 
-    def spy(x, t, *, tile_h, tile_w, **kw):
+    def spy(x, *, tile_h, tile_w, **kw):
         seen["tile"] = (tile_h, tile_w)
-        return real(x, t, tile_h=tile_h, tile_w=tile_w, **kw)
+        return real(x, tile_h=tile_h, tile_w=tile_w, **kw)
 
     monkeypatch.setattr(kernel, "dct8x8_pallas", spy)
     x = np.zeros((64, 64), np.float32)
     ops.dct8x8(x)                       # tile=None -> tuned 16
-    assert seen["tile"] == (16, 16)
+    assert seen["tile"] == common.tile_shape(64, 64, 16) == (8, 64)
     ops.dct8x8(x, tile=32)              # explicit tile pins the knob
-    assert seen["tile"] == (32, 32)
+    assert seen["tile"] == common.tile_shape(64, 64, 32) == (16, 64)
 
 
 def test_pack_bits_routes_tuned_tile_bits(tuning_path, monkeypatch):
