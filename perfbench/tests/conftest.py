@@ -1,0 +1,9 @@
+"""Tests of the benchmark itself: ``pytest perfbench/tests`` (CPU)."""
+
+import pathlib
+import sys
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+for p in (str(ROOT), str(ROOT / "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
