@@ -11,8 +11,8 @@ Phases (one process, in order; any failure raises and exits non-zero):
    on the host CPU in this process (PSNR and quantised levels);
 2. ``codec_engine.encode_batch`` / ``decode_batch`` bytes: every stream
    equals the host NumPy entropy coder on the same chip-computed levels
-   and decodes back to them exactly; the route of every entropy stage
-   is observed per image;
+   and decodes back to them exactly; the route every entropy stage took
+   is read from the program's counters (``repro.obs.counts()``);
 3. one ``CodecService`` serving 32 ragged requests at two quality
    tiers, every payload byte-identical to serial ``encode_batch``.
 
@@ -27,7 +27,6 @@ import collections
 import json
 import pathlib
 import sys
-import threading
 import time
 
 import jax
@@ -71,92 +70,20 @@ class Phase:
                   flush=True)
 
 
-# ---------------------------------------------------------------------------
-# Route observation: spies on module seams, recorded per calling thread
-# ---------------------------------------------------------------------------
-
-class Routes:
-    """Records which route each entropy stage took, per image call."""
-
-    def __init__(self):
-        self._tls = threading.local()
-        self.by_key: dict = {}
-        self.kernel_calls: list = []
-        self._lock = threading.Lock()
-
-    def note(self, stage: str, route: str) -> None:
-        events = getattr(self._tls, "events", None)
-        if events is not None:
-            events.append((stage, route))
-
-    def scoped(self, fn, key_of):
-        """Wrap ``fn`` so stage notes made inside it land under a key."""
-        def wrapper(*args, **kwargs):
-            self._tls.events = []
-            try:
-                out = fn(*args, **kwargs)
-            finally:
-                events, self._tls.events = self._tls.events, None
-            with self._lock:
-                self.by_key[key_of(args, out)] = events
-            return out
-        return wrapper
-
-    def kernel(self, stage: str, fn):
-        """Wrap a Pallas entry point: note its route and interpret flag."""
-        def wrapper(*args, **kwargs):
-            interpret = kwargs.get("interpret")
-            dev = sorted({d.id for a in args if hasattr(a, "devices")
-                          for d in a.devices()})
-            with self._lock:
-                self.kernel_calls.append((stage, interpret, tuple(dev)))
-            self.note(stage, "pallas" if interpret is False
-                      else "pallas-interpret")
-            return fn(*args, **kwargs)
-        return wrapper
+def counted(fn, *args, **kwargs):
+    """``(fn(*args, **kwargs), the program's counters it moved)``."""
+    from repro import obs
+    before = obs.counts()
+    out = fn(*args, **kwargs)
+    return out, {k: v - before.get(k, 0) for k, v in obs.counts().items()
+                 if v != before.get(k, 0)}
 
 
-def install_spies(routes: Routes) -> None:
-    from repro.core import entropy
-    from repro.kernels.pack_bits import kernel as pk
-    from repro.kernels.pack_bits import ref as pref
-    from repro.kernels.symbolize import kernel as sk
-    from repro.kernels.symbolize import ops as sops
-    from repro.kernels.unpack_bits import kernel as uk
-    from repro.kernels.unpack_bits import ref as uref
-
-    sk.symbolize_pallas = routes.kernel("symbolize", sk.symbolize_pallas)
-    pk.pack_bits_pallas = routes.kernel("pack", pk.pack_bits_pallas)
-    uk.unit_words_pallas = routes.kernel("unpack", uk.unit_words_pallas)
-
-    class HostPrepared(sops._NumpyPrepared):
-        def __init__(self, dense, packer):
-            routes.note("symbolize", f"host ({dense.total.shape[0]} "
-                        f"blocks)")
-            super().__init__(dense, packer)
-
-    sops._NumpyPrepared = HostPrepared
-
-    def host(stage, fn):
-        def wrapper(*args, **kwargs):
-            routes.note(stage, "host")
-            return fn(*args, **kwargs)
-        return wrapper
-
-    pref.scatter_pack_ref = host("pack", pref.scatter_pack_ref)
-    uref.unpack_bits_ref = host("unpack", uref.unpack_bits_ref)
-    entropy.encode_zigzag_host = routes.scoped(
-        entropy.encode_zigzag_host, lambda args, out: ("enc", out))
-    entropy.decode_zigzag_host = routes.scoped(
-        entropy.decode_zigzag_host, lambda args, out: ("dec", args[0]))
-
-
-def summarise(events) -> str:
-    stages = collections.defaultdict(list)
-    for stage, route in events:
-        if route not in stages[stage]:
-            stages[stage].append(route)
-    return " ".join(f"{s}={'+'.join(r)}" for s, r in sorted(stages.items()))
+def routes(delta: dict, stage: str) -> dict:
+    """``{route: images}`` of one entropy stage in a counter delta."""
+    head = f"entropy.{stage}."
+    return {k[len(head):]: v for k, v in delta.items()
+            if k.startswith(head) and ".device." not in k}
 
 
 # ---------------------------------------------------------------------------
@@ -185,14 +112,6 @@ def phase_roundtrip() -> None:
     from repro.serve import codec_engine as eng
 
     check(not common.interpret_default(), "Pallas would run interpreted")
-    calls = collections.Counter()
-    real = eng._fused_roundtrip_sharded
-
-    def spy(imgs, *args, **kwargs):
-        calls[imgs.shape] += 1
-        return real(imgs, *args, **kwargs)
-
-    eng._fused_roundtrip_sharded = spy
     lena = np.stack([images.lena_like(512, 512, seed=i) for i in range(8)])
     cable = np.stack([images.cablecar_like(512, 480, seed=i)
                       for i in range(8)])
@@ -201,49 +120,45 @@ def phase_roundtrip() -> None:
              ("8 x lena 512x512, cordic matched", lena, "cordic", "matched"),
              ("8 x cablecar 512x480, exact", cable, "exact", "standard"),
              ("1 x lena 1024x1024, exact", big, "exact", "standard")]
-    try:
-        for name, imgs, transform, mode in cases:
-            check(eng._fused_ok(transform, mode),
-                  f"{name}: the fused route is not taken")
-            hlo = real.lower(imgs, transform=transform, quality=QUALITY,
-                             cordic_config=cordic.PAPER_CONFIG,
-                             n_dev=1).as_text()
-            check("tpu_custom_call" in hlo,
-                  f"{name}: no Pallas kernel in the fused program")
-            before = sum(calls.values())
-            t0 = time.perf_counter()
-            rec, psnr = eng.roundtrip_batch(imgs, QUALITY, transform,
-                                            mode=mode)
-            rec = np.asarray(rec)
-            dt = time.perf_counter() - t0
-            check(sum(calls.values()) > before,
-                  f"{name}: roundtrip_batch did not call the fused kernel")
-            _, qc = fused_codec(imgs, quality=QUALITY, transform=transform)
-            qc = np.asarray(qc)
-            ref_levels, ref_rec, ref_psnr = cpu_reference(imgs, transform,
-                                                          mode)
-            diff = np.abs(qc.astype(np.int64) - ref_levels)
-            n_diff = int((diff > 0).sum())
-            frac = n_diff / diff.size
-            dpsnr = float(np.abs(psnr - ref_psnr).max())
-            print(f"   {name}: {dt:.2f} s (first call, compile included)")
-            print(f"     PSNR chip  {np.round(psnr, 4).tolist()}")
-            print(f"     PSNR cpu   {np.round(ref_psnr, 4).tolist()}")
-            print(f"     max |dPSNR| {dpsnr:.6f} dB; levels differing "
-                  f"{n_diff} of {diff.size} ({frac:.2e}), max |diff| "
-                  f"{int(diff.max())}; rec pixels differing "
-                  f"{int((rec != ref_rec).sum())}")
-            check(rec.shape == ref_rec.shape and np.isfinite(psnr).all(),
-                  f"{name}: bad output shape or PSNR")
-            check(dpsnr <= TOL_PSNR_DB, f"{name}: PSNR differs by "
-                  f"{dpsnr} dB from the CPU")
-            check(frac <= TOL_LEVEL_FRACTION and int(diff.max()) <= 1,
-                  f"{name}: {n_diff} levels differ (max {diff.max()})")
-    finally:
-        eng._fused_roundtrip_sharded = real
+    for name, imgs, transform, mode in cases:
+        check(eng._fused_ok(transform, mode),
+              f"{name}: the fused route is not taken")
+        hlo = eng._fused_roundtrip_sharded.lower(
+            imgs, transform=transform, quality=QUALITY,
+            cordic_config=cordic.PAPER_CONFIG, n_dev=1).as_text()
+        check("tpu_custom_call" in hlo,
+              f"{name}: no Pallas kernel in the fused program")
+        t0 = time.perf_counter()
+        (rec, psnr), delta = counted(eng.roundtrip_batch, imgs, QUALITY,
+                                     transform, mode=mode)
+        rec = np.asarray(rec)
+        dt = time.perf_counter() - t0
+        check(delta.get("engine.roundtrip.fused", 0) > 0,
+              f"{name}: roundtrip_batch did not call the fused kernel")
+        _, qc = fused_codec(imgs, quality=QUALITY, transform=transform)
+        qc = np.asarray(qc)
+        ref_levels, ref_rec, ref_psnr = cpu_reference(imgs, transform,
+                                                      mode)
+        diff = np.abs(qc.astype(np.int64) - ref_levels)
+        n_diff = int((diff > 0).sum())
+        frac = n_diff / diff.size
+        dpsnr = float(np.abs(psnr - ref_psnr).max())
+        print(f"   {name}: {dt:.2f} s (first call, compile included)")
+        print(f"     PSNR chip  {np.round(psnr, 4).tolist()}")
+        print(f"     PSNR cpu   {np.round(ref_psnr, 4).tolist()}")
+        print(f"     max |dPSNR| {dpsnr:.6f} dB; levels differing "
+              f"{n_diff} of {diff.size} ({frac:.2e}), max |diff| "
+              f"{int(diff.max())}; rec pixels differing "
+              f"{int((rec != ref_rec).sum())}")
+        check(rec.shape == ref_rec.shape and np.isfinite(psnr).all(),
+              f"{name}: bad output shape or PSNR")
+        check(dpsnr <= TOL_PSNR_DB, f"{name}: PSNR differs by "
+              f"{dpsnr} dB from the CPU")
+        check(frac <= TOL_LEVEL_FRACTION and int(diff.max()) <= 1,
+              f"{name}: {n_diff} levels differ (max {diff.max()})")
 
 
-def phase_bytes(routes: Routes) -> None:
+def phase_bytes() -> None:
     from repro.core import entropy, images
     from repro.kernels import symbolize, unpack_bits
     from repro.serve import codec_engine as eng
@@ -258,18 +173,28 @@ def phase_bytes(routes: Routes) -> None:
         stacked = np.stack(imgs)
         n_blocks = (imgs[0].shape[0] // 8) * (imgs[0].shape[1] // 8)
         t0 = time.perf_counter()
-        blobs = eng.encode_batch(stacked, QUALITY)
+        blobs, enc = counted(eng.encode_batch, stacked, QUALITY)
         t_enc = time.perf_counter() - t0
         t0 = time.perf_counter()
-        recs = eng.decode_batch(blobs)
+        recs, dec = counted(eng.decode_batch, blobs)
         t_dec = time.perf_counter() - t0
+        took = {stage: routes(enc if stage != "unpack" else dec, stage)
+                for stage in ("symbolize", "pack", "unpack")}
         print(f"   {name}: encode_batch {t_enc:.2f} s, decode_batch "
               f"{t_dec:.2f} s (first calls, compile included); "
               f"{n_blocks} blocks/image vs MAX_DEVICE_BLOCKS="
-              f"{symbolize.MAX_DEVICE_BLOCKS}")
+              f"{symbolize.MAX_DEVICE_BLOCKS}; images per route {took}")
         if n_blocks > symbolize.MAX_DEVICE_BLOCKS:
             print(f"     above the device guard: symbolize runs on the "
                   f"host for this size (ROADMAP A5)")
+            check(took["symbolize"] == {"host": len(imgs)},
+                  f"{name}: symbolize took {took['symbolize']}, not the "
+                  f"host for every image")
+        if must_be_device:
+            for stage, by_route in took.items():
+                check(by_route == {"pallas": len(imgs)},
+                      f"{name}: {stage} took {by_route}, not the compiled "
+                      f"Pallas kernel for every image")
         cb = eng.compress_batch(stacked, QUALITY)
         levels = cb._image_qcoeffs()
         ref_recs = eng.decompress_batch(cb)
@@ -286,17 +211,7 @@ def phase_bytes(routes: Routes) -> None:
                                  np.asarray(ref_recs[i])),
                   f"{name} image {i}: decode_batch differs from "
                   f"decompress_batch")
-            enc = routes.by_key.get(("enc", blob), [])
-            dec = routes.by_key.get(("dec", blob), [])
-            line = summarise(enc + [(f"{s}", r) for s, r in dec])
-            print(f"     image {i} {shape[0]}x{shape[1]}: {len(blob)} B; "
-                  f"{line}")
-            if must_be_device:
-                for stage in ("symbolize", "pack", "unpack"):
-                    took = {r for s, r in enc + dec if s == stage}
-                    check(took == {"pallas"}, f"{name} image {i}: "
-                          f"{stage} took {sorted(took)}, not the compiled "
-                          f"Pallas kernel")
+            print(f"     image {i} {shape[0]}x{shape[1]}: {len(blob)} B")
 
 
 async def _serve(imgs, tiers):
@@ -344,7 +259,7 @@ def phase_service() -> None:
               f"serial encode_batch")
 
 
-def phase_four_chips(routes: Routes) -> None:
+def phase_four_chips() -> None:
     from repro.core import codec, images
     from repro.kernels.fused_codec import fused_codec
     from repro.serve import codec_engine as eng
@@ -371,7 +286,7 @@ def phase_four_chips(routes: Routes) -> None:
           f"{np.round(psnr, 3).tolist()}")
     t0 = time.perf_counter()
     cb = eng.compress_batch(imgs, QUALITY)
-    blobs = eng.encode_batch(imgs, QUALITY)
+    blobs, delta = counted(eng.encode_batch, imgs, QUALITY)
     print(f"   encode_batch: {time.perf_counter() - t0:.2f} s; levels on "
           f"devices {sorted(d.id for d in cb.groups[0].qcoeffs.devices())}")
     levels = cb._image_qcoeffs()
@@ -381,10 +296,10 @@ def phase_four_chips(routes: Routes) -> None:
               f"image {i}: sharded levels differ from one device")
         check(blobs[i] == c.to_bytes(), f"image {i}: bytes differ from "
               f"per-image compress().to_bytes()")
-    kdev = collections.Counter((s, d) for s, _, d in routes.kernel_calls)
+    kdev = {k[len("entropy."):]: n for k, n in delta.items()
+            if ".device." in k}
     print(f"   levels and bytes identical to one device for all 16; "
-          f"entropy kernels ran on devices: "
-          f"{ {f'{s} on {list(d)}': n for (s, d), n in kdev.items()} }")
+          f"entropy-kernel launches by device: {kdev}")
 
 
 def main() -> int:
@@ -404,21 +319,20 @@ def main() -> int:
     print(f"device {dev.device_kind} x {len(jax.devices())}, jax "
           f"{jax.__version__}, compile cache {cache}", flush=True)
 
-    routes = Routes()
-    install_spies(routes)
+    from repro import obs
     t0 = time.perf_counter()
     if args.chips == 4:
         with Phase("4 devices: sharded roundtrip and encode"):
-            phase_four_chips(routes)
+            phase_four_chips()
     else:
         with Phase("1 roundtrip, fused kernel route"):
             phase_roundtrip()
         with Phase("2 bytes, entropy encode and decode"):
-            phase_bytes(routes)
+            phase_bytes()
         with Phase("3 service"):
             phase_service()
-    interp = {s for s, i, _ in routes.kernel_calls if i is not False}
-    check(not interp, f"kernels ran in interpret mode: {sorted(interp)}")
+    interp = sorted(k for k in obs.counts() if k.endswith(".interpret"))
+    check(not interp, f"kernels ran in interpret mode: {interp}")
     print(f"total {time.perf_counter() - t0:.1f} s, of which "
           f"{_compile_s[0]:.1f} s compiling", flush=True)
     print(json.dumps({"ok": True, "device": {

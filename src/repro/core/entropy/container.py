@@ -42,6 +42,7 @@ import zlib
 
 import numpy as np
 
+from repro import obs
 from repro.core.entropy import bitio, huffman, rle
 
 MAGIC = b"DCTZ"
@@ -236,30 +237,34 @@ def _frame_stream(dc_diff: np.ndarray, ac: np.ndarray, quality: int,
     (CI-gated), so the table negotiation and framing here never change.
     """
     prep = (symbolizer or rle.prepare_stream)(dc_diff, ac, packer=packer)
-    dc_id, dc_table = _choose_table(prep.dc_freq,
-                                    huffman.STANDARD_DC_LUMA_ID,
-                                    tables, "DC")
-    ac_id, ac_table = _choose_table(prep.ac_freq,
-                                    huffman.STANDARD_AC_LUMA_ID,
-                                    tables, "AC")
-    payload = prep.payload(dc_table, ac_table)
+    with obs.span("entropy.tables"):
+        dc_id, dc_table = _choose_table(prep.dc_freq,
+                                        huffman.STANDARD_DC_LUMA_ID,
+                                        tables, "DC")
+        ac_id, ac_table = _choose_table(prep.ac_freq,
+                                        huffman.STANDARD_AC_LUMA_ID,
+                                        tables, "AC")
+    with obs.span("entropy.payload"):
+        payload = prep.payload(dc_table, ac_table)
 
-    table_segs = b""
-    if dc_id == TABLE_EMBEDDED:
-        table_segs += dc_table.to_segment()
-    if ac_id == TABLE_EMBEDDED:
-        table_segs += ac_table.to_segment()
-    # fully-embedded streams keep the version-1 byte layout so pre-v2
-    # decoders (and the golden fixtures) are untouched
-    version = (VERSION_EMBEDDED
-               if dc_id == ac_id == TABLE_EMBEDDED else VERSION_SHARED)
-    header = _HEADER.pack(MAGIC, version, 0, int(quality),
-                          TRANSFORM_CODES[transform], h, w,
-                          dc_id, ac_id, 0, len(payload), 0)
-    # CRC protects every header field after the magic (a flipped quality
-    # or shape byte must not decode plausibly) plus tables and payload
-    crc = zlib.crc32(header[4:24] + table_segs + payload) & 0xFFFFFFFF
-    return header[:24] + struct.pack("<I", crc) + table_segs + payload
+    with obs.span("entropy.frame"):
+        table_segs = b""
+        if dc_id == TABLE_EMBEDDED:
+            table_segs += dc_table.to_segment()
+        if ac_id == TABLE_EMBEDDED:
+            table_segs += ac_table.to_segment()
+        # fully-embedded streams keep the version-1 byte layout so pre-v2
+        # decoders (and the golden fixtures) are untouched
+        version = (VERSION_EMBEDDED
+                   if dc_id == ac_id == TABLE_EMBEDDED else VERSION_SHARED)
+        header = _HEADER.pack(MAGIC, version, 0, int(quality),
+                              TRANSFORM_CODES[transform], h, w,
+                              dc_id, ac_id, 0, len(payload), 0)
+        # CRC protects every header field after the magic (a flipped
+        # quality or shape byte must not decode plausibly) plus tables
+        # and payload
+        crc = zlib.crc32(header[4:24] + table_segs + payload) & 0xFFFFFFFF
+        return header[:24] + struct.pack("<I", crc) + table_segs + payload
 
 
 def read_header(data: bytes) -> dict:
@@ -393,21 +398,22 @@ def decode_zigzag_host(data: bytes, *, unpacker=None) -> tuple:
             payload), trailing bytes, CRC mismatch, invalid table
             segments or ids, or an undecodable entropy payload.
     """
-    hdr = read_header(data)
-    dc_table, ac_table, off = _resolve_tables(data, hdr)
-    end = off + hdr["payload_nbytes"]
-    if len(data) < end:
-        raise BitstreamError(
-            f"truncated payload: stream has {len(data) - off} of "
-            f"{hdr['payload_nbytes']} declared bytes")
-    if len(data) > end:
-        raise BitstreamError(f"{len(data) - end} trailing bytes after "
-                             f"the declared payload")
-    crc = zlib.crc32(data[4:24] + data[HEADER_NBYTES:end]) & 0xFFFFFFFF
-    if crc != hdr["crc32"]:
-        raise BitstreamError(
-            f"CRC mismatch: header says {hdr['crc32']:#010x}, stream "
-            f"hashes to {crc:#010x} (corrupted stream)")
+    with obs.span("entropy.parse"):
+        hdr = read_header(data)
+        dc_table, ac_table, off = _resolve_tables(data, hdr)
+        end = off + hdr["payload_nbytes"]
+        if len(data) < end:
+            raise BitstreamError(
+                f"truncated payload: stream has {len(data) - off} of "
+                f"{hdr['payload_nbytes']} declared bytes")
+        if len(data) > end:
+            raise BitstreamError(f"{len(data) - end} trailing bytes after "
+                                 f"the declared payload")
+        crc = zlib.crc32(data[4:24] + data[HEADER_NBYTES:end]) & 0xFFFFFFFF
+        if crc != hdr["crc32"]:
+            raise BitstreamError(
+                f"CRC mismatch: header says {hdr['crc32']:#010x}, stream "
+                f"hashes to {crc:#010x} (corrupted stream)")
 
     gh, gw = _grid_shape(hdr["height"], hdr["width"])
     # every block costs at least 2 payload bits (DC code + EOB), so a

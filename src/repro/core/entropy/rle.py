@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import obs
 from repro.core.entropy import bitio, huffman
 
 EOB = 0x00
@@ -279,7 +280,10 @@ def encode_payload(is_dc, syms, amp_vals, amp_lens,
     """
     fields, widths = codeword_fields(is_dc, syms, amp_vals, amp_lens,
                                      dc_table, ac_table)
-    return (packer or bitio.pack_bits)(fields, widths)
+    if packer is not None:
+        return packer(fields, widths)
+    with obs.route("pack", "host"):
+        return bitio.pack_bits(fields, widths)
 
 
 class PreparedStream:
@@ -296,10 +300,11 @@ class PreparedStream:
     """
 
     def __init__(self, dc_diff: np.ndarray, ac: np.ndarray, packer=None):
-        self._stream = symbolize(dc_diff, ac)
-        self._packer = packer
-        self.dc_freq, self.ac_freq = symbol_frequencies(
-            self._stream[0], self._stream[1])
+        with obs.route("symbolize", "host", blocks=len(dc_diff)):
+            self._stream = symbolize(dc_diff, ac)
+            self._packer = packer
+            self.dc_freq, self.ac_freq = symbol_frequencies(
+                self._stream[0], self._stream[1])
 
     def payload(self, dc_table: huffman.CanonicalTable,
                 ac_table: huffman.CanonicalTable) -> bytes:
@@ -490,6 +495,14 @@ def decode_payload(payload: bytes, n_blocks: int,
         unpack = _staged_unpacker()
         if unpack is not None:
             return unpack(payload, n_blocks, dc_table, ac_table)
+    with obs.route("unpack", "host", blocks=n_blocks):
+        return _walk(payload, nbits, n_blocks, dc_table, ac_table)
+
+
+def _walk(payload: bytes, nbits: int, n_blocks: int,
+          dc_table: huffman.CanonicalTable,
+          ac_table: huffman.CanonicalTable) -> tuple:
+    """The LUT walk of :func:`decode_payload` (same contract)."""
     win = bitio.bit_windows(payload)
     dc_tab = _decode_table(win, nbits, dc_table)
     ac_tab = _decode_table(win, nbits, ac_table)

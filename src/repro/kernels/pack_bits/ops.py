@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels import tuning
 from repro.kernels.pack_bits import kernel, ref
 
@@ -76,7 +77,8 @@ def pack_bits(codes, lengths, *, backend: str = "auto",
         every ``tile_bits``.
     """
     if select_backend(backend) == "numpy":
-        return ref.pack_bits_ref(codes, lengths)
+        with obs.route("pack", "host"):
+            return ref.pack_bits_ref(codes, lengths)
     return _pack_bits_device(codes, lengths, interpret, tile_bits)
 
 
@@ -135,8 +137,10 @@ def pack_fields_device(fields, total: int, tile_bits: int,
                             tile_bits=tile_bits, window=window)
     out = kernel.pack_bits_pallas(fields, blocks, tile_bits=tile_bits,
                                   window=window, interpret=interpret)
+    obs.launched("pack", out)
     nbytes = (total + 7) // 8
-    by = np.asarray(out).reshape(-1)[:nbytes].astype(np.uint8)
+    with obs.d2h(out):
+        by = np.asarray(out).reshape(-1)[:nbytes].astype(np.uint8)
     pad = (-total) % 8
     if pad:                         # writer convention: 1-padded tail
         by[-1] |= (1 << pad) - 1
@@ -160,11 +164,14 @@ def _pack_bits_device(codes, lengths, interpret: bool | None,
         return b""
     m = int(c.size)
     if m > MAX_DEVICE_FIELDS:
-        return ref.scatter_pack_ref(c, ln, s, total).tobytes()
+        with obs.route("pack", "host"):
+            return ref.scatter_pack_ref(c, ln, s, total).tobytes()
     if tile_bits is None:
         tile_bits = tuning.tile_for("pack_bits", total)
-    fields = np.zeros((3, field_blocks(m, tile_bits)), np.int32)
-    fields[0, :m], fields[1, :m], fields[2, :m] = c, ln, s
-    fields[2, m:] = total
-    return pack_fields_device(jnp.asarray(fields), total, tile_bits,
-                              interpret)
+    with obs.device_route("pack", interpret):
+        fields = np.zeros((3, field_blocks(m, tile_bits)), np.int32)
+        fields[0, :m], fields[1, :m], fields[2, :m] = c, ln, s
+        fields[2, m:] = total
+        with obs.h2d(fields):
+            fields = jnp.asarray(fields)
+        return pack_fields_device(fields, total, tile_bits, interpret)

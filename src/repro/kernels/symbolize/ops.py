@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.entropy import huffman, rle
 from repro.kernels import tuning
 from repro.kernels.pack_bits import ops as pack_ops
@@ -86,10 +87,12 @@ def _run_kernel(dc_diff: np.ndarray, ac: np.ndarray, tile_blocks: int,
     acp = np.zeros((n_pad, ref.AC_LEN), np.int32)
     acp[:n] = ac
     nrows = np.array([n], np.int32)
-    return kernel.symbolize_pallas(jnp.asarray(dc), jnp.asarray(acp),
-                                   jnp.asarray(nrows),
-                                   tile_blocks=tile_blocks,
-                                   interpret=interpret)
+    with obs.h2d(dc, acp, nrows):
+        args = jnp.asarray(dc), jnp.asarray(acp), jnp.asarray(nrows)
+    out = kernel.symbolize_pallas(*args, tile_blocks=tile_blocks,
+                                  interpret=interpret)
+    obs.launched("symbolize", out[0])
+    return out
 
 
 def symbolize_dense(dc_diff, ac, *, backend: str = "auto",
@@ -118,16 +121,19 @@ def symbolize_dense(dc_diff, ac, *, backend: str = "auto",
     """
     dc_diff = np.asarray(dc_diff, dtype=np.int64)
     ac = np.asarray(ac, dtype=np.int64)
+    n = dc_diff.shape[0]
     if select_backend(backend) == "numpy" or not _device_ok(dc_diff, ac):
-        return ref.symbolize_dense(dc_diff, ac)
+        with obs.route("symbolize", "host", blocks=n):
+            return ref.symbolize_dense(dc_diff, ac)
     from repro.kernels import common
     if interpret is None:
         interpret = common.interpret_default()
     if tile_blocks is None:
-        tile_blocks = tuning.tile_for("symbolize", dc_diff.shape[0])
-    n = dc_diff.shape[0]
-    syms, amps, lens, total, dc_h, ac_h = jax.device_get(
-        _run_kernel(dc_diff, ac, tile_blocks, interpret))
+        tile_blocks = tuning.tile_for("symbolize", n)
+    with obs.device_route("symbolize", interpret, blocks=n):
+        out = _run_kernel(dc_diff, ac, tile_blocks, interpret)
+        with obs.d2h(*out):
+            syms, amps, lens, total, dc_h, ac_h = jax.device_get(out)
     return ref.DenseSymbols(
         syms=np.asarray(syms[:n], np.int16),
         amp_vals=np.asarray(amps[:n], np.int16),
@@ -218,19 +224,26 @@ class _PallasPrepared:
         self._n = n
         (self._syms, self._amps, self._lens, self._total,
          dc_h, ac_h) = _run_kernel(dc_diff, ac, tile_blocks, interpret)
-        dc_h, ac_h = jax.device_get((dc_h, ac_h))
+        with obs.d2h(dc_h, ac_h):
+            dc_h, ac_h = jax.device_get((dc_h, ac_h))
         self.dc_freq = np.asarray(dc_h[0], np.int64)
         self.ac_freq = np.asarray(ac_h[0], np.int64)
 
     def payload(self, dc_table: huffman.CanonicalTable,
                 ac_table: huffman.CanonicalTable) -> bytes:
-        lut = lambda a: jnp.asarray(np.asarray(a, np.int32))
-        dc_code, dc_len = huffman.encoder_luts(dc_table)
-        ac_code, ac_len = huffman.encoder_luts(ac_table)
+        with obs.device_route("pack", self._interpret):
+            return self._payload(dc_table, ac_table)
+
+    def _payload(self, dc_table, ac_table) -> bytes:
+        luts = [np.asarray(a, np.int32)
+                for a in (*huffman.encoder_luts(dc_table),
+                          *huffman.encoder_luts(ac_table))]
+        with obs.h2d(*luts):
+            luts = [jnp.asarray(a) for a in luts]
         f, w, s, total_bits, bad = _fields_device(
-            self._syms, self._amps, self._lens, self._total,
-            lut(dc_code), lut(dc_len), lut(ac_code), lut(ac_len))
-        bad, total = jax.device_get((bad, total_bits))
+            self._syms, self._amps, self._lens, self._total, *luts)
+        with obs.d2h(bad, total_bits):
+            bad, total = jax.device_get((bad, total_bits))
         if bool(bad):
             raise ValueError("symbol stream contains a symbol absent "
                              "from the Huffman table")
@@ -277,7 +290,10 @@ def make_symbolizer(backend: str = "auto", *,
                       if interpret is None else interpret)
             tiles = (tuning.tile_for("symbolize", dc_diff.shape[0])
                      if tile_blocks is None else tile_blocks)
-            return _PallasPrepared(dc_diff, ac, tiles, interp)
-        return _NumpyPrepared(ref.symbolize_dense(dc_diff, ac), packer)
+            with obs.device_route("symbolize", interp,
+                                  blocks=dc_diff.shape[0]):
+                return _PallasPrepared(dc_diff, ac, tiles, interp)
+        with obs.route("symbolize", "host", blocks=dc_diff.shape[0]):
+            return _NumpyPrepared(ref.symbolize_dense(dc_diff, ac), packer)
 
     return prepare

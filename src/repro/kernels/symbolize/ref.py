@@ -36,6 +36,7 @@ import dataclasses
 
 import numpy as np
 
+from repro import obs
 from repro.core.entropy import bitio, huffman, rle
 
 AC_LEN = rle.AC_LEN            # 63 zig-zag AC positions
@@ -229,4 +230,7 @@ def encode_payload_dense(dense: DenseSymbols,
     """Dense codeword lookup + bit packing; byte-identical to
     :func:`repro.core.entropy.rle.encode_payload` on the same stream."""
     fields, widths = encode_fields_dense(dense, dc_table, ac_table)
-    return (packer or bitio.pack_bits)(fields, widths)
+    if packer is not None:
+        return packer(fields, widths)
+    with obs.route("pack", "host"):
+        return bitio.pack_bits(fields, widths)

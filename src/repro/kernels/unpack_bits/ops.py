@@ -13,8 +13,10 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.entropy import bitio, huffman
 from repro.kernels import tuning
 from repro.kernels.unpack_bits import kernel, ref
@@ -82,7 +84,9 @@ def unpack_bits(payload: bytes, n_blocks: int,
         identical across backends and across every ``tile_bits``.
     """
     if select_backend(backend) == "numpy":
-        return ref.unpack_bits_ref(payload, n_blocks, dc_table, ac_table)
+        with obs.route("unpack", "host", blocks=n_blocks):
+            return ref.unpack_bits_ref(payload, n_blocks, dc_table,
+                                       ac_table)
     return _unpack_device(payload, n_blocks, dc_table, ac_table, interpret,
                           tile_bits)
 
@@ -166,9 +170,21 @@ def _unpack_device(payload: bytes, n_blocks: int,
         return (np.zeros(0, np.int32), np.zeros((0, ref.AC_LEN), np.int32))
     nbits = len(payload) * 8
     if nbits == 0 or nbits > MAX_DEVICE_BITS:
-        return ref.unpack_bits_ref(payload, n_blocks, dc_table, ac_table)
+        with obs.route("unpack", "host", blocks=n_blocks):
+            return ref.unpack_bits_ref(payload, n_blocks, dc_table,
+                                       ac_table)
     if tile_bits is None:
         tile_bits = tuning.tile_for("unpack_bits", nbits)
+    with obs.device_route("unpack", interpret, blocks=n_blocks):
+        return _unpack_staged(payload, nbits, n_blocks, dc_table, ac_table,
+                              interpret, tile_bits)
+
+
+def _unpack_staged(payload: bytes, nbits: int, n_blocks: int,
+                   dc_table: huffman.CanonicalTable,
+                   ac_table: huffman.CanonicalTable, interpret: bool,
+                   tile_bits: int) -> tuple:
+    """Stage, upload, launch, fetch the tiles, resolve the chain."""
     window = tile_bits + ref.MARGIN_BITS
     win = bitio.bit_windows(payload)
     n_tiles = _pow2(-(-(nbits + 1) // tile_bits))
@@ -180,13 +196,20 @@ def _unpack_device(payload: bytes, n_blocks: int,
     ac_params, ac_syms = table_params(ac_table)
     params = np.concatenate([np.array([nbits], np.int32), dc_params,
                              ac_params, dc_syms, ac_syms])
-    dcw, acw = kernel.unit_words_pallas(
-        params, win_pad.reshape(-1, kernel.LANES), interpret=interpret)
-    dcw, acw, outc = jax.device_get(kernel.stage_tiles(
-        dcw.reshape(-1), acw.reshape(-1), n_tiles=n_tiles,
-        tile_bits=tile_bits, window=window))
+    with obs.h2d(params, win_pad):
+        params_d = jnp.asarray(params)
+        win_d = jnp.asarray(win_pad.reshape(-1, kernel.LANES))
+    dcw, acw = kernel.unit_words_pallas(params_d, win_d,
+                                        interpret=interpret)
+    obs.launched("unpack", dcw)
+    staged = kernel.stage_tiles(dcw.reshape(-1), acw.reshape(-1),
+                                n_tiles=n_tiles, tile_bits=tile_bits,
+                                window=window)
+    with obs.d2h(*staged):
+        dcw, acw, outc = jax.device_get(staged)
 
     def get_tile(t):
         return dcw[t], acw[t], outc[t]
 
-    return ref.resolve(win, nbits, n_blocks, tile_bits, get_tile)
+    with obs.span("entropy.resolve"):
+        return ref.resolve(win, nbits, n_blocks, tile_bits, get_tile)

@@ -48,6 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core import codec, cordic, metrics
 from repro.launch import mesh as mesh_lib
 
@@ -120,7 +121,8 @@ class CompressedBatch:
         blocks that belong to no image)."""
         out = [None] * self.n_images
         for g in self.groups:
-            q = np.asarray(jax.device_get(g.qcoeffs))
+            with obs.d2h(g.qcoeffs):
+                q = np.asarray(jax.device_get(g.qcoeffs))
             for j, (idx, (h, w)) in enumerate(zip(g.indices,
                                                   g.orig_shapes)):
                 out[idx] = (q[j, :(h + 7) // 8, :(w + 7) // 8], (h, w))
@@ -174,12 +176,15 @@ class CompressedBatch:
             return list(self._streams[1])
         packer = pack_bits.make_packer(pack_backend)
         symbolizer = symbolize.make_symbolizer(symbolize_backend)
+        call = obs.current_call()
         if not pipelined:
             self._streams = (tables, [
-                entropy.encode_qcoeffs(q, self.quality, self.transform,
-                                       shape, tables=tables, packer=packer,
-                                       symbolizer=symbolizer)
-                for q, shape in self._image_qcoeffs()])
+                _encode_image(call, i, entropy.encode_qcoeffs, q,
+                              q.shape[0] * q.shape[1], self.quality,
+                              self.transform, shape, tables=tables,
+                              packer=packer, symbolizer=symbolizer)
+                for i, (q, shape) in enumerate(self._image_qcoeffs())])
+            obs.count("engine.images.encoded", self.n_images)
             return list(self._streams[1])
         # dispatch the zig-zag for every bucket up front: jax queues the
         # device work asynchronously, so bucket k+1 computes while the
@@ -190,18 +195,36 @@ class CompressedBatch:
                 _n_workers(workers)) as pool:
             for g, z in zip(self.groups, zs):
                 # blocks only on THIS bucket's device work
-                znp = np.asarray(jax.device_get(z))
+                with obs.d2h(z):
+                    znp = np.asarray(jax.device_get(z))
                 for j, (idx, (h, w)) in enumerate(zip(g.indices,
                                                       g.orig_shapes)):
                     gh, gw = (h + 7) // 8, (w + 7) // 8
                     jobs[idx] = pool.submit(
+                        _encode_image, call, idx,
                         entropy.encode_zigzag_host,
-                        znp[j, :gh, :gw].reshape(gh * gw, 64),
+                        znp[j, :gh, :gw].reshape(gh * gw, 64), gh * gw,
                         self.quality, self.transform, (h, w),
                         tables=tables, packer=packer,
                         symbolizer=symbolizer)
             self._streams = (tables, [f.result() for f in jobs])
+        obs.count("engine.images.encoded", self.n_images)
         return list(self._streams[1])
+
+
+def _encode_image(call: int, image: int, encode, levels, blocks: int,
+                  *args, **kwargs) -> bytes:
+    """``encode(levels, *args, **kwargs)`` in the image's span."""
+    with obs.span("entropy.encode_image", call=call, image=image,
+                  blocks=blocks):
+        return encode(levels, *args, **kwargs)
+
+
+def _decode_image(decode, call: int, image: int, blob):
+    """``decode(blob)`` in the image's span."""
+    with obs.span("entropy.decode_image", call=call, image=image,
+                  stream_bytes=len(blob)):
+        return decode(blob)
 
 
 # ---------------------------------------------------------------------------
@@ -280,6 +303,20 @@ def _run_batched(fn, arr: jnp.ndarray) -> jnp.ndarray:
 # Input normalisation (stacked vs ragged)
 # ---------------------------------------------------------------------------
 
+def _to_device(x):
+    """``x`` as a device array: a host array's copy is an ``xfer.h2d``
+    span, an array already on the device is returned as it is."""
+    if isinstance(x, jax.Array):
+        return x
+    x = np.asarray(x)
+    with obs.h2d(x):
+        return jnp.asarray(x)
+
+
+def _n_images(imgs) -> int:
+    return len(imgs) if getattr(imgs, "ndim", 1) else 0
+
+
 def _group_inputs(imgs):
     """Yield (stacked_padded_uint8, indices, orig_shapes) bucket groups.
 
@@ -289,7 +326,7 @@ def _group_inputs(imgs):
     O(#distinct buckets) compilations, not O(B).
     """
     if isinstance(imgs, (np.ndarray, jnp.ndarray)):
-        arr = jnp.asarray(imgs)
+        arr = _to_device(imgs)
         if arr.ndim != 3:
             raise ValueError(f"stacked batch must be (B, H, W), "
                              f"got {arr.shape}")
@@ -304,7 +341,7 @@ def _group_inputs(imgs):
         raise ValueError("empty batch: nothing to compress")
     buckets: dict = {}
     for i, im in enumerate(imgs):
-        im = jnp.asarray(im)
+        im = _to_device(im)
         if im.ndim != 2:
             raise ValueError(f"image {i} must be 2-D (H, W), got {im.shape}")
         h, w = im.shape
@@ -324,13 +361,14 @@ def _group_inputs(imgs):
 
 def _reassemble(per_group: list, groups: list, n: int, stacked: bool):
     """Scatter per-group outputs back to original input order."""
-    out = [None] * n
-    for imgs_out, (_, indices, orig_shapes) in zip(per_group, groups):
-        for j, (idx, (h, w)) in enumerate(zip(indices, orig_shapes)):
-            out[idx] = imgs_out[j, :h, :w]
-    if stacked:
-        return jnp.stack(out)
-    return out
+    with obs.span("engine.reassemble"):
+        out = [None] * n
+        for imgs_out, (_, indices, orig_shapes) in zip(per_group, groups):
+            for j, (idx, (h, w)) in enumerate(zip(indices, orig_shapes)):
+                out[idx] = imgs_out[j, :h, :w]
+        if stacked:
+            return jnp.stack(out)
+        return out
 
 
 # ---------------------------------------------------------------------------
@@ -357,17 +395,18 @@ def compress_batch(imgs, quality: int = 50,
         int32 quantised levels per bucket shape, plus the bookkeeping to
         restore input order and crop back to original sizes.
     """
-    groups, stacked = _group_inputs(imgs)
-    fn = functools.partial(_compress_sharded, transform=transform,
-                           quality=quality, cordic_config=cordic_config)
-    out = []
-    n = 0
-    for padded, indices, orig_shapes in groups:
-        q = _run_batched(
-            lambda a, nd: fn(a, n_dev=nd), padded)
-        out.append(CompressedGroup(qcoeffs=q, indices=indices,
-                                   orig_shapes=orig_shapes))
-        n += len(indices)
+    with obs.span("engine.compress"):
+        groups, stacked = _group_inputs(imgs)
+        fn = functools.partial(_compress_sharded, transform=transform,
+                               quality=quality, cordic_config=cordic_config)
+        out = []
+        n = 0
+        for padded, indices, orig_shapes in groups:
+            q = _run_batched(
+                lambda a, nd: fn(a, n_dev=nd), padded)
+            out.append(CompressedGroup(qcoeffs=q, indices=indices,
+                                       orig_shapes=orig_shapes))
+            n += len(indices)
     return CompressedBatch(groups=out, n_images=n, quality=quality,
                            transform=transform, cordic_config=cordic_config,
                            stacked=stacked)
@@ -393,8 +432,9 @@ def decompress_batch(cb: CompressedBatch, mode: str = "standard"):
     fn = functools.partial(_decompress_sharded, transform=dec_transform,
                            quality=cb.quality,
                            cordic_config=cb.cordic_config)
-    per_group = [_run_batched(lambda a, nd: fn(a, n_dev=nd), g.qcoeffs)
-                 for g in cb.groups]
+    with obs.span("engine.inverse"):
+        per_group = [_run_batched(lambda a, nd: fn(a, n_dev=nd), g.qcoeffs)
+                     for g in cb.groups]
     groups = [(None, g.indices, g.orig_shapes) for g in cb.groups]
     return _reassemble(per_group, groups, cb.n_images, cb.stacked)
 
@@ -435,7 +475,14 @@ def roundtrip_batch(imgs, quality: int = 50,
         for stacked input (a list for ragged input); ``psnr`` is a (B,)
         numpy array of dB values, or None when ``with_psnr=False``.
     """
+    with obs.call("engine.roundtrip", images=_n_images(imgs)):
+        return _roundtrip(imgs, quality, transform, cordic_config, mode,
+                          with_psnr)
+
+
+def _roundtrip(imgs, quality, transform, cordic_config, mode, with_psnr):
     if _fused_ok(transform, mode):
+        obs.count("engine.roundtrip.fused")
         groups, stacked = _group_inputs(imgs)
         fn = functools.partial(_fused_roundtrip_sharded, transform=transform,
                                quality=quality, cordic_config=cordic_config)
@@ -444,16 +491,22 @@ def roundtrip_batch(imgs, quality: int = 50,
         n = sum(len(g[1]) for g in groups)
         rec = _reassemble(per_group, groups, n, stacked)
     else:
+        obs.count("engine.roundtrip.staged")
         cb = compress_batch(imgs, quality, transform, cordic_config)
         rec = decompress_batch(cb, mode=mode)
 
     if not with_psnr:
         return rec, None
-    if isinstance(rec, list):
-        psnr = np.array([float(metrics.psnr(jnp.asarray(im), r))
-                         for im, r in zip(imgs, rec)])
-    else:
-        psnr = np.asarray(_psnr_vec(jnp.asarray(imgs), rec))
+    with obs.span("engine.psnr"):
+        if isinstance(rec, list):
+            vals = [metrics.psnr(_to_device(im), r)
+                    for im, r in zip(imgs, rec)]
+            with obs.d2h(*vals):
+                psnr = np.array([float(v) for v in jax.device_get(vals)])
+        else:
+            vals = _psnr_vec(_to_device(imgs), rec)
+            with obs.d2h(vals):
+                psnr = np.asarray(jax.device_get(vals))
     return rec, psnr
 
 
@@ -499,10 +552,11 @@ def encode_batch(imgs, quality: int = 50,
         each is bit-identical to ``core.codec.compress(img).to_bytes()``
         under the same table policy.
     """
-    cb = compress_batch(imgs, quality, transform, cordic_config)
-    return cb.to_bytes_list(pipelined=pipelined, workers=workers,
-                            pack_backend=pack_backend, tables=tables,
-                            symbolize_backend=symbolize_backend)
+    with obs.call("engine.encode", images=_n_images(imgs)):
+        cb = compress_batch(imgs, quality, transform, cordic_config)
+        return cb.to_bytes_list(pipelined=pipelined, workers=workers,
+                                pack_backend=pack_backend, tables=tables,
+                                symbolize_backend=symbolize_backend)
 
 
 def _hydrate_tables(segments) -> None:
@@ -581,7 +635,6 @@ def decode_batch(blobs, mode: str = "standard",
         any worker starts).
     """
     from repro.core import entropy
-    from repro.core.entropy import huffman, scan
     from repro.kernels import unpack_bits
     if executor not in ("thread", "process"):
         raise ValueError(f"unknown executor {executor!r}; expected "
@@ -601,6 +654,16 @@ def decode_batch(blobs, mode: str = "standard",
     blobs = list(blobs)
     if not blobs:
         raise ValueError("empty batch: nothing to decode")
+    with obs.call("engine.decode", images=len(blobs)) as call:
+        out = _decode(blobs, decode_one, call, mode, pipelined, workers,
+                      executor)
+    obs.count("engine.images.decoded", len(blobs))
+    return out
+
+
+def _decode(blobs, decode_one, call, mode, pipelined, workers, executor):
+    from repro.core.entropy import huffman, scan
+    traced = functools.partial(_decode_image, decode_one, call)
     if pipelined and len(blobs) > 1:
         # each stream's entropy decode is independent host/device work
         if executor == "process":
@@ -619,27 +682,31 @@ def decode_batch(blobs, mode: str = "standard",
         else:
             with concurrent.futures.ThreadPoolExecutor(
                     _n_workers(workers)) as pool:
-                decoded = list(pool.map(decode_one, blobs))
+                decoded = list(pool.map(traced, range(len(blobs)), blobs))
     else:
-        decoded = [decode_one(b) for b in blobs]
+        decoded = [traced(i, b) for i, b in enumerate(blobs)]
 
-    buckets: dict = {}
-    for i, (z, hdr) in enumerate(decoded):
-        dec_transform = "exact" if mode == "standard" else hdr["transform"]
-        grid = ((hdr["height"] + 7) // 8, (hdr["width"] + 7) // 8)
-        key = (grid, hdr["quality"], dec_transform)
-        buckets.setdefault(key, []).append(i)
+    with obs.span("engine.inverse"):
+        buckets: dict = {}
+        for i, (z, hdr) in enumerate(decoded):
+            dec_transform = ("exact" if mode == "standard"
+                             else hdr["transform"])
+            grid = ((hdr["height"] + 7) // 8, (hdr["width"] + 7) // 8)
+            key = (grid, hdr["quality"], dec_transform)
+            buckets.setdefault(key, []).append(i)
 
-    out = [None] * len(blobs)
-    for ((gh, gw), quality, dec_transform), members in buckets.items():
-        stackz = jnp.stack([jnp.asarray(decoded[i][0]) for i in members])
-        # device half of the inverse: un-zig-zag the whole group at once
-        stackq = scan.zigzag_unscan(stackz).reshape(-1, gh, gw, 8, 8)
-        fn = functools.partial(_decompress_sharded,
-                               transform=dec_transform, quality=quality,
-                               cordic_config=cordic.PAPER_CONFIG)
-        rec = _run_batched(lambda a, nd: fn(a, n_dev=nd), stackq)
-        for j, i in enumerate(members):
-            hdr = decoded[i][1]
-            out[i] = rec[j, :hdr["height"], :hdr["width"]]
+        out = [None] * len(blobs)
+        for ((gh, gw), quality, dec_transform), members in buckets.items():
+            zs = [decoded[i][0] for i in members]
+            with obs.h2d(*zs):
+                stackz = jnp.stack([jnp.asarray(z) for z in zs])
+            # device half of the inverse: un-zig-zag the whole group at once
+            stackq = scan.zigzag_unscan(stackz).reshape(-1, gh, gw, 8, 8)
+            fn = functools.partial(_decompress_sharded,
+                                   transform=dec_transform, quality=quality,
+                                   cordic_config=cordic.PAPER_CONFIG)
+            rec = _run_batched(lambda a, nd: fn(a, n_dev=nd), stackq)
+            for j, i in enumerate(members):
+                hdr = decoded[i][1]
+                out[i] = rec[j, :hdr["height"], :hdr["width"]]
     return out
