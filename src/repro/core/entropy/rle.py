@@ -15,7 +15,7 @@ scalar implementations survive as :func:`symbolize_reference` /
 :func:`decode_payload_reference` — the golden oracles the property
 tests and the ``entropy_throughput`` bench compare against.  A third
 decode family lives in ``repro.kernels.unpack_bits`` (speculative
-per-offset decode + pointer doubling, docs/decoding.md) and plugs in
+per-offset decode + chain resolution, docs/decoding.md) and plugs in
 through :func:`decode_payload`'s ``unpacker`` hook; all three agree on
 values *and* errors by CI gate.
 

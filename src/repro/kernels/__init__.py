@@ -11,8 +11,9 @@ ops.py (jit wrapper) / ref.py (pure-jnp oracle) layout:
                   ref.py is staged NumPy, not jnp — the oracle must be
                   byte-exact, and bytes are a host-edge artifact
   unpack_bits     entropy-stage speculative Huffman decode (per-offset
-                  unit words + pointer doubling, resolved per block on
-                  the host); staged NumPy ref.py for the same reason
+                  unit words + a bounded forward walk of every chain,
+                  resolved per block on the host); staged NumPy ref.py
+                  (pointer doubling) for the same reason
 
 `tuning` is the shared tuned-tile lookup: when an ops.py router's tile
 knob is left at None it consults the autotuned winners persisted in

@@ -1,43 +1,41 @@
-"""Pallas TPU kernel: speculative Huffman decode of entropy payloads.
+"""Pallas TPU kernel and XLA walk: speculative Huffman decode.
 
 Device-resident realisation of the decode half of the entropy stage,
 mirroring :mod:`repro.kernels.pack_bits.kernel` on the encode side.
 Huffman decode is serial in the *bit offset* chain, not in the work:
-following Cloud et al. (arXiv:1107.1525), the grid tiles the payload's
-bit space and every program decodes **from every candidate bit offset**
-of its tile at once, leaving only an O(1)-per-block chain resolution to
-the host (:func:`repro.kernels.unpack_bits.ref.resolve`).
+following Cloud et al. (arXiv:1107.1525), every program decodes **from
+every candidate bit offset** at once, leaving only an O(1)-per-block
+chain resolution to the host (:func:`repro.kernels.unpack_bits.ref.resolve`).
 
-The work splits in two device stages:
+The work splits in two device stages, both over the whole payload:
 
-* **unit words (Pallas)** — every bit offset of the payload is an
-  independent lookup, laid out lane-dense as ``(rows, 128)`` offsets
-  and tiled over rows.  Canonical bounds replace the 64K prefix LUT:
-  the host hands in the tables' per-length ``(mincode, maxcode,
-  valptr)`` triplets and symbol lists via scalar prefetch; a codeword
-  is matched by 16 unrolled compares of the window's top ``L`` bits
-  against the length-``L`` bounds (prefix-free codes make at most one
-  length match, so matches combine with ``where`` and no priority
-  logic), and the symbol comes from a loop over the 256 symbol slots.
-* **chain outcomes (XLA)** — each offset's AC unit is summarised as
-  ``next`` (first bit after the unit) and ``dpos`` (coefficient
-  positions covered); six squarings via ``jnp.take_along_axis``
-  collapse every speculative AC chain to its terminal or its
-  position-63 crossing, exactly as the NumPy stage.  These gathers run
-  across a whole tile window, which the TPU compiler does not lower
-  inside a kernel, so they are a plain jitted XLA program on the
-  staged tile windows.
+* **unit words** (``unit_words_pallas``, Pallas) — every bit offset
+  of the payload is an independent lookup, laid out lane-dense as
+  ``(rows, 128)`` offsets and tiled over rows.  Canonical bounds
+  replace the 64K prefix LUT: the host hands in the tables' per-length
+  ``(mincode, maxcode, valptr)`` triplets and symbol lists via scalar
+  prefetch; a codeword is matched by 16 unrolled compares of the
+  window's top ``L`` bits against the length-``L`` bounds (prefix-free
+  codes make at most one length match, so matches combine with
+  ``where`` and no priority logic), and the symbol comes from a loop
+  over the 256 symbol slots.
+* **chain outcomes** (``stage_tiles``, XLA) — a bounded forward walk.
+  Each offset carries one walk word: where its AC chain stands, and
+  how many coefficient positions lie behind it.  A step moves every
+  chain one unit on by reading the word at ``offset + hop`` (``hop <=
+  31``, the longest unit), a select over 31 static shifts with no
+  gather; a chain stops on a terminal or on the unit that reaches
+  position 63.  After 64 steps (more units than a block holds) the
+  word gives the same outcome as the NumPy stage's pointer doubling
+  and descent.  It is a ``fori_loop`` of 64 fused element-wise steps
+  over the flat payload; a Pallas form of the same walk, blocked in
+  VMEM, measured twice as slow on a v5e.
 
 Values stay in the bitstream: unit words carry control and advance
 only; amplitudes are re-read on the host at resolved offsets, so
-per-offset state is O(1) regardless of payload size.
-
-Each tile covers ``tile_bits`` offsets plus a ``window - tile_bits``
-overhang so any block *starting* in the tile finishes inside the
-window (see ``ref.MARGIN_BITS``).  Unit and outcome words are
-bit-identical to :mod:`repro.kernels.unpack_bits.ref` at every offset
-the resolver can consume; margin-start chains clamped at the window
-edge are never read back.
+per-offset state is O(1) regardless of payload size.  Unit and outcome
+words are bit-identical to :mod:`repro.kernels.unpack_bits.ref` at
+every offset up to the payload's bit count.
 """
 
 from __future__ import annotations
@@ -137,63 +135,73 @@ def unit_words_pallas(params: jnp.ndarray, win: jnp.ndarray, *,
     )(params, win)
 
 
-def _gather(arr, idx):
-    """``arr[t, idx[t]]`` per tile row."""
-    return jnp.take_along_axis(arr, idx, axis=1)
+# Walk word: one int32 per offset carrying a chain's state,
+#   bits 21..31  rel   offset of the chain's current unit, less the start
+#   bits 14..20  pos   coefficient positions covered before that unit
+#   bits  0..13  unit  that unit's word, ((ctrl + 2) << 5) | adv
+# A step adds the start unit's own hop and positions to the word read at
+# its end; rel stays under 64 * MAX_ADV = 1984 < 2**11 and pos under
+# 63 + 16 = 79 < 2**7, so no field carries into the next.
+_REL_SHIFT, _POS_SHIFT, _POS_MASK = 21, 14, 0x7F
+MAX_ADV = 16 + _ref.MAX_CATEGORY      # 16-bit code + 15-bit amplitude
+STEPS = 64                            # AC units a block may hold
 
 
-@functools.partial(jax.jit, static_argnames=("n_tiles", "tile_bits",
-                                             "window"))
-def stage_tiles(dc_words: jnp.ndarray, ac_words: jnp.ndarray, *,
-                n_tiles: int, tile_bits: int, window: int) -> tuple:
-    """Cut flat unit words into tile windows and resolve AC outcomes.
+def _outcome(y, pos):
+    """Outcome words (``ref`` layout) from final walk words at ``pos``."""
+    p = pos + jax.lax.shift_right_logical(y, _REL_SHIFT)
+    s = (y >> _POS_SHIFT) & _POS_MASK
+    ctrl = ((y >> 5) & 0x1FF) - 2
+    end = (p + (y & 0x1F)) << 2
+    # a ZRL may overshoot 63 freely; a coefficient landing past the
+    # last column (position 62) is the reference's "overruns block"
+    overrun = (ctrl > 0) & (ctrl != _ZRL) & (s + (ctrl >> 4) + 1 >= 64)
+    return jnp.where(ctrl == -1, (p << 2) | 1,
+                     jnp.where(ctrl == -2, (p << 2) | 2,
+                               jnp.where(overrun, 3, end)))
+
+
+@jax.jit
+def stage_tiles(dc_words: jnp.ndarray, ac_words: jnp.ndarray) -> tuple:
+    """Resolve the AC chain outcome of every payload bit offset.
+
+    A bounded forward walk over the whole payload: 64 steps, each of
+    which moves every offset's chain one unit on by reading the walk
+    word ``hop <= MAX_ADV`` offsets ahead (a select over 31 static
+    shifts, no gather), and keeps the move only while fewer than 63
+    positions lie behind the chain's unit.  A chain so stops on a
+    terminal or on the unit that reaches position 63, and the outcome
+    is read off that unit.
 
     Args:
-        dc_words, ac_words: flat int32 unit words covering at least
-            ``n_tiles * tile_bits + window`` offsets.
-        n_tiles: tiles to stage (static via the jit cache key).
-        tile_bits: bit offsets resolved per tile.
-        window: offsets staged per tile; must cover ``tile_bits +
-            MARGIN_BITS`` so chains starting in the tile finish inside.
+        dc_words, ac_words: unit words of ``unit_words_pallas`` for
+            ``n >= nbits + 1 + MAX_ADV`` offsets (any shape; read
+            flat).  Offsets past ``nbits`` must be terminal, as the
+            unit-word kernel makes them.
 
     Returns:
-        ``(dc_words, ac_words, outcomes)`` — (n_tiles, window) int32
-        arrays in the layouts documented in
-        :mod:`repro.kernels.unpack_bits.ref`.
+        ``(dc_words, ac_words, outcomes)`` — flat ``(n,)`` int32 in the
+        layouts of :mod:`repro.kernels.unpack_bits.ref`; outcomes equal
+        ``ref._ac_outcomes`` over the whole payload at every offset up
+        to ``nbits``.
     """
-    if window < tile_bits + _ref.MARGIN_BITS:
-        raise ValueError(f"window {window} cannot cover a {tile_bits}-bit "
-                         f"tile (needs >= tile_bits + {_ref.MARGIN_BITS})")
-    t0 = jnp.arange(n_tiles, dtype=jnp.int32)[:, None] * tile_bits
-    idx = jax.lax.broadcasted_iota(jnp.int32, (n_tiles, window), 1)
-    dcw = dc_words[t0 + idx]
-    acw = ac_words[t0 + idx]
-
+    acw = ac_words.reshape(-1)
+    n = acw.shape[0]
     ctrl = (acw >> 6) - 2
     adv = acw & 0x3F
-    term = ctrl <= 0
-    d0 = jnp.where(term, 0, (ctrl >> 4) + 1)
-    j0 = jnp.where(term, idx, jnp.minimum(idx + adv, window - 1))
-    levels = []
-    J, S = j0, d0
-    for _ in range(6):
-        levels.append((J, S))
-        S = S + _gather(S, J)
-        J = _gather(J, J)
-    t_ctrl = _gather(ctrl, J)
-    t_end = t0 + J + _gather(adv, J)
-    t_out = jnp.where(
-        t_ctrl == 0, t_end << 2,
-        jnp.where(t_ctrl == -1, ((t0 + J) << 2) | 1,
-                  ((t0 + J) << 2) | 2))
-    cur, s = idx, jnp.zeros((n_tiles, window), jnp.int32)
-    for Jk, Sk in reversed(levels):
-        ns = s + _gather(Sk, cur)
-        take = ns < 63
-        s = jnp.where(take, ns, s)
-        cur = jnp.where(take, _gather(Jk, cur), cur)
-    c_ctrl = _gather(ctrl, cur)
-    c_run = jnp.where(c_ctrl > 0, c_ctrl >> 4, 0)
-    overrun = (c_ctrl != _ZRL) & (s + c_run + 1 >= 64)
-    c_out = jnp.where(overrun, 3, (t0 + cur + _gather(adv, cur)) << 2)
-    return dcw, acw, jnp.where(S < 63, t_out, c_out)
+    term = ctrl <= 0                  # EOB, invalid, truncated: absorb
+    hop = jnp.where(term, 0, adv)
+    inc = ((hop << _REL_SHIFT)
+           + (jnp.where(term, 0, (ctrl >> 4) + 1) << _POS_SHIFT))
+
+    def step(_, y):
+        ahead = jnp.concatenate([y, jnp.zeros(MAX_ADV, jnp.int32)])
+        at = y
+        for k in range(1, MAX_ADV + 1):
+            at = jnp.where(hop == k, ahead[k:k + n], at)
+        cand = at + inc
+        return jnp.where(((cand >> _POS_SHIFT) & _POS_MASK) < 63, cand, y)
+
+    y = jax.lax.fori_loop(0, STEPS, step, ((ctrl + 2) << 5) | adv)
+    return (dc_words.reshape(-1), acw,
+            _outcome(y, jnp.arange(n, dtype=jnp.int32)))

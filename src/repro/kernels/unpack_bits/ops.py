@@ -18,23 +18,18 @@ import numpy as np
 
 from repro import obs
 from repro.core.entropy import bitio, huffman
-from repro.kernels import tuning
 from repro.kernels.unpack_bits import kernel, ref
-
-TILE_BITS = 2048                    # default bit offsets resolved per program
-WINDOW = TILE_BITS + ref.MARGIN_BITS
 
 # Above this many payload bits the stream decodes with the NumPy
 # reference.  VMEM does not bound it: the unit-word kernel streams
 # (16, 128) int32 blocks of bit windows (8 KiB in, 16 KiB out per
-# program) whatever the payload size.  The staged tile windows —
-# three (n_tiles, window) int32 arrays, pulled to the host for the
-# chain resolution — are what grows, up to 48 B per payload bit after
-# pow2 tile bucketing.  Compiled for a TPU v5e at 2**20 bits (a
-# ~128 KB payload; 1024 tiles of 4096 offsets), ``memory_analysis()``
-# gives the unit-word kernel 8,409,088 B in and 16,810,496 B out, and
-# the staging program 50,332,160 B of outputs plus 303,049,728 B of
-# HBM temporaries for the pointer-doubling levels.
+# program) whatever the payload size, and the chain walk is element-wise
+# XLA.  The three flat int32 arrays the walk hands back for host
+# resolution are what grows, up to 24 B per payload bit after the pow2
+# bucketing.  Compiled for a TPU v5e at 2**20 bits (a ~128 KB payload;
+# 2**21 offsets), ``memory_analysis()`` gives the unit-word kernel
+# 8,392,704 B in and 16,777,728 B out, and the walk program 16,777,216 B
+# in, 25,166,336 B of outputs and 0 B of HBM temporaries.
 MAX_DEVICE_BITS = 1 << 20
 
 BACKENDS = ("pallas", "numpy")
@@ -71,11 +66,10 @@ def unpack_bits(payload: bytes, n_blocks: int,
         ac_table: (run, size) Huffman table.
         backend: "auto" (Pallas on TPU, NumPy elsewhere), "pallas", or
             "numpy".
-        tile_bits: bit offsets resolved per kernel program (pow2);
-            ``None`` routes through the tuned-tile artifact
-            (:func:`repro.kernels.tuning.tile_for`, falling back to
-            :data:`TILE_BITS`).  Ignored by "numpy".  The speculative
-            window is always ``tile_bits + ref.MARGIN_BITS``.
+        tile_bits: bit offsets per host resolver tile; ``None``
+            resolves the whole payload as one tile.  Ignored by
+            "numpy".  The device stage covers the whole payload either
+            way.
         interpret: Pallas interpret-mode override (None = interpret
             exactly when no TPU is present); ignored by "numpy".
 
@@ -152,12 +146,12 @@ def _unpack_device(payload: bytes, n_blocks: int,
                    tile_bits: int | None = None) -> tuple:
     """Host orchestration of the device speculative decode.
 
-    The kernel stages unit words for every bit offset and an XLA stage
-    cuts them into tile windows with their chain outcomes; chain
+    The kernel stages unit words for every bit offset and the walk
+    program their chain outcomes, over the whole payload; chain
     resolution and value emission are the shared O(1)-per-block host
-    stage (:func:`repro.kernels.unpack_bits.ref.resolve`).  Tile count
-    is bucketed to powers of two so a streaming workload sees a
-    bounded set of compiled shapes.
+    stage (:func:`repro.kernels.unpack_bits.ref.resolve`).  Offsets are
+    bucketed to powers of two so a streaming workload sees a bounded
+    set of compiled shapes.
     """
     from repro.kernels import common
     if interpret is None:
@@ -173,23 +167,21 @@ def _unpack_device(payload: bytes, n_blocks: int,
         with obs.route("unpack", "host", blocks=n_blocks):
             return ref.unpack_bits_ref(payload, n_blocks, dc_table,
                                        ac_table)
-    if tile_bits is None:
-        tile_bits = tuning.tile_for("unpack_bits", nbits)
     with obs.device_route("unpack", interpret, blocks=n_blocks):
         return _unpack_staged(payload, nbits, n_blocks, dc_table, ac_table,
                               interpret, tile_bits)
 
 
-def _unpack_staged(payload: bytes, nbits: int, n_blocks: int,
-                   dc_table: huffman.CanonicalTable,
-                   ac_table: huffman.CanonicalTable, interpret: bool,
-                   tile_bits: int) -> tuple:
-    """Stage, upload, launch, fetch the tiles, resolve the chain."""
-    window = tile_bits + ref.MARGIN_BITS
+def stage(payload: bytes, nbits: int, dc_table: huffman.CanonicalTable,
+          ac_table: huffman.CanonicalTable, interpret: bool) -> tuple:
+    """Upload, launch and fetch the device stage of one payload.
+
+    Returns ``(win, dc_words, ac_words, outcomes)``: the host's 16-bit
+    windows and flat int32 arrays covering offsets ``0 .. n - 1``,
+    ``n = max(pow2(nbits + 1 + MAX_ADV), 2048)``.
+    """
     win = bitio.bit_windows(payload)
-    n_tiles = _pow2(-(-(nbits + 1) // tile_bits))
-    block = kernel.ROWS * kernel.LANES
-    n_pad = -(-(n_tiles * tile_bits + window) // block) * block
+    n_pad = max(_pow2(nbits + 1 + kernel.MAX_ADV), kernel.ROWS * kernel.LANES)
     win_pad = np.full(n_pad, 0xFFFF, np.int32)
     win_pad[:win.size] = win
     dc_params, dc_syms = table_params(dc_table)
@@ -202,14 +194,24 @@ def _unpack_staged(payload: bytes, nbits: int, n_blocks: int,
     dcw, acw = kernel.unit_words_pallas(params_d, win_d,
                                         interpret=interpret)
     obs.launched("unpack", dcw)
-    staged = kernel.stage_tiles(dcw.reshape(-1), acw.reshape(-1),
-                                n_tiles=n_tiles, tile_bits=tile_bits,
-                                window=window)
+    staged = kernel.stage_tiles(dcw, acw)
     with obs.d2h(*staged):
-        dcw, acw, outc = jax.device_get(staged)
+        return (win,) + tuple(jax.device_get(staged))
+
+
+def _unpack_staged(payload: bytes, nbits: int, n_blocks: int,
+                   dc_table: huffman.CanonicalTable,
+                   ac_table: huffman.CanonicalTable, interpret: bool,
+                   tile_bits: int | None) -> tuple:
+    """Stage on the device, then resolve the chain on the host."""
+    win, dcw, acw, outc = stage(payload, nbits, dc_table, ac_table,
+                                interpret)
+    if tile_bits is None:
+        tile_bits = dcw.size            # one tile covers the payload
 
     def get_tile(t):
-        return dcw[t], acw[t], outc[t]
+        t0 = t * tile_bits              # outcomes hold absolute offsets
+        return dcw[t0:], acw[t0:], outc[t0:]
 
-    with obs.span("entropy.resolve"):
+    with obs.span("entropy.resolve", tiles=-(-(nbits + 1) // tile_bits)):
         return ref.resolve(win, nbits, n_blocks, tile_bits, get_tile)

@@ -77,6 +77,14 @@ def test_pool_thread_spans_carry_the_callers_call_id(tmp_path):
                           if name == "repro.entropy.encode_image"}
 
 
+def test_device_decode_resolves_one_tile_per_image(tmp_path):
+    blobs = eng.encode_batch(_batch(3), 50)
+    with jax.profiler.trace(str(tmp_path)):
+        eng.decode_batch(blobs, unpack_backend="pallas", workers=2)
+    assert [st["tiles"] for _, name, _, _, st in _spans(tmp_path)
+            if name == "repro.entropy.resolve"] == [1, 1, 1]
+
+
 def test_counters_are_exact_under_a_thread_pool():
     old = sys.getswitchinterval()
     sys.setswitchinterval(1e-6)

@@ -107,21 +107,17 @@ def test_pack_bits_compiles(shape):
 
 
 def test_unpack_bits_compiles(shape):
-    from repro.kernels.unpack_bits import kernel, ops, ref
-    tile_bits = ops.TILE_BITS
-    window = tile_bits + ref.MARGIN_BITS
-    n_tiles = ops._pow2(-(-(ops.MAX_DEVICE_BITS + 1) // tile_bits))
-    block = kernel.ROWS * kernel.LANES
-    n_pad = -(-(n_tiles * tile_bits + window) // block) * block
+    from repro.kernels.unpack_bits import kernel, ops
+    n = ops._pow2(ops.MAX_DEVICE_BITS + 1 + kernel.MAX_ADV)
     hlo = _compile(
         lambda p, w: kernel.unit_words_pallas(p, w, interpret=False),
-        shape((kernel.N_PARAMS,)), shape((n_pad // kernel.LANES,
-                                          kernel.LANES)))
+        shape((kernel.N_PARAMS,)), shape((n // kernel.LANES, kernel.LANES)))
     assert "tpu_custom_call" in hlo
-    _compile(
-        lambda d, a: kernel.stage_tiles(d, a, n_tiles=n_tiles,
-                                        tile_bits=tile_bits, window=window),
-        shape((n_pad,)), shape((n_pad,)))
+    words = shape((n // kernel.LANES, kernel.LANES))
+    hlo = _compile(
+        lambda d, a: kernel.stage_tiles(d, a), words, words)
+    # the chain walk reads its words at static shifts: no gather
+    assert " gather(" not in hlo
 
 
 def test_engine_compress_compiles(shape):

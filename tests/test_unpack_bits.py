@@ -196,6 +196,175 @@ class TestUnpackBitsKernel:
             3 * rle.walk_table_nbytes(1 << 22)
 
 
+def _hand_stream(blocks, dc_t=huffman.STANDARD_DC_LUMA,
+                 ac_t=huffman.STANDARD_AC_LUMA):
+    """Pack blocks given as symbol lists — ``(dc_category, dc_bits,
+    [(ac_symbol, amplitude_bits), ...])`` — verbatim, so streams the
+    encoder never writes (a ZRL past position 63, a run overrunning the
+    block) can be built."""
+    fields, widths = [], []
+
+    def put(table, sym, bits, size):
+        code_of, len_of = table.encoder_luts()
+        fields.append(code_of[sym])
+        widths.append(len_of[sym])
+        if size:
+            fields.append(bits)
+            widths.append(size)
+
+    for cat, bits, units in blocks:
+        put(dc_t, cat, bits, cat)
+        for sym, amp in units:
+            put(ac_t, sym, amp, sym & 0xF if sym != rle.ZRL else 0)
+    return bitio.pack_bits(np.asarray(fields), np.asarray(widths))
+
+
+def _stage_streams():
+    """Named payloads for the device stage: real encodes at the chain
+    walk's extremes, and malformed streams."""
+    dc_t, ac_t = huffman.STANDARD_DC_LUMA, huffman.STANDARD_AC_LUMA
+    rng = np.random.default_rng(11)
+    # a lone coefficient at column 62: three ZRLs and a run-14 symbol
+    zrl = np.zeros((20, 63), np.int64)
+    zrl[:, 62] = 7
+    # 63 run-0 coefficients: the walk's 63-unit worst case
+    dense = rng.integers(1, 500, (6, 63))
+    wide = np.zeros((8, 63), np.int64)
+    wide[:, rng.choice(63, 8, replace=False)] = 32767
+    wide[:, 0] = -32767
+    random_dc, random_ac = _random_blocks(rng, 60)
+    valid = _encode(random_dc, random_ac)[0]
+    return {
+        "zrl_chains": _encode(np.zeros(20), zrl, std_tables=False),
+        # four ZRLs cover 64 positions: the chain crosses 63 on a ZRL
+        "zrl_overshoot": (_hand_stream([(0, 0, [(rle.ZRL, 0)] * 4)] * 5),
+                          dc_t, ac_t),
+        "run_overruns_block": (_hand_stream(
+            [(0, 0, [(rle.ZRL, 0)] * 3 + [(0xF1, 1)])] * 3), dc_t, ac_t),
+        "dense_63_units": _encode(rng.integers(-500, 500, 6), dense),
+        "category_15": _encode(rng.choice([-32767, 32767], 8), wide,
+                               std_tables=False),
+        "truncated": (valid[:len(valid) // 2 + 1], dc_t, ac_t),
+        "invalid_prefixes": (rng.integers(0, 256, 300, np.uint8).tobytes(),
+                             dc_t, ac_t),
+        "all_ones": (b"\xff" * 64, dc_t, ac_t),
+    }
+
+
+STAGE_STREAMS = _stage_streams()
+
+
+class TestUnpackStage:
+    """The device stage (unit words and the chain walk over the whole
+    payload) against the NumPy stage of one tile covering it."""
+
+    @staticmethod
+    def _check(payload, dc_t, ac_t):
+        from repro.kernels.unpack_bits import ops
+        nbits = len(payload) * 8
+        win, dcw, acw, outc = ops.stage(payload, nbits, dc_t, ac_t,
+                                        interpret=True)
+        assert dcw.size >= nbits + 1 + unpack_bits.kernel.MAX_ADV
+        want = [unpack_ref._unit_words(win, nbits, 0, nbits + 1,
+                                       *huffman.decoder_luts(t))
+                for t in (dc_t, ac_t)]
+        np.testing.assert_array_equal(dcw[:nbits + 1], want[0])
+        np.testing.assert_array_equal(acw[:nbits + 1], want[1])
+        np.testing.assert_array_equal(
+            outc[:nbits + 1], unpack_ref._ac_outcomes(want[1], 0))
+        return outc[:nbits + 1], dcw.size
+
+    @given(st.integers(0, 2**31 - 1))
+    @settings(max_examples=6, deadline=None)
+    def test_random_streams(self, seed):
+        rng = np.random.default_rng(seed)
+        n = int(rng.integers(1, 120))
+        dc, ac = _random_blocks(rng, n)
+        self._check(*_encode(dc, ac, std_tables=bool(n % 2)))
+
+    @given(st.binary(min_size=1, max_size=200))
+    @settings(max_examples=6, deadline=None)
+    def test_random_bytes(self, payload):
+        self._check(payload, huffman.STANDARD_DC_LUMA,
+                    huffman.STANDARD_AC_LUMA)
+
+    @pytest.mark.parametrize("name", sorted(STAGE_STREAMS))
+    def test_named_streams(self, name):
+        outc, _ = self._check(*STAGE_STREAMS[name])
+        kind = {"zrl_overshoot": 0, "run_overruns_block": 3}.get(name)
+        if kind is not None:
+            # the first block's AC chain (after a 2-bit DC category 0)
+            # crosses position 63 as built
+            assert outc[2] & 3 == kind
+
+    @pytest.mark.parametrize("nbytes", [252, 253])
+    def test_payload_at_the_pow2_edge(self, nbytes):
+        # 252 bytes fill the 2048 staged offsets exactly (2016 bits + the
+        # end slot + 31 bits of reach); 253 bytes need the next bucket
+        payload = np.random.default_rng(nbytes).integers(
+            0, 256, nbytes, np.uint8).tobytes()
+        _, staged = self._check(payload, huffman.STANDARD_DC_LUMA,
+                                huffman.STANDARD_AC_LUMA)
+        assert staged == (2048 if nbytes == 252 else 4096)
+
+
+class TestUnpackOneTile:
+    """``tile_bits=None`` (the engine's route): the payload resolves as
+    one tile, with the reference's values and errors."""
+
+    @staticmethod
+    def _result(fn):
+        try:
+            dc, ac = fn()
+            return ("ok", dc.tobytes(), ac.tobytes())
+        except (bitio.TruncatedStream, ValueError) as e:
+            return (type(e).__name__, str(e))
+
+    @pytest.mark.parametrize("name", sorted(STAGE_STREAMS))
+    @pytest.mark.parametrize("n_blocks", [1, 5, 40])
+    def test_identical_to_reference(self, name, n_blocks):
+        payload, dc_t, ac_t = STAGE_STREAMS[name]
+        got = self._result(lambda: unpack_bits.unpack_bits(
+            payload, n_blocks, dc_t, ac_t, backend="pallas",
+            interpret=True))
+        oracle = self._result(lambda: rle.decode_payload_reference(
+            payload, n_blocks, dc_t, ac_t))
+        # values, and whether the stream is rejected, match the scalar
+        # oracle; the error and its bit offset match the LUT walk, whose
+        # contract names truncation first where a unit both runs past
+        # the payload and breaks another rule
+        assert (got[0] == "ok") == (oracle[0] == "ok")
+        if oracle[0] == "ok":
+            assert got == oracle
+        assert got == self._result(lambda: rle.decode_payload(
+            payload, n_blocks, dc_t, ac_t))
+
+    def test_resolves_one_tile(self, monkeypatch):
+        from repro.kernels.unpack_bits import ops
+        seen = []
+        real = unpack_ref.resolve
+
+        def spy(win, nbits, n_blocks, tile_bits, get_tile):
+            def counted(t):
+                seen.append(t)
+                return get_tile(t)
+            return real(win, nbits, n_blocks, tile_bits, counted)
+
+        monkeypatch.setattr(ops.ref, "resolve", spy)
+        rng = np.random.default_rng(13)
+        dc, ac = _random_blocks(rng, 80)
+        payload, dc_t, ac_t = _encode(dc, ac)
+        want = rle.decode_payload_reference(payload, 80, dc_t, ac_t)
+        got = unpack_bits.unpack_bits(payload, 80, dc_t, ac_t,
+                                      backend="pallas", interpret=True)
+        np.testing.assert_array_equal(got[1], want[1])
+        assert seen == [0]
+        seen.clear()
+        unpack_bits.unpack_bits(payload, 80, dc_t, ac_t, backend="pallas",
+                                tile_bits=256, interpret=True)
+        assert len(set(seen)) > 1
+
+
 class TestUnpackThroughContainer:
     def test_golden_fixtures_identical_across_backends(self):
         from repro.core import entropy
