@@ -1,6 +1,6 @@
 """Smoke run of the codec on one TPU chip, through its user entry points.
 
-    python chip_smoke.py             # one chip: phases 0-3
+    python chip_smoke.py             # one chip: phases 0-4
     python chip_smoke.py --chips 4   # the engine's 4-device sharded path
 
 Phases (one process, in order; any failure raises and exits non-zero):
@@ -14,7 +14,11 @@ Phases (one process, in order; any failure raises and exits non-zero):
    and decodes back to them exactly; the route every entropy stage took
    is read from the program's counters (``repro.obs.counts()``);
 3. one ``CodecService`` serving 32 ragged requests at two quality
-   tiers, every payload byte-identical to serial ``encode_batch``.
+   tiers, every payload byte-identical to serial ``encode_batch``;
+4. colour: ``encode_batch`` / ``decode_batch`` of 768x512 RGB images as
+   ``DCTZ`` version-3 YCbCr 4:2:0 streams, checked against the float64
+   colour reference (``perfbench/reference_colour.py``: levels, its
+   independent decoder, RGB) and the route counters.
 
 The last line of standard output is one JSON object naming the device.
 """
@@ -198,7 +202,7 @@ def phase_bytes() -> None:
         cb = eng.compress_batch(stacked, QUALITY)
         levels = cb._image_qcoeffs()
         ref_recs = eng.decompress_batch(cb)
-        for i, (blob, (q, shape)) in enumerate(zip(blobs, levels)):
+        for i, (blob, (q, shape, _)) in enumerate(zip(blobs, levels)):
             host = entropy.encode_qcoeffs(q, QUALITY, "exact", shape,
                                           packer=None, symbolizer=None)
             check(blob == host, f"{name} image {i}: bytes differ from the "
@@ -257,6 +261,49 @@ def phase_service() -> None:
         want = eng.encode_batch([im], r.quality)[0]
         check(r.payload == want, f"request {i}: payload differs from "
               f"serial encode_batch")
+
+
+COLOUR_QUALITY = 75
+COLOUR_TOL = 1e-3    # float32 round-off, in levels and in pixel values
+
+
+def phase_colour() -> None:
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
+    from perfbench import colour
+    from perfbench import reference_colour as rc
+    from repro.serve import codec_engine as eng
+
+    def gap(x, n):
+        return float(np.maximum(0.0, np.abs(x - n) - 0.5).max())
+    imgs = np.stack([colour.colour_image(g, 512, 768, seed=40 + i)
+                     for i, g in enumerate(["lena_like", "cablecar_like"])])
+    for rnd in ("first call, compile included", "second call"):
+        t0 = time.perf_counter()
+        blobs, enc = counted(eng.encode_batch, imgs, COLOUR_QUALITY)
+        t_enc = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        recs, dec = counted(eng.decode_batch, blobs)
+        recs = [np.asarray(r) for r in recs]
+        t_dec = time.perf_counter() - t0
+        took = {stage: routes(enc if stage != "unpack" else dec, stage)
+                for stage in ("symbolize", "pack", "unpack")}
+        print(f"   2 x 768x512 RGB ({rnd}): encode_batch {t_enc:.2f} s, "
+              f"decode_batch {t_dec:.2f} s; images per route {took}")
+    check(took["unpack"] == {"pallas": len(imgs)},
+          f"colour unpack took {took['unpack']}, not the compiled kernel")
+    check(enc.get("engine.images.colour.encoded") == len(imgs) and
+          dec.get("engine.images.colour.decoded") == len(imgs),
+          "the colour counters did not count every image")
+    for i, (im, blob, rec) in enumerate(zip(imgs, blobs, recs)):
+        hdr, levels = rc.parse_dctz3(blob)
+        lg = max(gap(x, n) for x, n in zip(
+            rc.unrounded_levels(im, COLOUR_QUALITY), levels))
+        check(rec.shape == (512, 768, 3), f"image {i}: shape {rec.shape}")
+        pg = gap(rc.unrounded_rgb(levels, COLOUR_QUALITY)[:512, :768], rec)
+        print(f"     image {i}: {len(blob)} B, level gap {lg:.3g}, pixel "
+              f"gap {pg:.3g}")
+        check(lg <= COLOUR_TOL and pg <= COLOUR_TOL,
+              f"image {i}: the colour path departs from the reference")
 
 
 def phase_four_chips() -> None:
@@ -331,6 +378,8 @@ def main() -> int:
             phase_bytes()
         with Phase("3 service"):
             phase_service()
+        with Phase("4 colour, YCbCr 4:2:0 encode and decode"):
+            phase_colour()
     interp = sorted(k for k in obs.counts() if k.endswith(".interpret"))
     check(not interp, f"kernels ran in interpret mode: {interp}")
     print(f"total {time.perf_counter() - t0:.1f} s, of which "

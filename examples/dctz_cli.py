@@ -165,11 +165,14 @@ def cmd_info(args) -> int:
     except (entropy.BitstreamError, entropy.TruncatedStream) as exc:
         return _stream_error(args.input, exc)
     px = hdr["height"] * hdr["width"]
+    pairs = hdr.get("table_ids",
+                    ((hdr.get("dc_table_id"), hdr.get("ac_table_id")),))
+    tables = " ".join(f"(dc:{_table_desc(dc)},ac:{_table_desc(ac)})"
+                      for dc, ac in pairs)
     print(f"{args.input}: DCTZ v{hdr['version']} "
           f"{hdr['height']}x{hdr['width']} quality={hdr['quality']} "
           f"transform={hdr['transform']} "
-          f"tables=(dc:{_table_desc(hdr['dc_table_id'])},"
-          f"ac:{_table_desc(hdr['ac_table_id'])}) "
+          f"{'colour ' if len(pairs) > 1 else ''}tables={tables} "
           f"crc={'ok' if crc_ok else 'MISMATCH'} "
           f"payload={hdr['payload_nbytes']}B "
           f"total={len(data)}B ({len(data) * 8 / px:.3f} bits/px)")

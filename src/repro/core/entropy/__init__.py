@@ -15,8 +15,9 @@ bytes, not the :func:`repro.core.quant.estimate_bits` proxy:
   retained reference the routed :mod:`repro.kernels.pack_bits` backend
   is gated against),
 * :mod:`container` — the versioned ``DCTZ`` container (magic, version,
-  shape, quality, transform, table ids, CRC) with
-  :func:`encode_image` / :func:`decode_image`.
+  shape, quality, transform, table ids, CRC; version 3 adds colour
+  components and two table classes) with :func:`encode_image` /
+  :func:`decode_image`.
 
 The encode path is a staged pipeline — symbolize -> table choice ->
 codeword lookup -> prefix-sum offsets -> scatter-pack — whose packing
@@ -33,12 +34,14 @@ which is what makes the engine's process-pool decode fallback cheap.
 from repro.core.entropy.bitio import TruncatedStream
 from repro.core.entropy.container import (BitstreamError, decode_image,
                                           decode_qcoeffs,
-                                          decode_zigzag_host, encode_image,
-                                          encode_qcoeffs,
+                                          decode_zigzag_host,
+                                          encode_colour_zigzag_host,
+                                          encode_image, encode_qcoeffs,
                                           encode_zigzag_host, read_header,
-                                          verify_crc)
+                                          stream_layout, verify_crc)
 
 __all__ = ["BitstreamError", "TruncatedStream", "decode_image",
-           "decode_qcoeffs", "decode_zigzag_host", "encode_image",
-           "encode_qcoeffs", "encode_zigzag_host", "read_header",
+           "decode_qcoeffs", "decode_zigzag_host",
+           "encode_colour_zigzag_host", "encode_image", "encode_qcoeffs",
+           "encode_zigzag_host", "read_header", "stream_layout",
            "verify_crc"]

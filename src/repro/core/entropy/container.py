@@ -28,6 +28,17 @@ streams stay byte-identical to version 1.  Decoders reject unknown
 magic/version/transform/table ids and trailing bytes; within a version
 the format evolves by replacement, not extension.
 
+Version 3 is a **colour** stream: baseline YCbCr 4:2:0
+(:mod:`repro.core.colour`).  Bytes 16 and 17 hold the component count
+(3) and the table-class count (2); after the 28-byte header come one
+4-byte record per component (id, sampling ``H << 4 | V``, quantisation
+class, table class: Y ``1, 0x22, 0, 0``, Cb ``2, 0x11, 1, 1``, Cr ``3,
+0x11, 1, 1``), then the (DC id, AC id) pair of each table class, then
+the embedded segments (class 0 DC, class 0 AC, class 1 DC, class 1 AC,
+each only where its id is 0), then the payload: MCUs in raster order,
+each ``Y00 Y01 Y10 Y11 Cb Cr``, DC predicted per component.  The CRC
+covers the records and ids like the tables.
+
 This module is importable without jax: the host halves
 (:func:`encode_zigzag_host` / :func:`decode_zigzag_host`) are pure
 NumPy so process-pool workers (``codec_engine.decode_batch``) don't pay
@@ -48,14 +59,27 @@ from repro.core.entropy import bitio, huffman, rle
 MAGIC = b"DCTZ"
 VERSION_EMBEDDED = 1        # both tables embedded (the v1 layout)
 VERSION_SHARED = 2          # at least one shared table id
-SUPPORTED_VERSIONS = (VERSION_EMBEDDED, VERSION_SHARED)
-VERSION = VERSION_SHARED    # newest version this module writes/reads
+VERSION_COLOUR = 3          # YCbCr 4:2:0, two table classes
+SUPPORTED_VERSIONS = (VERSION_EMBEDDED, VERSION_SHARED, VERSION_COLOUR)
+VERSION = VERSION_COLOUR    # newest version this module writes/reads
 TABLE_EMBEDDED = 0
 
 TABLE_MODES = ("auto", "embedded", "shared")
 
 _HEADER = struct.Struct("<4sBBBBIIBBHII")
 HEADER_NBYTES = _HEADER.size            # 28
+
+# version 3: (component id, H << 4 | V, quantisation class, table class)
+COLOUR_COMPONENTS = ((1, 0x22, 0, 0), (2, 0x11, 1, 1), (3, 0x11, 1, 1))
+COLOUR_TABLE_CLASSES = 2
+# the per-component records and the (DC id, AC id) pair of each class
+_COLOUR_LAYOUT = bytes(b for c in COLOUR_COMPONENTS for b in c)
+COLOUR_HEADER_NBYTES = (HEADER_NBYTES + len(_COLOUR_LAYOUT)
+                        + 2 * COLOUR_TABLE_CLASSES)     # 44
+# per block of an MCU (Y00 Y01 Y10 Y11 Cb Cr): component and table class
+COLOUR_BLOCK_COMPONENTS = (0, 0, 0, 0, 1, 2)
+COLOUR_BLOCK_CLASSES = tuple(COLOUR_COMPONENTS[c][3]
+                             for c in COLOUR_BLOCK_COMPONENTS)
 
 TRANSFORM_CODES = {"exact": 0, "cordic": 1, "loeffler": 2}
 _TRANSFORM_NAMES = {v: k for k, v in TRANSFORM_CODES.items()}
@@ -187,6 +211,96 @@ def encode_zigzag_host(z: np.ndarray, quality: int, transform: str,
                          symbolizer=symbolizer)
 
 
+def _colour_grid(height: int, width: int) -> tuple:
+    """(MCU rows, MCU columns) of a colour image: 16x16 MCUs."""
+    return (height + 15) // 16, (width + 15) // 16
+
+
+def _component_index(n_blocks: int) -> list:
+    """Block indices of each component of an interleaved colour stream."""
+    comp = rle.block_classes(COLOUR_BLOCK_COMPONENTS, n_blocks)
+    return [np.flatnonzero(comp == c) for c in range(len(COLOUR_COMPONENTS))]
+
+
+def colour_dc_diff(dc: np.ndarray) -> np.ndarray:
+    """(n,) DC levels of an interleaved colour stream -> DC differences,
+    each block predicted from the previous block of its own component
+    (0 for a component's first block)."""
+    dc = np.asarray(dc, np.int64)
+    out = np.empty_like(dc)
+    for idx in _component_index(dc.size):
+        out[idx] = np.diff(dc[idx], prepend=np.int64(0))
+    return out
+
+
+def colour_dc_integrate(dc_diff: np.ndarray) -> np.ndarray:
+    """Invert :func:`colour_dc_diff`: (n,) differences -> (n,) DC levels."""
+    dc_diff = np.asarray(dc_diff, np.int64)
+    out = np.empty_like(dc_diff)
+    for idx in _component_index(dc_diff.size):
+        out[idx] = np.cumsum(dc_diff[idx])
+    return out
+
+
+def encode_colour_zigzag_host(z: np.ndarray, quality: int, transform: str,
+                              orig_shape: tuple, *, tables: str = "auto",
+                              packer=None, symbolizer=None) -> bytes:
+    """Entropy-code one colour image's interleaved zig-zag stream into a
+    ``DCTZ`` version-3 stream — pure host path.
+
+    Args:
+        z: (mh*mw*6, 64) int zig-zag levels, MCUs in raster order, each
+            ``Y00 Y01 Y10 Y11 Cb Cr`` (as produced by
+            :func:`repro.core.colour.compress_batch_mcus`).
+        quality: JPEG quality factor in [1, 100].
+        transform: encoder transform name (see :data:`TRANSFORM_CODES`).
+        orig_shape: (H, W) of the image before MCU padding.
+        tables: Huffman table policy, as in :func:`encode_qcoeffs`,
+            applied per table class (luma: shared ids 1 and 2; chroma:
+            shared ids 3 and 4).
+        packer: bit-packing backend override, as in
+            :func:`encode_qcoeffs`.
+        symbolizer: symbolisation backend override, as in
+            :func:`encode_qcoeffs`; called with ``classes=``.
+
+    Returns:
+        The complete container as bytes.
+
+    Raises:
+        ValueError: shape/quality/transform/tables out of range, or a
+            level too large for a 15-bit amplitude.
+    """
+    h, w = int(orig_shape[0]), int(orig_shape[1])
+    _check_encode_args(quality, transform, tables)
+    mh, mw = _colour_grid(h, w)
+    z = np.asarray(z)
+    n = mh * mw * len(COLOUR_BLOCK_CLASSES)
+    if z.shape != (n, 64):
+        raise ValueError(f"zig-zag stream shape {z.shape} does not match "
+                         f"the {mh}x{mw} MCU grid of a {h}x{w} image")
+    classes = COLOUR_BLOCK_CLASSES
+    prep = (symbolizer or rle.prepare_stream)(
+        colour_dc_diff(z[:, 0]), z[:, 1:], packer=packer, classes=classes)
+    with obs.span("entropy.tables"):
+        chosen = [(_choose_table(prep.dc_freq[c], dc_sid, tables, "DC"),
+                   _choose_table(prep.ac_freq[c], ac_sid, tables, "AC"))
+                  for c, (dc_sid, ac_sid) in enumerate(huffman.STANDARD_IDS)]
+    with obs.span("entropy.payload"):
+        payload = prep.payload(tuple(dc[1] for dc, _ in chosen),
+                               tuple(ac[1] for _, ac in chosen))
+    with obs.span("entropy.frame"):
+        ids = bytes(i for dc, ac in chosen for i in (dc[0], ac[0]))
+        segs = b"".join(t.to_segment() for pair in chosen
+                        for tid, t in pair if tid == TABLE_EMBEDDED)
+        header = _HEADER.pack(MAGIC, VERSION_COLOUR, 0, int(quality),
+                              TRANSFORM_CODES[transform], h, w,
+                              len(COLOUR_COMPONENTS), COLOUR_TABLE_CLASSES,
+                              0, len(payload), 0)
+        body = _COLOUR_LAYOUT + ids + segs + payload
+        crc = zlib.crc32(header[4:24] + body) & 0xFFFFFFFF
+        return header[:24] + struct.pack("<I", crc) + body
+
+
 def _choose_table(freqs: np.ndarray, shared_id: int, tables: str,
                   what: str) -> tuple:
     """Pick (table_id, table) for one alphabet under the table policy.
@@ -276,13 +390,18 @@ def read_header(data: bytes) -> dict:
     Returns:
         Dict with ``version``, ``quality``, ``transform``, ``height``,
         ``width``, ``dc_table_id``, ``ac_table_id``, ``payload_nbytes``,
-        ``crc32``.
+        ``crc32``.  A version-3 (colour) stream, whose component records
+        and table ids follow the fixed header, gives ``components`` (3)
+        and ``table_ids`` (one (DC id, AC id) pair per table class) in
+        place of the two table ids.
 
     Raises:
         BitstreamError: short data, bad magic, unsupported version,
             or any field outside its valid range — including a table id
             the version does not define (version 1 allows only
-            embedded; version 2 also allows registered shared ids).
+            embedded; versions 2 and 3 also allow registered shared
+            ids) and a version-3 component layout other than baseline
+            YCbCr 4:2:0.
     """
     if len(data) < HEADER_NBYTES:
         raise BitstreamError(
@@ -305,6 +424,35 @@ def read_header(data: bytes) -> dict:
         raise BitstreamError(f"quality {quality} outside [1, 100]")
     if height == 0 or width == 0:
         raise BitstreamError("zero image dimension")
+    hdr = {"version": version, "quality": quality,
+           "transform": _TRANSFORM_NAMES[tcode],
+           "height": height, "width": width}
+    if version == VERSION_COLOUR:
+        if (dc_id, ac_id) != (len(COLOUR_COMPONENTS), COLOUR_TABLE_CLASSES):
+            raise BitstreamError(
+                f"unsupported colour layout: {dc_id} components in {ac_id} "
+                f"table classes (version {VERSION_COLOUR} codes 3 in 2)")
+        if len(data) < COLOUR_HEADER_NBYTES:
+            raise BitstreamError(
+                f"truncated header: got {len(data)} bytes, need "
+                f"{COLOUR_HEADER_NBYTES}")
+        if data[HEADER_NBYTES:HEADER_NBYTES + len(_COLOUR_LAYOUT)] != \
+                _COLOUR_LAYOUT:
+            raise BitstreamError(
+                "unsupported component records: version 3 codes baseline "
+                "YCbCr 4:2:0 (Y 2x2 luma class, Cb and Cr 1x1 chroma)")
+        ids = data[COLOUR_HEADER_NBYTES - 2 * COLOUR_TABLE_CLASSES:
+                   COLOUR_HEADER_NBYTES]
+        for tid in ids:
+            if tid != TABLE_EMBEDDED and not huffman.DEFAULT_TABLES.known(
+                    tid):
+                raise BitstreamError(
+                    f"unknown table ids {tuple(ids)}; version 3 defines "
+                    f"embedded (id 0) and registered shared ids "
+                    f"{huffman.DEFAULT_TABLES.ids()}")
+        return dict(hdr, components=len(COLOUR_COMPONENTS),
+                    table_ids=((ids[0], ids[1]), (ids[2], ids[3])),
+                    payload_nbytes=payload_nbytes, crc32=crc)
     for tid in (dc_id, ac_id):
         if tid == TABLE_EMBEDDED:
             continue
@@ -318,30 +466,33 @@ def read_header(data: bytes) -> dict:
                 f"unknown table ids ({dc_id}, {ac_id}); version "
                 f"{VERSION_SHARED} defines embedded (id 0) and "
                 f"registered shared ids {huffman.DEFAULT_TABLES.ids()}")
-    return {"version": version, "quality": quality,
-            "transform": _TRANSFORM_NAMES[tcode],
-            "height": height, "width": width,
-            "dc_table_id": dc_id, "ac_table_id": ac_id,
-            "payload_nbytes": payload_nbytes, "crc32": crc}
+    return dict(hdr, dc_table_id=dc_id, ac_table_id=ac_id,
+                payload_nbytes=payload_nbytes, crc32=crc)
 
 
 def _resolve_tables(data: bytes, hdr: dict) -> tuple:
     """(dc_table, ac_table, payload_offset): embedded segments are
     parsed from the stream (DC first), shared ids resolve through the
-    default registry (``read_header`` already vetted the ids)."""
-    off = HEADER_NBYTES
+    default registry (``read_header`` already vetted the ids).  For a
+    colour stream the two tables are tuples, one table per class."""
+    if hdr["version"] == VERSION_COLOUR:
+        ids, off = hdr["table_ids"], COLOUR_HEADER_NBYTES
+    else:
+        ids, off = ((hdr["dc_table_id"], hdr["ac_table_id"]),), \
+            HEADER_NBYTES
+    out = []
     try:
-        if hdr["dc_table_id"] == TABLE_EMBEDDED:
-            dc_table, off = huffman.CanonicalTable.from_segment(data, off)
-        else:
-            dc_table = huffman.DEFAULT_TABLES.get(hdr["dc_table_id"])
-        if hdr["ac_table_id"] == TABLE_EMBEDDED:
-            ac_table, off = huffman.CanonicalTable.from_segment(data, off)
-        else:
-            ac_table = huffman.DEFAULT_TABLES.get(hdr["ac_table_id"])
+        for tid in (t for pair in ids for t in pair):
+            if tid == TABLE_EMBEDDED:
+                table, off = huffman.CanonicalTable.from_segment(data, off)
+            else:
+                table = huffman.DEFAULT_TABLES.get(tid)
+            out.append(table)
     except huffman.InvalidTable as e:
         raise BitstreamError(f"bad embedded Huffman table: {e}") from e
-    return dc_table, ac_table, off
+    if hdr["version"] == VERSION_COLOUR:
+        return tuple(out[0::2]), tuple(out[1::2]), off
+    return out[0], out[1], off
 
 
 def verify_crc(data: bytes) -> bool:
@@ -382,7 +533,7 @@ def decode_zigzag_host(data: bytes, *, unpacker=None) -> tuple:
     zig-zag permutation is left for the device.
 
     Args:
-        data: one complete ``DCTZ`` stream (version 1 or 2).
+        data: one complete ``DCTZ`` stream (version 1, 2 or 3).
         unpacker: optional payload-decode backend handed through to
             :func:`repro.core.entropy.rle.decode_payload` — e.g. the
             routed :func:`repro.kernels.unpack_bits.unpack_bits` for a
@@ -391,7 +542,8 @@ def decode_zigzag_host(data: bytes, *, unpacker=None) -> tuple:
 
     Returns:
         ``(z, header)``: the (gh*gw, 64) int32 zig-zag stream in raster
-        block order and the parsed header dict.
+        block order and the parsed header dict; for a colour stream the
+        (mh*mw*6, 64) interleaved stream, MCUs in raster order.
 
     Raises:
         BitstreamError: any malformation — truncation (header, tables or
@@ -415,28 +567,51 @@ def decode_zigzag_host(data: bytes, *, unpacker=None) -> tuple:
                 f"CRC mismatch: header says {hdr['crc32']:#010x}, stream "
                 f"hashes to {crc:#010x} (corrupted stream)")
 
-    gh, gw = _grid_shape(hdr["height"], hdr["width"])
+    colour = hdr["version"] == VERSION_COLOUR
+    if colour:
+        mh, mw = _colour_grid(hdr["height"], hdr["width"])
+        n_blocks = mh * mw * len(COLOUR_BLOCK_CLASSES)
+        extra = {"classes": COLOUR_BLOCK_CLASSES}
+    else:
+        gh, gw = _grid_shape(hdr["height"], hdr["width"])
+        n_blocks, extra = gh * gw, {}
     # every block costs at least 2 payload bits (DC code + EOB), so a
     # shape whose block count exceeds 4 bytes^-1 * payload is invalid —
     # this bounds allocation before trusting the header's dimensions
-    if gh * gw > 4 * hdr["payload_nbytes"]:
+    if n_blocks > 4 * hdr["payload_nbytes"]:
         raise BitstreamError(
             f"declared {hdr['height']}x{hdr['width']} image needs "
-            f"{gh * gw} blocks but the {hdr['payload_nbytes']}-byte "
+            f"{n_blocks} blocks but the {hdr['payload_nbytes']}-byte "
             f"payload cannot hold them (corrupted shape)")
     try:
-        dc_diff, ac = rle.decode_payload(data[off:end], gh * gw,
+        dc_diff, ac = rle.decode_payload(data[off:end], n_blocks,
                                          dc_table, ac_table,
-                                         unpacker=unpacker)
+                                         unpacker=unpacker, **extra)
     except (bitio.TruncatedStream, ValueError) as e:
         raise BitstreamError(f"bad entropy payload: {e}") from e
 
     # DC integration is integer-exact, so the host cumsum matches the
     # device's scan.dc_integrate bit for bit
-    z = np.empty((gh * gw, 64), dtype=np.int32)
-    z[:, 0] = np.cumsum(dc_diff, dtype=np.int64)
+    z = np.empty((n_blocks, 64), dtype=np.int32)
+    z[:, 0] = (colour_dc_integrate(dc_diff) if colour
+               else np.cumsum(dc_diff, dtype=np.int64))
     z[:, 1:] = ac
     return z, hdr
+
+
+def stream_layout(data: bytes) -> tuple:
+    """``(components, mcus)`` of a stream from its header alone:
+    ``(1, blocks)`` for grayscale, ``(3, MCUs)`` for colour, ``(0, 0)``
+    where the header does not parse (the decode then says why)."""
+    try:
+        hdr = read_header(data)
+    except BitstreamError:
+        return 0, 0
+    if hdr["version"] == VERSION_COLOUR:
+        mh, mw = _colour_grid(hdr["height"], hdr["width"])
+        return hdr["components"], mh * mw
+    gh, gw = _grid_shape(hdr["height"], hdr["width"])
+    return 1, gh * gw
 
 
 def decode_qcoeffs(data: bytes, *, unpacker=None) -> tuple:
@@ -460,6 +635,10 @@ def decode_qcoeffs(data: bytes, *, unpacker=None) -> tuple:
 
     from repro.core.entropy import scan
     z, hdr = decode_zigzag_host(data, unpacker=unpacker)
+    if hdr["version"] == VERSION_COLOUR:
+        raise ValueError("a colour (version 3) stream has no single block "
+                         "grid: decode it with decode_image or "
+                         "codec_engine.decode_batch")
     gh, gw = _grid_shape(hdr["height"], hdr["width"])
     # accelerated half of the inverse: the inverse zig-zag permutation
     return scan.unblock_stream(jnp.asarray(z), gh, gw), hdr
@@ -467,14 +646,16 @@ def decode_qcoeffs(data: bytes, *, unpacker=None) -> tuple:
 
 def encode_image(img, quality: int = 50, transform: str = "exact",
                  cordic_config=None, *, tables: str = "auto") -> bytes:
-    """Compress a (H, W) grayscale image to a complete ``DCTZ`` stream.
+    """Compress a (H, W) grayscale image to a complete ``DCTZ`` stream,
+    or an (H, W, 3) RGB image to a version-3 colour stream
+    (:func:`repro.core.colour.encode_image`).
 
     The array half (DCT + quantise + zig-zag) runs the same jitted path
     as :func:`repro.core.codec.compress`; only bit packing happens on
     the host.
 
     Args:
-        img: (H, W) uint8/float grayscale image.
+        img: (H, W) uint8/float grayscale or (H, W, 3) RGB image.
         quality: JPEG quality factor in [1, 100].
         transform: encoder transform ("exact"/"cordic"/"loeffler").
         cordic_config: CORDIC config for ``transform == "cordic"``
@@ -485,7 +666,10 @@ def encode_image(img, quality: int = 50, transform: str = "exact",
         The container bytes; ``len()`` of it is the *measured* size the
         rate–distortion benches report.
     """
-    from repro.core import codec, cordic
+    from repro.core import codec, colour, cordic
+    if colour.is_colour(img):
+        return colour.encode_image(img, quality, transform, cordic_config,
+                                   tables=tables)
     c = codec.compress(img, quality, transform,
                        cordic_config or cordic.PAPER_CONFIG)
     return c.to_bytes(tables=tables)
@@ -508,11 +692,17 @@ def decode_image(data: bytes, mode: str = "standard", *, unpacker=None):
             ``repro.kernels.unpack_bits.make_unpacker()``.
 
     Returns:
-        (H, W) uint8 reconstruction, cropped to the stored shape.
+        (H, W) uint8 reconstruction, cropped to the stored shape; (H, W,
+        3) uint8 RGB for a colour stream.
 
     Raises:
         BitstreamError: see :func:`decode_qcoeffs`.
     """
-    from repro.core import codec
+    from repro.core import codec, colour
+    if read_header(data)["version"] == VERSION_COLOUR:
+        z, hdr = decode_zigzag_host(data, unpacker=unpacker)
+        return colour.decompress(
+            z, hdr["height"], hdr["width"], hdr["quality"],
+            "exact" if mode == "standard" else hdr["transform"])
     c = codec.CompressedImage.from_bytes(data, unpacker=unpacker)
     return codec.decompress(c, mode=mode)

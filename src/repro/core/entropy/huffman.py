@@ -19,9 +19,12 @@ shared tables** by id (:class:`TableRegistry`): the encoder skips both
 the per-stream table build and the ~56 embedded table bytes whenever a
 registered table codes the stream more cheaply (:func:`coded_bits` is
 the cost model).  Ids 1 and 2 ship the ITU-T T.81 Annex K luminance
-tables — the canonical "well-known" JPEG tables — and encoder and
-decoder share one registry (:data:`DEFAULT_TABLES`) so the choice needs
-no negotiation beyond the id byte in the header.
+tables and ids 3 and 4 its chrominance tables — the canonical
+"well-known" JPEG tables — and encoder and decoder share one registry
+(:data:`DEFAULT_TABLES`) so the choice needs no negotiation beyond the
+id byte in the header.  Colour streams (container version 3) code luma
+blocks with one (DC, AC) pair and chroma blocks with another: the two
+*table classes* of :data:`STANDARD_IDS`.
 """
 
 from __future__ import annotations
@@ -351,9 +354,49 @@ STANDARD_AC_LUMA = CanonicalTable(
         0xF1, 0xF2, 0xF3, 0xF4, 0xF5, 0xF6, 0xF7, 0xF8,
         0xF9, 0xFA))
 
+# ITU-T T.81 Annex K, Tables K.4 and K.6 (chrominance), the shared
+# tables of a colour stream's chroma class.
+STANDARD_DC_CHROMA_ID = 3
+STANDARD_AC_CHROMA_ID = 4
+
+STANDARD_DC_CHROMA = CanonicalTable(
+    counts=(0, 3, 1, 1, 1, 1, 1, 1, 1, 1, 1, 0, 0, 0, 0, 0),
+    symbols=tuple(range(12)))
+
+STANDARD_AC_CHROMA = CanonicalTable(
+    counts=(0, 2, 1, 2, 4, 4, 3, 4, 7, 5, 4, 4, 0, 1, 2, 119),
+    symbols=(
+        0x00, 0x01, 0x02, 0x03, 0x11, 0x04, 0x05, 0x21,
+        0x31, 0x06, 0x12, 0x41, 0x51, 0x07, 0x61, 0x71,
+        0x13, 0x22, 0x32, 0x81, 0x08, 0x14, 0x42, 0x91,
+        0xA1, 0xB1, 0xC1, 0x09, 0x23, 0x33, 0x52, 0xF0,
+        0x15, 0x62, 0x72, 0xD1, 0x0A, 0x16, 0x24, 0x34,
+        0xE1, 0x25, 0xF1, 0x17, 0x18, 0x19, 0x1A, 0x26,
+        0x27, 0x28, 0x29, 0x2A, 0x35, 0x36, 0x37, 0x38,
+        0x39, 0x3A, 0x43, 0x44, 0x45, 0x46, 0x47, 0x48,
+        0x49, 0x4A, 0x53, 0x54, 0x55, 0x56, 0x57, 0x58,
+        0x59, 0x5A, 0x63, 0x64, 0x65, 0x66, 0x67, 0x68,
+        0x69, 0x6A, 0x73, 0x74, 0x75, 0x76, 0x77, 0x78,
+        0x79, 0x7A, 0x82, 0x83, 0x84, 0x85, 0x86, 0x87,
+        0x88, 0x89, 0x8A, 0x92, 0x93, 0x94, 0x95, 0x96,
+        0x97, 0x98, 0x99, 0x9A, 0xA2, 0xA3, 0xA4, 0xA5,
+        0xA6, 0xA7, 0xA8, 0xA9, 0xAA, 0xB2, 0xB3, 0xB4,
+        0xB5, 0xB6, 0xB7, 0xB8, 0xB9, 0xBA, 0xC2, 0xC3,
+        0xC4, 0xC5, 0xC6, 0xC7, 0xC8, 0xC9, 0xCA, 0xD2,
+        0xD3, 0xD4, 0xD5, 0xD6, 0xD7, 0xD8, 0xD9, 0xDA,
+        0xE2, 0xE3, 0xE4, 0xE5, 0xE6, 0xE7, 0xE8, 0xE9,
+        0xEA, 0xF2, 0xF3, 0xF4, 0xF5, 0xF6, 0xF7, 0xF8,
+        0xF9, 0xFA))
+
+#: Shared (DC id, AC id) per table class: 0 luma, 1 chroma.
+STANDARD_IDS = ((STANDARD_DC_LUMA_ID, STANDARD_AC_LUMA_ID),
+                (STANDARD_DC_CHROMA_ID, STANDARD_AC_CHROMA_ID))
+
 DEFAULT_TABLES = TableRegistry()
 DEFAULT_TABLES.register(STANDARD_DC_LUMA_ID, STANDARD_DC_LUMA)
 DEFAULT_TABLES.register(STANDARD_AC_LUMA_ID, STANDARD_AC_LUMA)
+DEFAULT_TABLES.register(STANDARD_DC_CHROMA_ID, STANDARD_DC_CHROMA)
+DEFAULT_TABLES.register(STANDARD_AC_CHROMA_ID, STANDARD_AC_CHROMA)
 
 
 @functools.lru_cache(maxsize=64)

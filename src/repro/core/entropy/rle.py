@@ -30,6 +30,13 @@ Symbol alphabet (docs/bitstream.md):
   zeros without coding a coefficient.
 * amplitudes use JPEG's one's-complement convention: ``v > 0`` codes as
   ``v``; ``v < 0`` codes as ``v + 2**size - 1``.
+
+**Table classes.** A colour stream codes its blocks with more than one
+pair of Huffman tables: block ``k`` uses the pair of class
+``classes[k % len(classes)]`` (``(0, 0, 0, 0, 1, 1)`` for a 4:2:0 MCU,
+luma then chroma; :data:`ONE_CLASS` for grayscale).  Wherever a
+function takes ``classes``, its ``dc_table``/``ac_table`` may be one
+table or a sequence indexed by class, and its histograms are per class.
 """
 
 from __future__ import annotations
@@ -43,6 +50,29 @@ EOB = 0x00
 ZRL = 0xF0
 MAX_CATEGORY = 15          # amplitudes are at most 15 bits
 AC_LEN = 63                # zig-zag positions 1..63
+
+
+#: The class pattern of a grayscale stream: every block, class 0.
+ONE_CLASS = (0,)
+
+
+def table_sets(dc_table, ac_table) -> tuple:
+    """``(dc_tables, ac_tables)``: a table, or a sequence of tables
+    indexed by class, as tuples of one table per class."""
+    def per_class(t):
+        return tuple(t) if isinstance(t, (tuple, list)) else (t,)
+    return per_class(dc_table), per_class(ac_table)
+
+
+def block_classes(classes: tuple, n_blocks: int) -> np.ndarray:
+    """(n_blocks,) int64 class of each block under a periodic pattern."""
+    return np.resize(np.asarray(classes, np.int64), n_blocks)
+
+
+def class_luts(tables: tuple) -> tuple:
+    """(codes, lengths): (n_classes, 256) encoder LUTs, one row per class."""
+    luts = [huffman.encoder_luts(t) for t in tables]
+    return (np.stack([c for c, _ in luts]), np.stack([n for _, n in luts]))
 
 
 class RangeError(ValueError):
@@ -223,22 +253,32 @@ def symbolize_reference(dc_diff: np.ndarray, ac: np.ndarray) -> tuple:
             np.asarray(amp_lens, dtype=np.int64))
 
 
-def symbol_frequencies(is_dc, syms) -> tuple:
-    """(dc_freqs, ac_freqs): 256-bin histograms of the two alphabets."""
-    dc = np.bincount(syms[is_dc], minlength=256)
-    ac = np.bincount(syms[~is_dc], minlength=256)
-    return dc, ac
+def symbol_frequencies(is_dc, syms, sym_cls=None, n_classes: int = 1
+                       ) -> tuple:
+    """(dc_freqs, ac_freqs): 256-bin histograms of the two alphabets;
+    with ``sym_cls`` (each symbol's table class), (n_classes, 256)."""
+    if sym_cls is None:
+        dc = np.bincount(syms[is_dc], minlength=256)
+        ac = np.bincount(syms[~is_dc], minlength=256)
+        return dc, ac
+    key = sym_cls * 256 + syms
+    n = n_classes * 256
+    return (np.bincount(key[is_dc], minlength=n).reshape(n_classes, 256),
+            np.bincount(key[~is_dc], minlength=n).reshape(n_classes, 256))
 
 
-def codeword_fields(is_dc, syms, amp_vals, amp_lens,
-                    dc_table: huffman.CanonicalTable,
-                    ac_table: huffman.CanonicalTable) -> tuple:
+def codeword_fields(is_dc, syms, amp_vals, amp_lens, dc_table, ac_table,
+                    sym_cls=None) -> tuple:
     """Codeword-lookup stage: symbol stream -> interleaved bit fields.
 
     Every symbol contributes its Huffman code, immediately followed by
     its amplitude field (when present); the interleave is realised by
     laying codes at even and amplitudes at odd slots of a (2M,) field
     array — packers drop the zero-width slots.
+
+    With ``sym_cls`` (each symbol's table class), ``dc_table`` and
+    ``ac_table`` are sequences indexed by class and each symbol takes
+    its class's codes.
 
     Returns:
         ``(fields, widths)`` int64 arrays ready for any bit packer
@@ -250,10 +290,18 @@ def codeword_fields(is_dc, syms, amp_vals, amp_lens,
             (possible with shared tables; the container's cost-based
             selection never picks an uncovering table).
     """
-    dc_code, dc_len = huffman.encoder_luts(dc_table)
-    ac_code, ac_len = huffman.encoder_luts(ac_table)
-    codes = np.where(is_dc, dc_code[syms], ac_code[syms])
-    lens = np.where(is_dc, dc_len[syms], ac_len[syms])
+    if sym_cls is None:
+        dc_code, dc_len = huffman.encoder_luts(dc_table)
+        ac_code, ac_len = huffman.encoder_luts(ac_table)
+        codes = np.where(is_dc, dc_code[syms], ac_code[syms])
+        lens = np.where(is_dc, dc_len[syms], ac_len[syms])
+    else:
+        dc_code, dc_len = class_luts(dc_table)
+        ac_code, ac_len = class_luts(ac_table)
+        codes = np.where(is_dc, dc_code[sym_cls, syms],
+                         ac_code[sym_cls, syms])
+        lens = np.where(is_dc, dc_len[sym_cls, syms],
+                        ac_len[sym_cls, syms])
     if bool((lens == 0).any()):
         raise ValueError("symbol stream contains a symbol absent from "
                          "the Huffman table")
@@ -265,10 +313,8 @@ def codeword_fields(is_dc, syms, amp_vals, amp_lens,
     return fields, widths
 
 
-def encode_payload(is_dc, syms, amp_vals, amp_lens,
-                   dc_table: huffman.CanonicalTable,
-                   ac_table: huffman.CanonicalTable,
-                   packer=None) -> bytes:
+def encode_payload(is_dc, syms, amp_vals, amp_lens, dc_table, ac_table,
+                   packer=None, sym_cls=None) -> bytes:
     """Huffman-code the symbol stream and pack it into bytes.
 
     Two explicit stages of the staged encode pipeline: codeword lookup
@@ -277,9 +323,10 @@ def encode_payload(is_dc, syms, amp_vals, amp_lens,
     the routed :func:`repro.kernels.pack_bits.pack_bits`; ``None`` uses
     the NumPy reference :func:`repro.core.entropy.bitio.pack_bits`.
     Every backend is byte-identical by contract (CI-gated).
+    ``sym_cls`` picks each symbol's tables, as in :func:`codeword_fields`.
     """
     fields, widths = codeword_fields(is_dc, syms, amp_vals, amp_lens,
-                                     dc_table, ac_table)
+                                     dc_table, ac_table, sym_cls)
     if packer is not None:
         return packer(fields, widths)
     with obs.route("pack", "host"):
@@ -299,24 +346,36 @@ class PreparedStream:
     or the device-resident chain, byte-identically (CI-gated).
     """
 
-    def __init__(self, dc_diff: np.ndarray, ac: np.ndarray, packer=None):
+    def __init__(self, dc_diff: np.ndarray, ac: np.ndarray, packer=None,
+                 classes: tuple = ONE_CLASS):
         with obs.route("symbolize", "host", blocks=len(dc_diff)):
             self._stream = symbolize(dc_diff, ac)
             self._packer = packer
+            self._sym_cls = None
+            if classes != ONE_CLASS:
+                is_dc = self._stream[0]
+                self._sym_cls = block_classes(classes, len(dc_diff))[
+                    np.cumsum(is_dc) - 1]
             self.dc_freq, self.ac_freq = symbol_frequencies(
-                self._stream[0], self._stream[1])
+                self._stream[0], self._stream[1], self._sym_cls,
+                max(classes) + 1)
 
-    def payload(self, dc_table: huffman.CanonicalTable,
-                ac_table: huffman.CanonicalTable) -> bytes:
-        """Huffman-code + pack the prepared stream for chosen tables."""
+    def payload(self, dc_table, ac_table) -> bytes:
+        """Huffman-code + pack the prepared stream for chosen tables
+        (one per class where the stream has several)."""
         return encode_payload(*self._stream, dc_table, ac_table,
-                              packer=self._packer)
+                              packer=self._packer, sym_cls=self._sym_cls)
 
 
 def prepare_stream(dc_diff: np.ndarray, ac: np.ndarray,
-                   packer=None) -> PreparedStream:
-    """The default ``symbolizer=`` backend: vectorised host pipeline."""
-    return PreparedStream(dc_diff, ac, packer=packer)
+                   packer=None, classes: tuple = ONE_CLASS
+                   ) -> PreparedStream:
+    """The default ``symbolizer=`` backend: vectorised host pipeline.
+
+    ``classes`` is the stream's table-class pattern; with more than one
+    class, ``dc_freq``/``ac_freq`` are (n_classes, 256) and ``payload``
+    takes one table per class."""
+    return PreparedStream(dc_diff, ac, packer=packer, classes=classes)
 
 
 _PAST_END = 32     # sentinel slots appended past the last window position
@@ -436,10 +495,17 @@ def walk_table_nbytes(nbits: int) -> int:
     return entries * (36 if nbits <= _WALK_LIST_MAX_BITS else 8)
 
 
-def decode_payload(payload: bytes, n_blocks: int,
-                   dc_table: huffman.CanonicalTable,
-                   ac_table: huffman.CanonicalTable, *,
-                   unpacker=None) -> tuple:
+def check_dc_tables(dc_tables: tuple) -> None:
+    """Reject a DC table coding a symbol above :data:`MAX_CATEGORY`."""
+    for t in dc_tables:
+        if t.symbols and max(t.symbols) > MAX_CATEGORY:
+            raise ValueError(
+                f"DC table codes symbol {max(t.symbols)} > "
+                f"{MAX_CATEGORY}: not a magnitude-category alphabet")
+
+
+def decode_payload(payload: bytes, n_blocks: int, dc_table, ac_table, *,
+                   unpacker=None, classes: tuple = ONE_CLASS) -> tuple:
     """Decode ``n_blocks`` blocks from an entropy payload (LUT decoder).
 
     Replaces bit-at-a-time Huffman walking: the peek-16 prefix LUTs of
@@ -470,7 +536,11 @@ def decode_payload(payload: bytes, n_blocks: int,
             is bounded per tile).  Any
             unpacker must honour this function's full contract —
             values *and* errors (CI-gated by ``bench_entropy_throughput
-            --check-identical``).
+            --check-identical``) — and takes ``classes=`` when the
+            stream has more than one table class.
+        classes: the table-class pattern (module docstring); with more
+            than one class, ``dc_table``/``ac_table`` are sequences
+            indexed by class.
 
     Returns:
         ``(dc_diff, ac)`` — (n,) int32 DC differences and (n, 63) int32
@@ -481,12 +551,11 @@ def decode_payload(payload: bytes, n_blocks: int,
         ValueError: an invalid Huffman prefix, a coefficient overrun, or
             an out-of-spec DC table (corrupted stream).
     """
+    extra = {} if classes == ONE_CLASS else {"classes": classes}
     if unpacker is not None:
-        return unpacker(payload, n_blocks, dc_table, ac_table)
-    if dc_table.symbols and max(dc_table.symbols) > MAX_CATEGORY:
-        raise ValueError(
-            f"DC table codes symbol {max(dc_table.symbols)} > "
-            f"{MAX_CATEGORY}: not a magnitude-category alphabet")
+        return unpacker(payload, n_blocks, dc_table, ac_table, **extra)
+    dc_tables, ac_tables = table_sets(dc_table, ac_table)
+    check_dc_tables(dc_tables)
     nbits = len(payload) * 8
     if nbits > _ROUTED_DECODE_MIN_BITS:
         # the walk tables below would cost ~16 B per payload bit; route
@@ -494,18 +563,20 @@ def decode_payload(payload: bytes, n_blocks: int,
         # (it picks its own backend via unpack_bits.select_backend)
         unpack = _staged_unpacker()
         if unpack is not None:
-            return unpack(payload, n_blocks, dc_table, ac_table)
-    with obs.route("unpack", "host", blocks=n_blocks):
-        return _walk(payload, nbits, n_blocks, dc_table, ac_table)
+            return unpack(payload, n_blocks, dc_table, ac_table, **extra)
+    with obs.route("unpack", "host", blocks=n_blocks,
+                   table_classes=len(dc_tables)):
+        return _walk(payload, nbits, n_blocks, dc_tables, ac_tables,
+                     classes)
 
 
-def _walk(payload: bytes, nbits: int, n_blocks: int,
-          dc_table: huffman.CanonicalTable,
-          ac_table: huffman.CanonicalTable) -> tuple:
+def _walk(payload: bytes, nbits: int, n_blocks: int, dc_tables: tuple,
+          ac_tables: tuple, classes: tuple) -> tuple:
     """The LUT walk of :func:`decode_payload` (same contract)."""
     win = bitio.bit_windows(payload)
-    dc_tab = _decode_table(win, nbits, dc_table)
-    ac_tab = _decode_table(win, nbits, ac_table)
+    dc_tabs = [_decode_table(win, nbits, t) for t in dc_tables]
+    ac_tabs = [_decode_table(win, nbits, t) for t in ac_tables]
+    period = len(classes)
 
     def bad(s: int, p: int, what: str):
         if s == -2:
@@ -519,7 +590,9 @@ def _walk(payload: bytes, nbits: int, n_blocks: int,
     vals: list = []
     p = 0
     for b in range(n_blocks):
-        x = dc_tab[p]
+        c = classes[b % period]
+        ac_tab = ac_tabs[c]
+        x = dc_tabs[c][p]
         s = (x >> _CTRL_SHIFT) - 2
         if s < 0:
             raise bad(s, p, "DC")
@@ -556,21 +629,24 @@ def _walk(payload: bytes, nbits: int, n_blocks: int,
     return np.asarray(dc_out, dtype=np.int32), ac
 
 
-def decode_payload_reference(payload: bytes, n_blocks: int,
-                             dc_table: huffman.CanonicalTable,
-                             ac_table: huffman.CanonicalTable) -> tuple:
+def decode_payload_reference(payload: bytes, n_blocks: int, dc_table,
+                             ac_table, classes: tuple = ONE_CLASS
+                             ) -> tuple:
     """Bit-at-a-time oracle for :func:`decode_payload` (same contract).
 
     The original :class:`repro.core.entropy.bitio.BitReader` walk, kept
     as the golden reference for the property tests and the
     ``--check-identical`` bench gate.  Not on the production path.
     """
-    dc_sym, dc_len = dc_table.decoder_lut()
-    ac_sym, ac_len = ac_table.decoder_lut()
+    dc_tables, ac_tables = table_sets(dc_table, ac_table)
+    dc_luts = [t.decoder_lut() for t in dc_tables]
+    ac_luts = [t.decoder_lut() for t in ac_tables]
     reader = bitio.BitReader(payload)
     dc_diff = np.zeros(n_blocks, dtype=np.int32)
     ac = np.zeros((n_blocks, AC_LEN), dtype=np.int32)
     for b in range(n_blocks):
+        c = classes[b % len(classes)]
+        (dc_sym, dc_len), (ac_sym, ac_len) = dc_luts[c], ac_luts[c]
         w = reader.peek16()
         length = int(dc_len[w])
         if length == 0:

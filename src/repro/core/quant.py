@@ -1,7 +1,8 @@
 """JPEG-style quantisation for 8x8 DCT coefficient blocks.
 
 The paper's pipeline is DCT -> quantiser -> IDCT (each a separate CUDA
-kernel).  We use the ITU-T T.81 Annex K luminance table with the standard
+kernel).  We use the ITU-T T.81 Annex K luminance table (and, for the
+chroma planes of colour images, its chrominance table) with the standard
 IJG quality scaling.  Note: the orthonormal 2-D DCT used throughout this
 repo coincides exactly with the JPEG FDCT convention (the (1/4)·C(u)C(v)
 scaling equals the orthonormal alpha_u·alpha_v), so the table applies
@@ -27,20 +28,35 @@ JPEG_LUMA_QTABLE = np.array([
     [72, 92, 95, 98, 112, 100, 103, 99],
 ], dtype=np.float64)
 
+# ITU-T T.81 Annex K, Table K.2 (chrominance): the Cb and Cr planes of a
+# colour stream.
+JPEG_CHROMA_QTABLE = np.array([
+    [17, 18, 24, 47, 99, 99, 99, 99],
+    [18, 21, 26, 66, 99, 99, 99, 99],
+    [24, 26, 56, 99, 99, 99, 99, 99],
+    [47, 66, 99, 99, 99, 99, 99, 99],
+    [99, 99, 99, 99, 99, 99, 99, 99],
+    [99, 99, 99, 99, 99, 99, 99, 99],
+    [99, 99, 99, 99, 99, 99, 99, 99],
+    [99, 99, 99, 99, 99, 99, 99, 99],
+], dtype=np.float64)
+
 
 @functools.lru_cache(maxsize=None)
-def _scaled_qtable_np(quality: int) -> np.ndarray:
+def _scaled_qtable_np(quality: int, chroma: bool = False) -> np.ndarray:
     """IJG quality scaling: quality in [1, 100]."""
     quality = int(np.clip(quality, 1, 100))
     if quality < 50:
         scale = 5000.0 / quality
     else:
         scale = 200.0 - 2.0 * quality
-    q = np.floor((JPEG_LUMA_QTABLE * scale + 50.0) / 100.0)
+    base = JPEG_CHROMA_QTABLE if chroma else JPEG_LUMA_QTABLE
+    q = np.floor((base * scale + 50.0) / 100.0)
     return np.clip(q, 1.0, 255.0)
 
 
-def qtable(quality: int = 50, dtype=jnp.float32) -> jnp.ndarray:
+def qtable(quality: int = 50, dtype=jnp.float32, *,
+           chroma: bool = False) -> jnp.ndarray:
     """Quantisation step table for an IJG quality factor.
 
     This is the only table-derivation rule in the codec: the ``DCTZ``
@@ -51,11 +67,13 @@ def qtable(quality: int = 50, dtype=jnp.float32) -> jnp.ndarray:
         quality: IJG quality factor, clipped to [1, 100]; 50 is the
             unscaled Annex K table, lower is coarser.
         dtype: element dtype of the returned table.
+        chroma: scale Table K.2 (chrominance, the quantisation class
+            of a colour stream's Cb and Cr) instead of K.1.
 
     Returns:
         (8, 8) array of quantisation steps in [1, 255].
     """
-    return jnp.asarray(_scaled_qtable_np(quality), dtype=dtype)
+    return jnp.asarray(_scaled_qtable_np(quality, chroma), dtype=dtype)
 
 
 def quantize(coeffs: jnp.ndarray, q: jnp.ndarray) -> jnp.ndarray:

@@ -60,11 +60,15 @@ def _category(mag: jnp.ndarray) -> jnp.ndarray:
     return cat
 
 
-def _make_kernel(tile_blocks: int):
+def _make_kernel(tile_blocks: int, n_classes: int):
     t = tile_blocks
 
-    def kernel(nrows_ref, dc_ref, ac_ref, syms_ref, amps_ref, lens_ref,
-               total_ref, dc_hist_ref, ac_hist_ref):
+    def kernel(nrows_ref, dc_ref, ac_ref, *refs):
+        if n_classes > 1:
+            cls = refs[0][...]                             # (t, 1) int32
+            refs = refs[1:]
+        (syms_ref, amps_ref, lens_ref, total_ref, dc_hist_ref,
+         ac_hist_ref) = refs
         i = pl.program_id(0)
         row = (i * t + jax.lax.broadcasted_iota(jnp.int32, (t, 1), 0))
         valid_row = row < nrows_ref[0]
@@ -123,34 +127,49 @@ def _make_kernel(tile_blocks: int):
         total_ref[...] = total
 
         # per-alphabet histograms, accumulated across sequential grid
-        # steps into one revisited (1, 256) block
+        # steps into one revisited (n_classes, 256) block
         bins = jax.lax.broadcasted_iota(jnp.int32, (1, 256), 1)
-        dc_sym_h = jnp.where(valid_row, dc_cat, -1)        # (t, 1)
-        dc_step = (dc_sym_h == bins).astype(jnp.int32).sum(
-            axis=0, keepdims=True)                         # (1, 256)
-        ac_sym_h = jnp.where(nz, coef_sym, -1)[:, :, None]  # (t, 63, 1)
-        ac_step = (ac_sym_h == bins[None]).astype(jnp.int32).sum(
-            axis=1).sum(axis=0, keepdims=True)
-        zrl_sum = jnp.where(nz, zrl, 0).sum()
-        eob_sum = eob.astype(jnp.int32).sum()
-        ac_step = (ac_step
-                   + jnp.where(bins == ZRL, zrl_sum, 0)
-                   + jnp.where(bins == EOB, eob_sum, 0))
+
+        def hist(rows, nzc, eobc):
+            dc_sym_h = jnp.where(rows, dc_cat, -1)         # (t, 1)
+            dc_step = (dc_sym_h == bins).astype(jnp.int32).sum(
+                axis=0, keepdims=True)                     # (1, 256)
+            ac_sym_h = jnp.where(nzc, coef_sym, -1)[:, :, None]
+            ac_step = (ac_sym_h == bins[None]).astype(jnp.int32).sum(
+                axis=1).sum(axis=0, keepdims=True)
+            zrl_sum = jnp.where(nzc, zrl, 0).sum()
+            eob_sum = eobc.astype(jnp.int32).sum()
+            ac_step = (ac_step
+                       + jnp.where(bins == ZRL, zrl_sum, 0)
+                       + jnp.where(bins == EOB, eob_sum, 0))
+            return dc_step, ac_step
 
         @pl.when(i == 0)
         def _init():
             dc_hist_ref[...] = jnp.zeros_like(dc_hist_ref)
             ac_hist_ref[...] = jnp.zeros_like(ac_hist_ref)
 
-        dc_hist_ref[...] += dc_step
-        ac_hist_ref[...] += ac_step
+        if n_classes == 1:
+            dc_step, ac_step = hist(valid_row, nz, eob)
+            dc_hist_ref[...] += dc_step
+            ac_hist_ref[...] += ac_step
+        else:
+            # one class at a time, each row accumulated before the next
+            # class's one-hot is built
+            for c in range(n_classes):
+                dc_step, ac_step = hist(valid_row & (cls == c),
+                                        nz & (cls == c), eob & (cls == c))
+                dc_hist_ref[c:c + 1, :] += dc_step
+                ac_hist_ref[c:c + 1, :] += ac_step
 
     return kernel
 
 
-@functools.partial(jax.jit, static_argnames=("tile_blocks", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tile_blocks", "n_classes",
+                                             "interpret"))
 def symbolize_pallas(dc_diff: jnp.ndarray, ac: jnp.ndarray,
-                     nrows: jnp.ndarray, *, tile_blocks: int = 64,
+                     nrows: jnp.ndarray, cls: jnp.ndarray | None = None, *,
+                     tile_blocks: int = 64, n_classes: int = 1,
                      interpret: bool = True) -> tuple:
     """Symbolise padded zig-zag blocks into dense slots + histograms.
 
@@ -161,13 +180,17 @@ def symbolize_pallas(dc_diff: jnp.ndarray, ac: jnp.ndarray,
         nrows: (1,) int32 scalar-prefetch — the real block count; rows
             at and past it are padding (zero histogram weight,
             ``total == 0``).
+        cls: (n_pad, 1) int32 table class of each block, in
+            ``[0, n_classes)``; required when ``n_classes > 1``.
         tile_blocks: blocks per grid program.
+        n_classes: table classes, one histogram row each.
         interpret: run in Pallas interpret mode (non-TPU backends).
 
     Returns:
         ``(syms, amp_vals, amp_lens, total, dc_hist, ac_hist)`` —
         (n_pad, 64) int32 dense slot arrays, (n_pad, 1) int32 per-block
-        symbol counts, and two (1, 256) int32 alphabet histograms.
+        symbol counts, and two (n_classes, 256) int32 alphabet
+        histograms.
     """
     n_pad = dc_diff.shape[0]
     if n_pad % tile_blocks:
@@ -177,20 +200,22 @@ def symbolize_pallas(dc_diff: jnp.ndarray, ac: jnp.ndarray,
     t = tile_blocks
     tile = lambda i, nrows_ref: (i, 0)
     fixed = lambda i, nrows_ref: (0, 0)
+    in_specs = [pl.BlockSpec((t, 1), tile), pl.BlockSpec((t, AC_LEN), tile)]
+    args = [nrows, dc_diff, ac]
+    if n_classes > 1:
+        in_specs.append(pl.BlockSpec((t, 1), tile))
+        args.append(cls)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((t, 1), tile),
-            pl.BlockSpec((t, AC_LEN), tile),
-        ],
+        in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((t, SLOTS), tile),
             pl.BlockSpec((t, SLOTS), tile),
             pl.BlockSpec((t, SLOTS), tile),
             pl.BlockSpec((t, 1), tile),
-            pl.BlockSpec((1, 256), fixed),
-            pl.BlockSpec((1, 256), fixed),
+            pl.BlockSpec((n_classes, 256), fixed),
+            pl.BlockSpec((n_classes, 256), fixed),
         ],
     )
     out_shape = [
@@ -198,12 +223,12 @@ def symbolize_pallas(dc_diff: jnp.ndarray, ac: jnp.ndarray,
         jax.ShapeDtypeStruct((n_pad, SLOTS), jnp.int32),
         jax.ShapeDtypeStruct((n_pad, SLOTS), jnp.int32),
         jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
-        jax.ShapeDtypeStruct((1, 256), jnp.int32),
-        jax.ShapeDtypeStruct((1, 256), jnp.int32),
+        jax.ShapeDtypeStruct((n_classes, 256), jnp.int32),
+        jax.ShapeDtypeStruct((n_classes, 256), jnp.int32),
     ]
     return pl.pallas_call(
-        _make_kernel(tile_blocks),
+        _make_kernel(tile_blocks, n_classes),
         out_shape=out_shape,
         grid_spec=grid_spec,
         interpret=interpret,
-    )(nrows, dc_diff, ac)
+    )(*args)

@@ -78,7 +78,7 @@ def _device_ok(dc_diff: np.ndarray, ac: np.ndarray) -> bool:
 
 
 def _run_kernel(dc_diff: np.ndarray, ac: np.ndarray, tile_blocks: int,
-                interpret: bool) -> tuple:
+                interpret: bool, classes: tuple = rle.ONE_CLASS) -> tuple:
     """Pad, launch, and return the kernel's device outputs + n_pad."""
     n = dc_diff.shape[0]
     n_pad = max(_pow2(n), tile_blocks)
@@ -87,9 +87,14 @@ def _run_kernel(dc_diff: np.ndarray, ac: np.ndarray, tile_blocks: int,
     acp = np.zeros((n_pad, ref.AC_LEN), np.int32)
     acp[:n] = ac
     nrows = np.array([n], np.int32)
-    with obs.h2d(dc, acp, nrows):
-        args = jnp.asarray(dc), jnp.asarray(acp), jnp.asarray(nrows)
+    host = [dc, acp, nrows]
+    if classes != rle.ONE_CLASS:
+        host.append(rle.block_classes(classes, n_pad).astype(
+            np.int32).reshape(n_pad, 1))
+    with obs.h2d(*host):
+        args = [jnp.asarray(a) for a in host]
     out = kernel.symbolize_pallas(*args, tile_blocks=tile_blocks,
+                                  n_classes=max(classes) + 1,
                                   interpret=interpret)
     obs.launched("symbolize", out[0])
     return out
@@ -97,7 +102,8 @@ def _run_kernel(dc_diff: np.ndarray, ac: np.ndarray, tile_blocks: int,
 
 def symbolize_dense(dc_diff, ac, *, backend: str = "auto",
                     tile_blocks: int | None = None,
-                    interpret: bool | None = None) -> ref.DenseSymbols:
+                    interpret: bool | None = None,
+                    classes: tuple = rle.ONE_CLASS) -> ref.DenseSymbols:
     """Routed fused pass: dense slots + histograms on the host.
 
     Args:
@@ -110,6 +116,8 @@ def symbolize_dense(dc_diff, ac, *, backend: str = "auto",
             (:func:`repro.kernels.tuning.tile_for`).  Ignored by
             "numpy".
         interpret: Pallas interpret-mode override; ignored by "numpy".
+        classes: the blocks' table-class pattern; with more than one
+            class the histograms are (n_classes, 256).
 
     Returns:
         A :class:`repro.kernels.symbolize.ref.DenseSymbols`, identical
@@ -124,14 +132,14 @@ def symbolize_dense(dc_diff, ac, *, backend: str = "auto",
     n = dc_diff.shape[0]
     if select_backend(backend) == "numpy" or not _device_ok(dc_diff, ac):
         with obs.route("symbolize", "host", blocks=n):
-            return ref.symbolize_dense(dc_diff, ac)
+            return ref.symbolize_dense(dc_diff, ac, classes)
     from repro.kernels import common
     if interpret is None:
         interpret = common.interpret_default()
     if tile_blocks is None:
         tile_blocks = tuning.tile_for("symbolize", n)
     with obs.device_route("symbolize", interpret, blocks=n):
-        out = _run_kernel(dc_diff, ac, tile_blocks, interpret)
+        out = _run_kernel(dc_diff, ac, tile_blocks, interpret, classes)
         with obs.d2h(*out):
             syms, amps, lens, total, dc_h, ac_h = jax.device_get(out)
     return ref.DenseSymbols(
@@ -139,8 +147,15 @@ def symbolize_dense(dc_diff, ac, *, backend: str = "auto",
         amp_vals=np.asarray(amps[:n], np.int16),
         amp_lens=np.asarray(lens[:n], np.int16),
         total=np.asarray(total[:n, 0], np.int64),
-        dc_freq=np.asarray(dc_h[0], np.int64),
-        ac_freq=np.asarray(ac_h[0], np.int64))
+        dc_freq=_hist(dc_h, classes), ac_freq=_hist(ac_h, classes),
+        classes=classes)
+
+
+def _hist(h, classes: tuple) -> np.ndarray:
+    """A fetched (n_classes, 256) histogram as the host reference shapes
+    it: one (256,) row for a one-class stream."""
+    h = np.asarray(h, np.int64)
+    return h[0] if classes == rle.ONE_CLASS else h
 
 
 def symbolize(dc_diff, ac, *, backend: str = "auto",
@@ -178,19 +193,25 @@ class _NumpyPrepared:
 
 @jax.jit
 def _fields_device(syms, amps, lens, total, dc_code, dc_len,
-                   ac_code, ac_len):
+                   ac_code, ac_len, cls=None):
     """Dense codeword gather + stable zero-width compaction, on device.
 
     Returns the flattened field/width/start arrays ready for the
     scatter-pack kernel (kept fields first, in stream order; zero-width
     tail at offset ``total_bits``), plus the payload bit count and an
-    uncodeable-symbol flag.
+    uncodeable-symbol flag.  With ``cls`` ((n_pad, 1) table class per
+    block) the code tables are (n_classes, 256) and each block takes
+    its class's row.
     """
     slot = jnp.arange(ref.SLOTS, dtype=jnp.int32)[None, :]
     valid = slot < total                                    # (n_pad, 64)
     isdc = slot == 0
-    codes = jnp.where(isdc, dc_code[syms], ac_code[syms])
-    clens = jnp.where(isdc, dc_len[syms], ac_len[syms])
+    if cls is None:
+        codes = jnp.where(isdc, dc_code[syms], ac_code[syms])
+        clens = jnp.where(isdc, dc_len[syms], ac_len[syms])
+    else:
+        codes = jnp.where(isdc, dc_code[cls, syms], ac_code[cls, syms])
+        clens = jnp.where(isdc, dc_len[cls, syms], ac_len[cls, syms])
     bad = jnp.any((clens == 0) & valid)
     f = jnp.stack([codes, amps], axis=-1).reshape(-1)
     w = jnp.stack([jnp.where(valid, clens, 0),
@@ -218,26 +239,33 @@ class _PallasPrepared:
     bytes (plus one scalar bit count to size the tile grid).
     """
 
-    def __init__(self, dc_diff, ac, tile_blocks, interpret):
+    def __init__(self, dc_diff, ac, tile_blocks, interpret,
+                 classes=rle.ONE_CLASS):
         self._interpret = interpret
+        self._classes = classes
         n = dc_diff.shape[0]
         self._n = n
-        (self._syms, self._amps, self._lens, self._total,
-         dc_h, ac_h) = _run_kernel(dc_diff, ac, tile_blocks, interpret)
+        out = _run_kernel(dc_diff, ac, tile_blocks, interpret, classes)
+        (self._syms, self._amps, self._lens, self._total, dc_h, ac_h) = out
         with obs.d2h(dc_h, ac_h):
             dc_h, ac_h = jax.device_get((dc_h, ac_h))
-        self.dc_freq = np.asarray(dc_h[0], np.int64)
-        self.ac_freq = np.asarray(ac_h[0], np.int64)
+        self.dc_freq = _hist(dc_h, classes)
+        self.ac_freq = _hist(ac_h, classes)
 
-    def payload(self, dc_table: huffman.CanonicalTable,
-                ac_table: huffman.CanonicalTable) -> bytes:
+    def payload(self, dc_table, ac_table) -> bytes:
         with obs.device_route("pack", self._interpret):
             return self._payload(dc_table, ac_table)
 
     def _payload(self, dc_table, ac_table) -> bytes:
-        luts = [np.asarray(a, np.int32)
-                for a in (*huffman.encoder_luts(dc_table),
-                          *huffman.encoder_luts(ac_table))]
+        extra = []
+        if self._classes == rle.ONE_CLASS:
+            luts = (*huffman.encoder_luts(dc_table),
+                    *huffman.encoder_luts(ac_table))
+        else:
+            luts = (*rle.class_luts(dc_table), *rle.class_luts(ac_table))
+            extra = [rle.block_classes(self._classes, self._syms.shape[0])
+                     .astype(np.int32).reshape(-1, 1)]
+        luts = [np.asarray(a, np.int32) for a in (*luts, *extra)]
         with obs.h2d(*luts):
             luts = [jnp.asarray(a) for a in luts]
         f, w, s, total_bits, bad = _fields_device(
@@ -277,11 +305,14 @@ def make_symbolizer(backend: str = "auto", *,
 
     On "pallas", ``packer`` only applies to streams the device guards
     reject (size/range fallbacks run the staged NumPy pass): accepted
-    streams pack through the chained device scatter-pack.
+    streams pack through the chained device scatter-pack.  A
+    ``classes=`` pattern with more than one table class gives
+    (n_classes, 256) histograms and a ``payload`` that takes one table
+    per class, on either route.
     """
     resolved = select_backend(backend)
 
-    def prepare(dc_diff, ac, packer=None):
+    def prepare(dc_diff, ac, packer=None, classes=rle.ONE_CLASS):
         dc_diff = np.asarray(dc_diff, dtype=np.int64)
         ac = np.asarray(ac, dtype=np.int64)
         if resolved == "pallas" and _device_ok(dc_diff, ac):
@@ -292,8 +323,9 @@ def make_symbolizer(backend: str = "auto", *,
                      if tile_blocks is None else tile_blocks)
             with obs.device_route("symbolize", interp,
                                   blocks=dc_diff.shape[0]):
-                return _PallasPrepared(dc_diff, ac, tiles, interp)
+                return _PallasPrepared(dc_diff, ac, tiles, interp, classes)
         with obs.route("symbolize", "host", blocks=dc_diff.shape[0]):
-            return _NumpyPrepared(ref.symbolize_dense(dc_diff, ac), packer)
+            return _NumpyPrepared(
+                ref.symbolize_dense(dc_diff, ac, classes), packer)
 
     return prepare

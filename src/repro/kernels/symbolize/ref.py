@@ -68,16 +68,28 @@ class DenseSymbols:
     amp_vals: np.ndarray       # (n, 64) int16
     amp_lens: np.ndarray       # (n, 64) int16
     total: np.ndarray          # (n,) int64, in [1, 64]
-    dc_freq: np.ndarray        # (256,) int64
-    ac_freq: np.ndarray        # (256,) int64
+    dc_freq: np.ndarray        # (256,) int64; (n_classes, 256) with classes
+    ac_freq: np.ndarray        # (256,) int64; (n_classes, 256) with classes
+    classes: tuple = rle.ONE_CLASS   # the blocks' table-class pattern
 
 
-def symbolize_dense(dc_diff: np.ndarray, ac: np.ndarray) -> DenseSymbols:
+def _class_hist(sym: np.ndarray, cls: np.ndarray, n_classes: int,
+                weights=None) -> np.ndarray:
+    """(n_classes, 256) histogram of ``sym`` split by ``cls``."""
+    return np.bincount(cls * 256 + sym, weights=weights,
+                       minlength=n_classes * 256).astype(np.int64).reshape(
+                           n_classes, 256)
+
+
+def symbolize_dense(dc_diff: np.ndarray, ac: np.ndarray,
+                    classes: tuple = rle.ONE_CLASS) -> DenseSymbols:
     """Blocks -> dense per-block symbol slots + histograms, one pass.
 
     Args:
         dc_diff: (n,) int DC differences in block order.
         ac: (n, 63) int AC tails in zig-zag order.
+        classes: the table-class pattern (:mod:`repro.core.entropy.rle`);
+            with more than one class the histograms are per class.
 
     Raises:
         rle.RangeError: some level needs an amplitude wider than 15
@@ -149,14 +161,25 @@ def symbolize_dense(dc_diff: np.ndarray, ac: np.ndarray) -> DenseSymbols:
         live = zrl > t
         flat_syms[zidx[live] + t] = rle.ZRL
 
-    dc_freq = np.bincount(dc_cat, minlength=256)
     # coded symbols never collide with ZRL (their size nibble is >= 1)
     # or EOB (nonzero), so the three contributions just add
-    ac_freq = np.bincount(coef_sym, minlength=256)
-    ac_freq[rle.ZRL] += int(zrl.sum())
-    ac_freq[rle.EOB] += int(eob.sum())
+    if classes == rle.ONE_CLASS:
+        dc_freq = np.bincount(dc_cat, minlength=256)
+        ac_freq = np.bincount(coef_sym, minlength=256)
+        ac_freq[rle.ZRL] += int(zrl.sum())
+        ac_freq[rle.EOB] += int(eob.sum())
+    else:
+        n_cls = max(classes) + 1
+        cls = rle.block_classes(classes, n)
+        dc_freq = _class_hist(dc_cat, cls, n_cls)
+        ac_freq = _class_hist(coef_sym, cls[rows], n_cls)
+        ac_freq[:, rle.ZRL] += np.bincount(cls[rows], weights=zrl,
+                                           minlength=n_cls).astype(np.int64)
+        ac_freq[:, rle.EOB] += np.bincount(cls[eob],
+                                           minlength=n_cls).astype(np.int64)
     return DenseSymbols(syms=syms_d, amp_vals=amps_d, amp_lens=lens_d,
-                        total=total, dc_freq=dc_freq, ac_freq=ac_freq)
+                        total=total, dc_freq=dc_freq, ac_freq=ac_freq,
+                        classes=classes)
 
 
 def dense_to_stream(dense: DenseSymbols) -> tuple:
@@ -181,9 +204,7 @@ def symbolize_ref(dc_diff: np.ndarray, ac: np.ndarray) -> tuple:
     return dense_to_stream(symbolize_dense(dc_diff, ac))
 
 
-def encode_fields_dense(dense: DenseSymbols,
-                        dc_table: huffman.CanonicalTable,
-                        ac_table: huffman.CanonicalTable) -> tuple:
+def encode_fields_dense(dense: DenseSymbols, dc_table, ac_table) -> tuple:
     """Codeword lookup on the dense layout: -> (fields, widths).
 
     Valid slots are addressed by flat index (per-block prefix sums of
@@ -191,14 +212,14 @@ def encode_fields_dense(dense: DenseSymbols,
     contributes its Huffman code then its amplitude field, and the
     row-major interleave *is* the stream order.  Byte output equals
     :func:`repro.core.entropy.rle.codeword_fields` + the same packer
-    (zero-width amplitude fields are dropped by every packer).
+    (zero-width amplitude fields are dropped by every packer).  With
+    more than one table class, ``dc_table``/``ac_table`` hold one table
+    per class and each block takes its class's codes.
 
     Raises:
         ValueError: a valid slot holds a symbol its table cannot code
             (same message as ``codeword_fields``).
     """
-    dc_code, dc_len = huffman.encoder_luts(dc_table)
-    ac_code, ac_len = huffman.encoder_luts(ac_table)
     n = dense.syms.shape[0]
     # flat indices of the valid slots, in coding order: slot arithmetic
     # on O(stream) elements, not O(n * 64) lanes
@@ -209,8 +230,17 @@ def encode_fields_dense(dense: DenseSymbols,
                                                     dense.total)
     syms = dense.syms.reshape(-1)[row * SLOTS + slot]
     is_dc = slot == 0
-    codes = np.where(is_dc, dc_code[syms], ac_code[syms])
-    lens = np.where(is_dc, dc_len[syms], ac_len[syms])
+    if dense.classes == rle.ONE_CLASS:
+        dc_code, dc_len = huffman.encoder_luts(dc_table)
+        ac_code, ac_len = huffman.encoder_luts(ac_table)
+        codes = np.where(is_dc, dc_code[syms], ac_code[syms])
+        lens = np.where(is_dc, dc_len[syms], ac_len[syms])
+    else:
+        dc_code, dc_len = rle.class_luts(dc_table)
+        ac_code, ac_len = rle.class_luts(ac_table)
+        cls = rle.block_classes(dense.classes, n)[row]
+        codes = np.where(is_dc, dc_code[cls, syms], ac_code[cls, syms])
+        lens = np.where(is_dc, dc_len[cls, syms], ac_len[cls, syms])
     if bool((lens == 0).any()):
         raise ValueError("symbol stream contains a symbol absent from "
                          "the Huffman table")
@@ -223,9 +253,7 @@ def encode_fields_dense(dense: DenseSymbols,
     return fields.reshape(-1), widths.reshape(-1)
 
 
-def encode_payload_dense(dense: DenseSymbols,
-                         dc_table: huffman.CanonicalTable,
-                         ac_table: huffman.CanonicalTable,
+def encode_payload_dense(dense: DenseSymbols, dc_table, ac_table,
                          packer=None) -> bytes:
     """Dense codeword lookup + bit packing; byte-identical to
     :func:`repro.core.entropy.rle.encode_payload` on the same stream."""

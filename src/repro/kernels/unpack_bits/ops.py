@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.core.entropy import bitio, huffman
+from repro.core.entropy import bitio, huffman, rle
 from repro.kernels.unpack_bits import kernel, ref
 
 # Above this many payload bits the stream decodes with the NumPy
@@ -47,12 +47,11 @@ def select_backend(backend: str = "auto") -> str:
     return backend
 
 
-def unpack_bits(payload: bytes, n_blocks: int,
-                dc_table: huffman.CanonicalTable,
-                ac_table: huffman.CanonicalTable, *,
+def unpack_bits(payload: bytes, n_blocks: int, dc_table, ac_table, *,
                 backend: str = "auto",
                 tile_bits: int | None = None,
-                interpret: bool | None = None) -> tuple:
+                interpret: bool | None = None,
+                classes: tuple = rle.ONE_CLASS) -> tuple:
     """Decode one entropy payload into ``(dc_diff, ac)`` coefficients.
 
     Same contract as :func:`repro.core.entropy.rle.decode_payload`
@@ -72,17 +71,25 @@ def unpack_bits(payload: bytes, n_blocks: int,
             way.
         interpret: Pallas interpret-mode override (None = interpret
             exactly when no TPU is present); ignored by "numpy".
+        classes: the table-class pattern (:mod:`repro.core.entropy.rle`);
+            with more than one class, ``dc_table``/``ac_table`` hold one
+            table per class and the device stages each class's words.
 
     Returns:
         ``(dc_diff (n_blocks,) int32, ac (n_blocks, 63) int32)``,
         identical across backends and across every ``tile_bits``.
     """
     if select_backend(backend) == "numpy":
-        with obs.route("unpack", "host", blocks=n_blocks):
+        with _host_route(n_blocks, classes):
             return ref.unpack_bits_ref(payload, n_blocks, dc_table,
-                                       ac_table)
+                                       ac_table, classes=classes)
     return _unpack_device(payload, n_blocks, dc_table, ac_table, interpret,
-                          tile_bits)
+                          tile_bits, classes)
+
+
+def _host_route(n_blocks: int, classes: tuple):
+    return obs.route("unpack", "host", blocks=n_blocks,
+                     table_classes=max(classes) + 1)
 
 
 def make_unpacker(backend: str = "auto", interpret: bool | None = None,
@@ -139,11 +146,9 @@ def table_params(table: huffman.CanonicalTable) -> tuple:
     return params, syms
 
 
-def _unpack_device(payload: bytes, n_blocks: int,
-                   dc_table: huffman.CanonicalTable,
-                   ac_table: huffman.CanonicalTable,
-                   interpret: bool | None,
-                   tile_bits: int | None = None) -> tuple:
+def _unpack_device(payload: bytes, n_blocks: int, dc_table, ac_table,
+                   interpret: bool | None, tile_bits: int | None = None,
+                   classes: tuple = rle.ONE_CLASS) -> tuple:
     """Host orchestration of the device speculative decode.
 
     The kernel stages unit words for every bit offset and the walk
@@ -156,20 +161,18 @@ def _unpack_device(payload: bytes, n_blocks: int,
     from repro.kernels import common
     if interpret is None:
         interpret = common.interpret_default()
-    if dc_table.symbols and max(dc_table.symbols) > ref.MAX_CATEGORY:
-        raise ValueError(f"DC table codes symbol {max(dc_table.symbols)} "
-                         f"> {ref.MAX_CATEGORY}: not a magnitude-category "
-                         f"alphabet")
+    rle.check_dc_tables(rle.table_sets(dc_table, ac_table)[0])
     if n_blocks == 0:
         return (np.zeros(0, np.int32), np.zeros((0, ref.AC_LEN), np.int32))
     nbits = len(payload) * 8
     if nbits == 0 or nbits > MAX_DEVICE_BITS:
-        with obs.route("unpack", "host", blocks=n_blocks):
+        with _host_route(n_blocks, classes):
             return ref.unpack_bits_ref(payload, n_blocks, dc_table,
-                                       ac_table)
-    with obs.device_route("unpack", interpret, blocks=n_blocks):
+                                       ac_table, classes=classes)
+    with obs.device_route("unpack", interpret, blocks=n_blocks,
+                          table_classes=max(classes) + 1):
         return _unpack_staged(payload, nbits, n_blocks, dc_table, ac_table,
-                              interpret, tile_bits)
+                              interpret, tile_bits, classes)
 
 
 def stage(payload: bytes, nbits: int, dc_table: huffman.CanonicalTable,
@@ -199,19 +202,33 @@ def stage(payload: bytes, nbits: int, dc_table: huffman.CanonicalTable,
         return (win,) + tuple(jax.device_get(staged))
 
 
-def _unpack_staged(payload: bytes, nbits: int, n_blocks: int,
-                   dc_table: huffman.CanonicalTable,
-                   ac_table: huffman.CanonicalTable, interpret: bool,
-                   tile_bits: int | None) -> tuple:
+def _unpack_staged(payload: bytes, nbits: int, n_blocks: int, dc_table,
+                   ac_table, interpret: bool, tile_bits: int | None,
+                   classes: tuple = rle.ONE_CLASS) -> tuple:
     """Stage on the device, then resolve the chain on the host."""
-    win, dcw, acw, outc = stage(payload, nbits, dc_table, ac_table,
-                                interpret)
+    if classes == rle.ONE_CLASS:
+        win, dcw, acw, outc = stage(payload, nbits, dc_table, ac_table,
+                                    interpret)
+        n = dcw.size
+
+        def get_tile(t):
+            t0 = t * tile_bits          # outcomes hold absolute offsets
+            return dcw[t0:], acw[t0:], outc[t0:]
+        extra = ()
+    else:
+        # one device stage per table class: each class's unit words and
+        # chain outcomes over the whole payload
+        staged = [stage(payload, nbits, d, a, interpret)
+                  for d, a in zip(*rle.table_sets(dc_table, ac_table))]
+        win = staged[0][0]
+        n = staged[0][1].size
+
+        def get_tile(t):
+            t0 = t * tile_bits
+            return tuple([s[k][t0:] for s in staged] for k in (1, 2, 3))
+        extra = (classes,)
     if tile_bits is None:
-        tile_bits = dcw.size            # one tile covers the payload
-
-    def get_tile(t):
-        t0 = t * tile_bits              # outcomes hold absolute offsets
-        return dcw[t0:], acw[t0:], outc[t0:]
-
+        tile_bits = n                   # one tile covers the payload
     with obs.span("entropy.resolve", tiles=-(-(nbits + 1) // tile_bits)):
-        return ref.resolve(win, nbits, n_blocks, tile_bits, get_tile)
+        return ref.resolve(win, nbits, n_blocks, tile_bits, get_tile,
+                           *extra)

@@ -47,13 +47,20 @@ every payload bit (see :func:`scratch_nbytes`).
 
 Bit-exact against ``decode_payload_reference`` on every stream, with
 the same error classes and messages on malformed input.
+
+A stream with more than one table class (a colour stream: block ``k``
+takes the tables of class ``classes[k % len(classes)]``, see
+:mod:`repro.core.entropy.rle`) stages unit words and outcomes once per
+class, and the resolver hops each block through its own class's words.
 """
 
 from __future__ import annotations
 
+import itertools
+
 import numpy as np
 
-from repro.core.entropy import bitio, huffman
+from repro.core.entropy import bitio, huffman, rle
 
 AC_LEN = 63                   # AC coefficients per 8x8 block
 MAX_CATEGORY = 15             # largest magnitude category (amplitude width)
@@ -167,20 +174,31 @@ def _ac_outcomes(ac_words: np.ndarray, t0: int) -> np.ndarray:
     return np.where(S < 63, t_out, c_out)
 
 
-def _emit_tile(win: np.ndarray, t0: int, dc_words: np.ndarray,
-               ac_words: np.ndarray, dc_starts: list, ac_starts: list,
-               block_ids: list, dc_out: np.ndarray,
-               ac_out: np.ndarray) -> None:
+def _emit_tile(win: np.ndarray, t0: int, dc_words: list, ac_words: list,
+               dc_starts: list, ac_starts: list, block_ids: list,
+               dc_out: np.ndarray, ac_out: np.ndarray,
+               classes: tuple) -> None:
     """Emit coefficient values for all blocks starting in one tile.
 
     DC amplitudes are gathered in one shot; AC units are emitted with a
     wavefront — every live block consumes one unit per step, so the
     loop runs at most 64 times however many blocks the tile holds.
     Amplitude bits are re-read from ``win`` at the resolved offsets
-    only (the unit words carry no values).
+    only (the unit words carry no values).  ``dc_words``/``ac_words``
+    hold one array per table class.
     """
-    def amplitude(p, words):
-        x = words[p - t0]
+    bids = np.asarray(block_ids, np.int64)
+    cls = (None if len(dc_words) == 1
+           else np.asarray(classes, np.int64)[bids % len(classes)])
+
+    def amplitude(p, words, cls):
+        if cls is None:
+            x = words[0][p - t0]
+        else:
+            x = np.empty(p.shape, np.int64)
+            for k, wk in enumerate(words):
+                m = cls == k
+                x[m] = wk[p[m] - t0]
         adv = x & _ADV_MASK
         c = (x >> _CTRL_SHIFT) - 2
         size = c & 0xF                     # c >= 0 for resolved units
@@ -190,15 +208,15 @@ def _emit_tile(win: np.ndarray, t0: int, dc_words: np.ndarray,
                        bits)
         return c, adv, np.where(size == 0, 0, val)
 
-    bids = np.asarray(block_ids, np.int64)
-    _, _, dc_val = amplitude(np.asarray(dc_starts, np.int64), dc_words)
+    _, _, dc_val = amplitude(np.asarray(dc_starts, np.int64), dc_words, cls)
     dc_out[bids] = dc_val.astype(np.int32)
 
     pos = np.zeros(len(bids), np.int64)
     p = np.asarray(ac_starts, np.int64)
     alive = np.ones(len(bids), bool)
     while alive.any():
-        c, adv, val = amplitude(p[alive], ac_words)
+        c, adv, val = amplitude(p[alive], ac_words,
+                                None if cls is None else cls[alive])
         eob = c == 0
         run = c >> 4
         coef = ~eob & (c != ZRL)
@@ -213,7 +231,7 @@ def _emit_tile(win: np.ndarray, t0: int, dc_words: np.ndarray,
 
 
 def resolve(win: np.ndarray, nbits: int, n_blocks: int, tile_bits: int,
-            get_tile) -> tuple:
+            get_tile, classes: tuple = rle.ONE_CLASS) -> tuple:
     """Resolve the true chain and emit values from staged tiles.
 
     ``get_tile(t)`` must return ``(dc_words, ac_words, outcomes)`` for
@@ -221,6 +239,9 @@ def resolve(win: np.ndarray, nbits: int, n_blocks: int, tile_bits: int,
     ``w >= min(tile_bits + MARGIN_BITS, nbits + 1 - t * tile_bits)`` —
     the stage is the parallel part; this resolver is the serial O(1)
     -per-block remainder, shared by the NumPy and Pallas backends.
+    With a ``classes`` pattern of more than one class, each of the three
+    is a sequence of one array per class, and block ``b`` hops through
+    the arrays of class ``classes[b % len(classes)]``.
 
     Raises exactly what ``rle.decode_payload`` raises, at the same bit
     offsets: :class:`repro.core.entropy.bitio.TruncatedStream` when a
@@ -229,23 +250,25 @@ def resolve(win: np.ndarray, nbits: int, n_blocks: int, tile_bits: int,
     """
     dc_out = np.zeros(n_blocks, np.int32)
     ac_out = np.zeros((n_blocks, AC_LEN), np.int32)
+    one = classes == rle.ONE_CLASS
     t = -1
     dcw = acw = outc = None
     dc_starts: list = []
     ac_starts: list = []
     block_ids: list = []
     p = 0
-    for b in range(n_blocks):
+    for b, k in zip(range(n_blocks), itertools.cycle(classes)):
         nt = p // tile_bits
         if nt != t:
             if block_ids:
                 _emit_tile(win, t * tile_bits, dcw, acw, dc_starts,
-                           ac_starts, block_ids, dc_out, ac_out)
+                           ac_starts, block_ids, dc_out, ac_out, classes)
                 dc_starts, ac_starts, block_ids = [], [], []
-            dcw, acw, outc = get_tile(nt)
+            dcw, acw, outc = ([a] for a in get_tile(nt)) if one else \
+                get_tile(nt)
             t = nt
         t0 = t * tile_bits
-        x = int(dcw[p - t0])
+        x = int(dcw[k][p - t0])
         c = (x >> _CTRL_SHIFT) - 2
         if c == -2:
             raise bitio.TruncatedStream(
@@ -253,7 +276,7 @@ def resolve(win: np.ndarray, nbits: int, n_blocks: int, tile_bits: int,
         if c == -1:
             raise ValueError(f"invalid DC Huffman prefix at bit {p}")
         q = p + (x & _ADV_MASK)
-        o = int(outc[q - t0])
+        o = int(outc[k][q - t0])
         kind = o & 3
         v = o >> 2
         if kind == _INVALID:
@@ -269,14 +292,13 @@ def resolve(win: np.ndarray, nbits: int, n_blocks: int, tile_bits: int,
         p = v
     if block_ids:
         _emit_tile(win, t * tile_bits, dcw, acw, dc_starts, ac_starts,
-                   block_ids, dc_out, ac_out)
+                   block_ids, dc_out, ac_out, classes)
     return dc_out, ac_out
 
 
-def unpack_bits_ref(payload: bytes, n_blocks: int,
-                    dc_table: huffman.CanonicalTable,
-                    ac_table: huffman.CanonicalTable, *,
-                    tile_bits: int = TILE_BITS) -> tuple:
+def unpack_bits_ref(payload: bytes, n_blocks: int, dc_table, ac_table, *,
+                    tile_bits: int = TILE_BITS,
+                    classes: tuple = rle.ONE_CLASS) -> tuple:
     """Staged NumPy decode of one entropy payload.
 
     Same contract as :func:`repro.core.entropy.rle.decode_payload`:
@@ -291,25 +313,30 @@ def unpack_bits_ref(payload: bytes, n_blocks: int,
         tile_bits: bit offsets staged per tile; any positive value
             decodes identically (tests shrink it to force blocks to
             straddle tile boundaries).
+        classes: the table-class pattern; with more than one class,
+            ``dc_table``/``ac_table`` hold one table per class.
     """
-    if dc_table.symbols and max(dc_table.symbols) > MAX_CATEGORY:
-        raise ValueError(f"DC table codes symbol {max(dc_table.symbols)} "
-                         f"> {MAX_CATEGORY}: not a magnitude-category "
-                         f"alphabet")
+    dc_tables, ac_tables = rle.table_sets(dc_table, ac_table)
+    rle.check_dc_tables(dc_tables)
     if n_blocks == 0:
         return np.zeros(0, np.int32), np.zeros((0, AC_LEN), np.int32)
     if tile_bits <= 0:
         raise ValueError(f"tile_bits must be positive, got {tile_bits}")
     nbits = len(payload) * 8
     win = bitio.bit_windows(payload)
-    dc_sym, dc_len = huffman.decoder_luts(dc_table)
-    ac_sym, ac_len = huffman.decoder_luts(ac_table)
+    luts = [(huffman.decoder_luts(d), huffman.decoder_luts(a))
+            for d, a in zip(dc_tables, ac_tables)]
 
     def get_tile(t):
         t0 = t * tile_bits
         w = min(tile_bits + MARGIN_BITS, nbits + 1 - t0)
-        dcw = _unit_words(win, nbits, t0, w, dc_sym, dc_len)
-        acw = _unit_words(win, nbits, t0, w, ac_sym, ac_len)
-        return dcw, acw, _ac_outcomes(acw, t0)
+        words = []
+        for (dc_sym, dc_len), (ac_sym, ac_len) in luts:
+            dcw = _unit_words(win, nbits, t0, w, dc_sym, dc_len)
+            acw = _unit_words(win, nbits, t0, w, ac_sym, ac_len)
+            words.append((dcw, acw, _ac_outcomes(acw, t0)))
+        if classes == rle.ONE_CLASS:
+            return words[0]
+        return tuple(zip(*words))
 
-    return resolve(win, nbits, n_blocks, tile_bits, get_tile)
+    return resolve(win, nbits, n_blocks, tile_bits, get_tile, classes)

@@ -33,6 +33,15 @@ The fused kernel reconstructs with the *matched* (adjoint) transform, so it
 only serves roundtrips whose semantics agree with it: ``transform="exact"``
 (both decode modes coincide) or ``mode="matched"``.  A standards-compliant
 decode of a CORDIC stream always takes the staged path.
+
+Colour images — a stacked ``(B, H, W, 3)`` uint8 batch or ``(H, W, 3)``
+entries of a ragged list — take the same path as baseline YCbCr 4:2:0
+(:mod:`repro.core.colour`): their own sharded programs
+(``_compress_sharded_colour``, ``_decompress_sharded_colour``) convert,
+subsample, transform and quantise three planes and interleave the
+levels into MCU order with zig-zag on the device, and the entropy stage
+writes ``DCTZ`` version-3 streams with two Huffman table classes.  A
+ragged list may mix grayscale and colour images: they bucket apart.
 """
 
 from __future__ import annotations
@@ -49,7 +58,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro import obs
-from repro.core import codec, cordic, metrics
+from repro.core import codec, colour, cordic, metrics
 from repro.launch import mesh as mesh_lib
 
 SHAPE_BUCKET = 64      # ragged H/W round up to this (multiple of the block)
@@ -68,10 +77,19 @@ def _n_workers(workers: int | None) -> int:
 
 @dataclasses.dataclass
 class CompressedGroup:
-    """Images sharing one padded bucket shape, compressed together."""
+    """Images sharing one padded bucket shape, compressed together.
+
+    A colour group holds, per image, its (bh/16, bw/16, 6, 64) levels:
+    MCUs of ``Y00 Y01 Y10 Y11 Cb Cr`` blocks, each in zig-zag order."""
     qcoeffs: jnp.ndarray           # (n, bh/8, bw/8, 8, 8) int32
     indices: tuple                 # positions in the original input order
     orig_shapes: tuple             # per-image (H, W) before padding
+    colour: bool = False
+
+    def grid(self, h: int, w: int) -> tuple:
+        """An image's own block (or, for colour, MCU) grid."""
+        return colour.mcu_grid(h, w) if self.colour else \
+            ((h + 7) // 8, (w + 7) // 8)
 
 
 @dataclasses.dataclass
@@ -118,14 +136,16 @@ class CompressedBatch:
     def _image_qcoeffs(self):
         """Per-image (gh, gw, 8, 8) levels in input order, cropped to
         each image's own block grid (ragged buckets carry padding
-        blocks that belong to no image)."""
+        blocks that belong to no image); a colour image's
+        (mh, mw, 6, 64) levels, cropped to its MCU grid."""
         out = [None] * self.n_images
         for g in self.groups:
             with obs.d2h(g.qcoeffs):
                 q = np.asarray(jax.device_get(g.qcoeffs))
             for j, (idx, (h, w)) in enumerate(zip(g.indices,
                                                   g.orig_shapes)):
-                out[idx] = (q[j, :(h + 7) // 8, :(w + 7) // 8], (h, w))
+                gh, gw = g.grid(h, w)
+                out[idx] = (q[j, :gh, :gw], (h, w), g.colour)
         return out
 
     def to_bytes_list(self, pipelined: bool = True,
@@ -179,17 +199,21 @@ class CompressedBatch:
         call = obs.current_call()
         if not pipelined:
             self._streams = (tables, [
-                _encode_image(call, i, entropy.encode_qcoeffs, q,
-                              q.shape[0] * q.shape[1], self.quality,
+                _encode_image(call, i, entropy.encode_colour_zigzag_host
+                              if rgb else entropy.encode_qcoeffs,
+                              q.reshape(-1, 64) if rgb else q,
+                              q.shape[0] * q.shape[1], rgb, self.quality,
                               self.transform, shape, tables=tables,
                               packer=packer, symbolizer=symbolizer)
-                for i, (q, shape) in enumerate(self._image_qcoeffs())])
-            obs.count("engine.images.encoded", self.n_images)
+                for i, (q, shape, rgb) in enumerate(self._image_qcoeffs())])
+            self._count_encoded()
             return list(self._streams[1])
         # dispatch the zig-zag for every bucket up front: jax queues the
         # device work asynchronously, so bucket k+1 computes while the
-        # pool below is still coding bucket k's streams
-        zs = [scan.zigzag_scan(g.qcoeffs) for g in self.groups]
+        # pool below is still coding bucket k's streams (a colour
+        # group's program already wrote its levels in zig-zag order)
+        zs = [g.qcoeffs if g.colour else scan.zigzag_scan(g.qcoeffs)
+              for g in self.groups]
         jobs: list = [None] * self.n_images
         with concurrent.futures.ThreadPoolExecutor(
                 _n_workers(workers)) as pool:
@@ -197,33 +221,44 @@ class CompressedBatch:
                 # blocks only on THIS bucket's device work
                 with obs.d2h(z):
                     znp = np.asarray(jax.device_get(z))
+                encode = (entropy.encode_colour_zigzag_host if g.colour
+                          else entropy.encode_zigzag_host)
                 for j, (idx, (h, w)) in enumerate(zip(g.indices,
                                                       g.orig_shapes)):
-                    gh, gw = (h + 7) // 8, (w + 7) // 8
+                    gh, gw = g.grid(h, w)
                     jobs[idx] = pool.submit(
-                        _encode_image, call, idx,
-                        entropy.encode_zigzag_host,
-                        znp[j, :gh, :gw].reshape(gh * gw, 64), gh * gw,
-                        self.quality, self.transform, (h, w),
+                        _encode_image, call, idx, encode,
+                        znp[j, :gh, :gw].reshape(-1, 64), gh * gw,
+                        g.colour, self.quality, self.transform, (h, w),
                         tables=tables, packer=packer,
                         symbolizer=symbolizer)
             self._streams = (tables, [f.result() for f in jobs])
-        obs.count("engine.images.encoded", self.n_images)
+        self._count_encoded()
         return list(self._streams[1])
 
+    def _count_encoded(self) -> None:
+        obs.count("engine.images.encoded", self.n_images)
+        n_colour = sum(len(g.indices) for g in self.groups if g.colour)
+        if n_colour:
+            obs.count("engine.images.colour.encoded", n_colour)
 
-def _encode_image(call: int, image: int, encode, levels, blocks: int,
-                  *args, **kwargs) -> bytes:
-    """``encode(levels, *args, **kwargs)`` in the image's span."""
+
+def _encode_image(call: int, image: int, encode, levels, units: int,
+                  rgb: bool, *args, **kwargs) -> bytes:
+    """``encode(levels, *args, **kwargs)`` in the image's span; ``units``
+    is its block count, or for a colour image its MCU count."""
+    blocks = units * colour.BLOCKS_PER_MCU if rgb else units
     with obs.span("entropy.encode_image", call=call, image=image,
-                  blocks=blocks):
+                  blocks=blocks, components=3 if rgb else 1, mcus=units):
         return encode(levels, *args, **kwargs)
 
 
 def _decode_image(decode, call: int, image: int, blob):
     """``decode(blob)`` in the image's span."""
+    from repro.core import entropy
+    components, mcus = entropy.stream_layout(blob)
     with obs.span("entropy.decode_image", call=call, image=image,
-                  stream_bytes=len(blob)):
+                  stream_bytes=len(blob), components=components, mcus=mcus):
         return decode(blob)
 
 
@@ -252,6 +287,27 @@ def _pad_rows(n: int, n_dev: int) -> int:
 
 def _bucket_dim(d: int) -> int:
     return d + (-d) % SHAPE_BUCKET
+
+
+@functools.partial(jax.jit, static_argnames=("transform", "quality",
+                                             "cordic_config", "n_dev"))
+def _compress_sharded_colour(imgs, transform, quality, cordic_config, n_dev):
+    body = lambda x: colour.compress_batch_mcus(x, transform, quality,
+                                                cordic_config)
+    if n_dev == 1:
+        return body(imgs)
+    return _shard_data(body, n_dev)(imgs)
+
+
+@functools.partial(jax.jit, static_argnames=("transform", "quality",
+                                             "cordic_config", "n_dev"))
+def _decompress_sharded_colour(z, transform, quality, cordic_config,
+                               n_dev):
+    body = lambda q: colour.decompress_batch_mcus(q, transform, quality,
+                                                  cordic_config)
+    if n_dev == 1:
+        return body(z)
+    return _shard_data(body, n_dev)(z)
 
 
 @functools.partial(jax.jit, static_argnames=("transform", "quality",
@@ -318,44 +374,52 @@ def _n_images(imgs) -> int:
 
 
 def _group_inputs(imgs):
-    """Yield (stacked_padded_uint8, indices, orig_shapes) bucket groups.
+    """Yield (stacked_padded_uint8, indices, orig_shapes, colour) bucket
+    groups.
 
     A stacked (B, H, W) array is one group padded to the 8-block like the
-    single-image API.  A ragged list buckets each image's H/W up to
-    SHAPE_BUCKET and groups equal buckets so B mixed sizes cost at most
-    O(#distinct buckets) compilations, not O(B).
+    single-image API, a stacked (B, H, W, 3) colour batch one group
+    padded to 16x16 MCUs.  A ragged list buckets each image's H/W up to
+    SHAPE_BUCKET and groups equal buckets (grayscale and colour apart)
+    so B mixed sizes cost at most O(#distinct buckets) compilations, not
+    O(B).
     """
     if isinstance(imgs, (np.ndarray, jnp.ndarray)):
         arr = _to_device(imgs)
-        if arr.ndim != 3:
-            raise ValueError(f"stacked batch must be (B, H, W), "
-                             f"got {arr.shape}")
+        rgb = arr.ndim == 4 and arr.shape[-1] == 3
+        if arr.ndim != 3 and not rgb:
+            raise ValueError(f"stacked batch must be (B, H, W) or "
+                             f"(B, H, W, 3), got {arr.shape}")
         if arr.shape[0] == 0:
             raise ValueError("empty batch: nothing to compress")
-        h, w = arr.shape[-2:]
-        padded = codec.pad_to_block(arr)
+        h, w = arr.shape[1:3]
+        padded = colour.pad_to_mcu(arr) if rgb else codec.pad_to_block(arr)
         return [(padded, tuple(range(arr.shape[0])),
-                 tuple((h, w) for _ in range(arr.shape[0])))], True
+                 tuple((h, w) for _ in range(arr.shape[0])), rgb)], True
 
     if not len(imgs):
         raise ValueError("empty batch: nothing to compress")
     buckets: dict = {}
     for i, im in enumerate(imgs):
         im = _to_device(im)
-        if im.ndim != 2:
-            raise ValueError(f"image {i} must be 2-D (H, W), got {im.shape}")
-        h, w = im.shape
-        key = (_bucket_dim(h), _bucket_dim(w))
+        rgb = colour.is_colour(im) and im.ndim == 3
+        if im.ndim != 2 and not rgb:
+            raise ValueError(f"image {i} must be (H, W) or (H, W, 3), "
+                             f"got {im.shape}")
+        h, w = im.shape[:2]
+        key = (_bucket_dim(h), _bucket_dim(w), rgb)
         buckets.setdefault(key, []).append((i, im))
 
     groups = []
-    for (bh, bw), members in buckets.items():
+    for (bh, bw, rgb), members in buckets.items():
+        chan = [(0, 0)] if rgb else []
         padded = jnp.stack([
-            jnp.pad(im, ((0, bh - im.shape[0]), (0, bw - im.shape[1])),
-                    mode="edge") for _, im in members])
+            jnp.pad(im, [(0, bh - im.shape[0]), (0, bw - im.shape[1])]
+                    + chan, mode="edge") for _, im in members])
         groups.append((padded,
                        tuple(i for i, _ in members),
-                       tuple(tuple(im.shape) for _, im in members)))
+                       tuple(tuple(im.shape[:2]) for _, im in members),
+                       rgb))
     return groups, False
 
 
@@ -363,7 +427,8 @@ def _reassemble(per_group: list, groups: list, n: int, stacked: bool):
     """Scatter per-group outputs back to original input order."""
     with obs.span("engine.reassemble"):
         out = [None] * n
-        for imgs_out, (_, indices, orig_shapes) in zip(per_group, groups):
+        for imgs_out, (_, indices, orig_shapes, *_) in zip(per_group,
+                                                          groups):
             for j, (idx, (h, w)) in enumerate(zip(indices, orig_shapes)):
                 out[idx] = imgs_out[j, :h, :w]
         if stacked:
@@ -379,33 +444,39 @@ def compress_batch(imgs, quality: int = 50,
                    transform: codec.Transform = "exact",
                    cordic_config: cordic.CordicConfig = cordic.PAPER_CONFIG
                    ) -> CompressedBatch:
-    """Compress a (B, H, W) batch or ragged list of grayscale images.
+    """Compress a (B, H, W) batch or ragged list of grayscale images,
+    or colour (H, W, 3) RGB ones.
 
     Args:
         imgs: either a stacked (B, H, W) uint8/float array (one compiled
-            shape) or a list of 2-D (H, W) images of mixed sizes; ragged
-            sizes bucket up to multiples of :data:`SHAPE_BUCKET` and
-            equal buckets are compressed together.
+            shape), a stacked (B, H, W, 3) colour batch, or a list of
+            (H, W) or (H, W, 3) images of mixed sizes; ragged sizes
+            bucket up to multiples of :data:`SHAPE_BUCKET` and equal
+            buckets are compressed together.
         quality: JPEG quality factor in [1, 100].
         transform: encoder transform, see :data:`repro.core.codec.Transform`.
         cordic_config: CORDIC config for ``transform == "cordic"``.
 
     Returns:
         A :class:`CompressedBatch` whose groups hold (n, bh/8, bw/8, 8, 8)
-        int32 quantised levels per bucket shape, plus the bookkeeping to
-        restore input order and crop back to original sizes.
+        int32 quantised levels per bucket shape (colour groups: (n,
+        bh/16, bw/16, 6, 64) interleaved zig-zag levels), plus the
+        bookkeeping to restore input order and crop back to original
+        sizes.
     """
     with obs.span("engine.compress"):
         groups, stacked = _group_inputs(imgs)
-        fn = functools.partial(_compress_sharded, transform=transform,
-                               quality=quality, cordic_config=cordic_config)
         out = []
         n = 0
-        for padded, indices, orig_shapes in groups:
+        for padded, indices, orig_shapes, rgb in groups:
+            fn = functools.partial(
+                _compress_sharded_colour if rgb else _compress_sharded,
+                transform=transform, quality=quality,
+                cordic_config=cordic_config)
             q = _run_batched(
                 lambda a, nd: fn(a, n_dev=nd), padded)
             out.append(CompressedGroup(qcoeffs=q, indices=indices,
-                                       orig_shapes=orig_shapes))
+                                       orig_shapes=orig_shapes, colour=rgb))
             n += len(indices)
     return CompressedBatch(groups=out, n_images=n, quality=quality,
                            transform=transform, cordic_config=cordic_config,
@@ -426,15 +497,19 @@ def decompress_batch(cb: CompressedBatch, mode: str = "standard"):
 
     Returns:
         (B, H, W) uint8 array when the input was stacked, else a list of
-        (H, W) uint8 arrays, each cropped to its original size.
+        (H, W) uint8 arrays, each cropped to its original size; colour
+        images come back (H, W, 3).
     """
     dec_transform = "exact" if mode == "standard" else cb.transform
-    fn = functools.partial(_decompress_sharded, transform=dec_transform,
-                           quality=cb.quality,
-                           cordic_config=cb.cordic_config)
     with obs.span("engine.inverse"):
-        per_group = [_run_batched(lambda a, nd: fn(a, n_dev=nd), g.qcoeffs)
-                     for g in cb.groups]
+        per_group = []
+        for g in cb.groups:
+            fn = functools.partial(
+                _decompress_sharded_colour if g.colour
+                else _decompress_sharded, transform=dec_transform,
+                quality=cb.quality, cordic_config=cb.cordic_config)
+            per_group.append(_run_batched(lambda a, nd: fn(a, n_dev=nd),
+                                          g.qcoeffs))
     groups = [(None, g.indices, g.orig_shapes) for g in cb.groups]
     return _reassemble(per_group, groups, cb.n_images, cb.stacked)
 
@@ -480,14 +555,21 @@ def roundtrip_batch(imgs, quality: int = 50,
                           with_psnr)
 
 
+def _has_colour(imgs) -> bool:
+    if isinstance(imgs, (np.ndarray, jnp.ndarray)):
+        return imgs.ndim == 4
+    return any(np.ndim(im) == 3 for im in imgs)
+
+
 def _roundtrip(imgs, quality, transform, cordic_config, mode, with_psnr):
-    if _fused_ok(transform, mode):
+    # the fused kernel codes grayscale planes only
+    if _fused_ok(transform, mode) and not _has_colour(imgs):
         obs.count("engine.roundtrip.fused")
         groups, stacked = _group_inputs(imgs)
         fn = functools.partial(_fused_roundtrip_sharded, transform=transform,
                                quality=quality, cordic_config=cordic_config)
         per_group = [_run_batched(lambda a, nd: fn(a, n_dev=nd), padded)
-                     for padded, _, _ in groups]
+                     for padded, *_ in groups]
         n = sum(len(g[1]) for g in groups)
         rec = _reassemble(per_group, groups, n, stacked)
     else:
@@ -533,7 +615,9 @@ def encode_batch(imgs, quality: int = 50,
 
     Args:
         imgs: stacked (B, H, W) array or ragged list of (H, W) images,
-            as in :func:`compress_batch`.
+            as in :func:`compress_batch`; colour images, stacked (B, H,
+            W, 3) or (H, W, 3) entries of a list, are coded as ``DCTZ``
+            version-3 YCbCr 4:2:0 streams.
         quality: JPEG quality factor in [1, 100].
         transform: encoder transform ("exact"/"cordic"/"loeffler").
         cordic_config: CORDIC config for ``transform == "cordic"``.
@@ -625,7 +709,9 @@ def decode_batch(blobs, mode: str = "standard",
     Returns:
         List of (H, W) uint8 reconstructions in input order, each
         bit-identical to the single-image
-        ``codec.decompress(CompressedImage.from_bytes(blob), mode)``.
+        ``codec.decompress(CompressedImage.from_bytes(blob), mode)``;
+        (H, W, 3) uint8 RGB for a colour stream, bit-identical to
+        ``entropy.decode_image(blob, mode)``.
 
     Raises:
         repro.core.entropy.BitstreamError: any malformed stream (the
@@ -691,22 +777,36 @@ def _decode(blobs, decode_one, call, mode, pipelined, workers, executor):
         for i, (z, hdr) in enumerate(decoded):
             dec_transform = ("exact" if mode == "standard"
                              else hdr["transform"])
-            grid = ((hdr["height"] + 7) // 8, (hdr["width"] + 7) // 8)
-            key = (grid, hdr["quality"], dec_transform)
+            rgb = hdr.get("components", 1) == 3
+            grid = (colour.mcu_grid(hdr["height"], hdr["width"]) if rgb
+                    else ((hdr["height"] + 7) // 8, (hdr["width"] + 7) // 8))
+            key = (grid, hdr["quality"], dec_transform, rgb)
             buckets.setdefault(key, []).append(i)
 
         out = [None] * len(blobs)
-        for ((gh, gw), quality, dec_transform), members in buckets.items():
+        n_colour = 0
+        for ((gh, gw), quality, dec_transform, rgb), members in \
+                buckets.items():
             zs = [decoded[i][0] for i in members]
             with obs.h2d(*zs):
                 stackz = jnp.stack([jnp.asarray(z) for z in zs])
-            # device half of the inverse: un-zig-zag the whole group at once
-            stackq = scan.zigzag_unscan(stackz).reshape(-1, gh, gw, 8, 8)
-            fn = functools.partial(_decompress_sharded,
-                                   transform=dec_transform, quality=quality,
-                                   cordic_config=cordic.PAPER_CONFIG)
+            if rgb:
+                # the colour program un-zig-zags and de-interleaves itself
+                stackq = stackz.reshape(-1, gh, gw, colour.BLOCKS_PER_MCU,
+                                        64)
+                n_colour += len(members)
+            else:
+                # device half of the inverse: un-zig-zag the whole group
+                stackq = scan.zigzag_unscan(stackz).reshape(-1, gh, gw, 8,
+                                                            8)
+            fn = functools.partial(
+                _decompress_sharded_colour if rgb else _decompress_sharded,
+                transform=dec_transform, quality=quality,
+                cordic_config=cordic.PAPER_CONFIG)
             rec = _run_batched(lambda a, nd: fn(a, n_dev=nd), stackq)
             for j, i in enumerate(members):
                 hdr = decoded[i][1]
                 out[i] = rec[j, :hdr["height"], :hdr["width"]]
+    if n_colour:
+        obs.count("engine.images.colour.decoded", n_colour)
     return out

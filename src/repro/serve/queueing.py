@@ -148,8 +148,10 @@ class BatchPlanner:
     # -- observation ------------------------------------------------------
 
     def bucket_key(self, shape: tuple, quality: int) -> tuple:
-        """Queue key: requests batch only within equal buckets."""
-        return (shape_bucket(shape[0], shape[1], self.bucket), quality)
+        """Queue key: requests batch only within equal buckets (a colour
+        image's channel axis keeps it apart from grayscale ones)."""
+        return (shape_bucket(shape[0], shape[1], self.bucket)
+                + tuple(shape[2:]), quality)
 
     def step_estimate(self, key: tuple) -> float:
         """Current model-step EWMA for a bucket (seconds)."""

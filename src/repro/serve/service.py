@@ -482,8 +482,9 @@ class CodecService:
         """Encode one image to a ``DCTZ`` stream under the service SLOs.
 
         Args:
-            image: 2-D (H, W) uint8 array (anything ``np.asarray``
-                accepts).
+            image: 2-D (H, W) grayscale or (H, W, 3) RGB uint8 array
+                (anything ``np.asarray`` accepts); colour images are
+                coded as ``DCTZ`` version-3 YCbCr 4:2:0 streams.
             quality: requested JPEG quality (default
                 ``config.default_quality``); clamped by the tenant tier.
             tenant: tenant name — selects the
@@ -512,9 +513,9 @@ class CodecService:
             raise RuntimeError("service not started: use `async with "
                                "CodecService(...)` or await start()")
         image = np.asarray(image)
-        if image.ndim != 2:
-            raise ValueError(f"image must be 2-D (H, W), "
-                             f"got shape {image.shape}")
+        if image.ndim != 2 and image.shape[2:] != (3,):
+            raise ValueError(f"image must be 2-D (H, W) or colour "
+                             f"(H, W, 3), got shape {image.shape}")
         tier = self.config.tier(tenant)
         q = tier.resolve_quality(quality if quality is not None
                                  else self.config.default_quality)

@@ -207,7 +207,7 @@ class TestContainer:
         (lambda b: b"JUNK" + b[4:], "not a DCTZ"),
         (lambda b: b[:4] + bytes([99]) + b[5:], "version"),
         (lambda b: b[:7] + bytes([9]) + b[8:], "transform"),
-        (lambda b: b[:16] + bytes([3]) + b[17:], "table id"),
+        (lambda b: b[:16] + bytes([9]) + b[17:], "table id"),
         (lambda b: b[:len(b) - 8], "truncated payload"),
         (lambda b: b + b"x", "trailing"),
         (lambda b: b[:-4] + bytes([b[-4] ^ 0xFF]) + b[-3:], "CRC"),
@@ -399,12 +399,13 @@ class TestSharedTables:
     selection, and version negotiation against v1."""
 
     def test_registry_contents_are_canonical(self):
-        assert huffman.DEFAULT_TABLES.ids() == (1, 2)
-        dc = huffman.DEFAULT_TABLES.get(huffman.STANDARD_DC_LUMA_ID)
-        assert dc.symbols == tuple(range(12))
-        ac = huffman.DEFAULT_TABLES.get(huffman.STANDARD_AC_LUMA_ID)
-        assert len(ac.symbols) == 162
-        assert rle.EOB in ac.symbols and rle.ZRL in ac.symbols
+        assert huffman.DEFAULT_TABLES.ids() == (1, 2, 3, 4)
+        for dc_id, ac_id in huffman.STANDARD_IDS:
+            dc = huffman.DEFAULT_TABLES.get(dc_id)
+            assert dc.symbols == tuple(range(12))
+            ac = huffman.DEFAULT_TABLES.get(ac_id)
+            assert len(ac.symbols) == 162
+            assert rle.EOB in ac.symbols and rle.ZRL in ac.symbols
 
     def test_registry_validates(self):
         reg = huffman.TableRegistry()

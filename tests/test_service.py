@@ -97,8 +97,9 @@ def test_invalid_image_shape_raises_valueerror():
     async def go():
         async with CodecService(fast_config(),
                                 engine=EchoEngine()) as svc:
+            # (H, W, 3) is a colour image now; two channels are not
             with pytest.raises(ValueError, match="2-D"):
-                await svc.submit(np.zeros((4, 4, 3), dtype=np.uint8))
+                await svc.submit(np.zeros((4, 4, 2), dtype=np.uint8))
             # validation errors are caller bugs, not requests: they must
             # not count as submitted, or the conservation invariant
             # submitted == served + rejected + failed would break

@@ -94,6 +94,18 @@ def test_symbolize_compiles(shape, n_blocks):
     assert "tpu_custom_call" in hlo
 
 
+def test_symbolize_two_classes_compiles(shape):
+    # a colour stream: per-block table class, two histogram rows
+    from repro.kernels.symbolize import kernel, ops
+    n = ops.MAX_DEVICE_BLOCKS
+    hlo = _compile(
+        lambda d, a, r, c: kernel.symbolize_pallas(
+            d, a, r, c, tile_blocks=ops.TILE_BLOCKS, n_classes=2,
+            interpret=False),
+        shape((n, 1)), shape((n, 63)), shape((1,)), shape((n, 1)))
+    assert "tpu_custom_call" in hlo
+
+
 def test_pack_bits_compiles(shape):
     from repro.kernels.pack_bits import kernel, ops
     tile_bits = ops.TILE_BITS
@@ -127,3 +139,18 @@ def test_engine_compress_compiles(shape):
         lambda x: codec_engine._compress_sharded(
             x, "exact", 50, cordic.PAPER_CONFIG, 1),
         shape((8, 512, 512), jnp.uint8))
+
+
+@pytest.mark.parametrize("h,w", [(512, 768), (768, 512)])
+def test_engine_colour_programs_compile(shape, h, w):
+    # the Kodak sizes: 8 images per padded batch, as the colour cell runs
+    from repro.core import cordic
+    from repro.serve import codec_engine
+    _compile(
+        lambda x: codec_engine._compress_sharded_colour(
+            x, "exact", 75, cordic.PAPER_CONFIG, 1),
+        shape((8, h, w, 3), jnp.uint8))
+    _compile(
+        lambda z: codec_engine._decompress_sharded_colour(
+            z, "exact", 75, cordic.PAPER_CONFIG, 1),
+        shape((8, h // 16, w // 16, 6, 64)))
