@@ -90,6 +90,18 @@ def routes(delta: dict, stage: str) -> dict:
             if k.startswith(head) and ".device." not in k}
 
 
+def check_resolved_on_device(name: str, took: dict, dec: dict) -> None:
+    """Every stream unpacked on the device had its block chain resolved
+    there too (``entropy.resolve.device``), none on the host."""
+    unpacked = sum(n for how, n in took["unpack"].items() if how != "host")
+    resolved = routes(dec, "resolve")
+    print(f"     {name}: block chains resolved per route {resolved}")
+    check(resolved.get("device", 0) == unpacked and
+          not resolved.get("host"),
+          f"{name}: {unpacked} streams unpacked on the device, chains "
+          f"resolved {resolved}")
+
+
 # ---------------------------------------------------------------------------
 # Phases
 # ---------------------------------------------------------------------------
@@ -199,6 +211,7 @@ def phase_bytes() -> None:
                 check(by_route == {"pallas": len(imgs)},
                       f"{name}: {stage} took {by_route}, not the compiled "
                       f"Pallas kernel for every image")
+        check_resolved_on_device(name, took, dec)
         cb = eng.compress_batch(stacked, QUALITY)
         levels = cb._image_qcoeffs()
         ref_recs = eng.decompress_batch(cb)
@@ -291,6 +304,7 @@ def phase_colour() -> None:
               f"decode_batch {t_dec:.2f} s; images per route {took}")
     check(took["unpack"] == {"pallas": len(imgs)},
           f"colour unpack took {took['unpack']}, not the compiled kernel")
+    check_resolved_on_device("colour", took, dec)
     check(enc.get("engine.images.colour.encoded") == len(imgs) and
           dec.get("engine.images.colour.decoded") == len(imgs),
           "the colour counters did not count every image")

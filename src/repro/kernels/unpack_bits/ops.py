@@ -1,10 +1,13 @@
 """Routed public wrappers for the unpack_bits kernel.
 
 ``unpack_bits`` is the decode backend the entropy layer routes through
-via ``rle.decode_payload(unpacker=)``: the Pallas speculative-decode
-kernel on TPU, the staged NumPy reference everywhere else — the same
-backend-selection shape as :mod:`repro.kernels.pack_bits` on the
-encode side, and coefficient-identical output either way (CI-gated by
+via ``rle.decode_payload(unpacker=)``: on TPU one device program decodes
+the whole payload — unit words, chain walk, block-chain resolution and
+coefficient emission — and only ``(dc_diff, ac)`` comes back; the staged
+NumPy reference decodes everywhere else, and on the device route's
+guards.  The same backend-selection shape as
+:mod:`repro.kernels.pack_bits` on the encode side, and
+coefficient-identical output either way (CI-gated by
 ``bench_entropy_throughput --check-identical``).
 """
 
@@ -23,14 +26,35 @@ from repro.kernels.unpack_bits import kernel, ref
 # Above this many payload bits the stream decodes with the NumPy
 # reference.  VMEM does not bound it: the unit-word kernel streams
 # (16, 128) int32 blocks of bit windows (8 KiB in, 16 KiB out per
-# program) whatever the payload size, and the chain walk is element-wise
-# XLA.  The three flat int32 arrays the walk hands back for host
-# resolution are what grows, up to 24 B per payload bit after the pow2
-# bucketing.  Compiled for a TPU v5e at 2**20 bits (a ~128 KB payload;
-# 2**21 offsets), ``memory_analysis()`` gives the unit-word kernel
-# 8,392,704 B in and 16,777,728 B out, and the walk program 16,777,216 B
-# in, 25,166,336 B of outputs and 0 B of HBM temporaries.
+# program) whatever the payload size, the chain walk is element-wise
+# XLA and the resolver holds two windows of words per array in SMEM.
+# What grows is HBM: about 28 B per staged offset and class (windows,
+# two unit-word planes, outcomes, two value planes), offsets bucketed
+# to a power of two.  Compiled for a TPU v5e at 2**20 bits (a ~128 KB
+# payload; 2**21 offsets), ``memory_analysis()`` of the whole-stream
+# program ``kernel.unit_words_resolve`` gives 8,392,704 B in and 0 B of
+# HBM temporaries for one table class, 8,396,800 B in and 16,906,240 B
+# of temporaries for two (tests/test_tpu_compile.py).
 MAX_DEVICE_BITS = 1 << 20
+
+# Above this many blocks the device stages the payload and the host
+# resolves the chain (``ref.resolve``).  The resolver keeps every output
+# tile resident in VMEM, 256 B per block: 4 MiB at 2**14 blocks (a
+# 1024x1024 grayscale image; a Kodak colour photo holds 9,216), which
+# double-buffered stays inside the 16 MiB of scoped VMEM that Mosaic
+# grants a kernel on a v5e.  Compiled there at MAX_DEVICE_BITS,
+# ``memory_analysis()`` gives 2,098,176 B of output (the int16
+# coefficients and the error record) at 2**14 blocks and one class,
+# 1,180,672 B at 9,216 blocks and two.
+MAX_DEVICE_BLOCKS = 1 << 14
+
+# Table classes the device resolver takes: its SMEM windows shrink with
+# the classes (``kernel.RESOLVE_WORDS``) and must hold a block's reach.
+MAX_DEVICE_CLASSES = 2
+
+# Blocks per bucket of the resolver's output, so that a workload sees a
+# bounded set of compiled shapes.
+BLOCK_BUCKET = 1024
 
 BACKENDS = ("pallas", "numpy")
 
@@ -66,9 +90,10 @@ def unpack_bits(payload: bytes, n_blocks: int, dc_table, ac_table, *,
         backend: "auto" (Pallas on TPU, NumPy elsewhere), "pallas", or
             "numpy".
         tile_bits: bit offsets per host resolver tile; ``None``
-            resolves the whole payload as one tile.  Ignored by
-            "numpy".  The device stage covers the whole payload either
-            way.
+            resolves the chain and emits the values on the device (a
+            stream over ``MAX_DEVICE_BLOCKS`` or ``MAX_DEVICE_CLASSES``
+            resolves on the host, as one tile).  Ignored by "numpy".
+            The device stage covers the whole payload either way.
         interpret: Pallas interpret-mode override (None = interpret
             exactly when no TPU is present); ignored by "numpy".
         classes: the table-class pattern (:mod:`repro.core.entropy.rle`);
@@ -88,6 +113,9 @@ def unpack_bits(payload: bytes, n_blocks: int, dc_table, ac_table, *,
 
 
 def _host_route(n_blocks: int, classes: tuple):
+    """The unpack span of a stream decoded by the NumPy reference,
+    which resolves its chain on the host."""
+    obs.count("entropy.resolve.host")
     return obs.route("unpack", "host", blocks=n_blocks,
                      table_classes=max(classes) + 1)
 
@@ -151,12 +179,13 @@ def _unpack_device(payload: bytes, n_blocks: int, dc_table, ac_table,
                    classes: tuple = rle.ONE_CLASS) -> tuple:
     """Host orchestration of the device speculative decode.
 
-    The kernel stages unit words for every bit offset and the walk
-    program their chain outcomes, over the whole payload; chain
-    resolution and value emission are the shared O(1)-per-block host
-    stage (:func:`repro.kernels.unpack_bits.ref.resolve`).  Offsets are
-    bucketed to powers of two so a streaming workload sees a bounded
-    set of compiled shapes.
+    With ``tile_bits=None`` (the engine's route) one program decodes
+    the payload, chain and values included
+    (:func:`resolve_on_device`).  An explicit ``tile_bits``, or a stream
+    over the resolver's guards, stages on the device and resolves on
+    the host (:func:`_unpack_staged`).  Offsets are bucketed to powers
+    of two so a streaming workload sees a bounded set of compiled
+    shapes.
     """
     from repro.kernels import common
     if interpret is None:
@@ -171,8 +200,64 @@ def _unpack_device(payload: bytes, n_blocks: int, dc_table, ac_table,
                                        ac_table, classes=classes)
     with obs.device_route("unpack", interpret, blocks=n_blocks,
                           table_classes=max(classes) + 1):
+        if (tile_bits is None and n_blocks <= MAX_DEVICE_BLOCKS
+                and max(classes) < MAX_DEVICE_CLASSES):
+            return resolve_on_device(payload, nbits, n_blocks, dc_table,
+                                     ac_table, interpret, classes)
         return _unpack_staged(payload, nbits, n_blocks, dc_table, ac_table,
                               interpret, tile_bits, classes)
+
+
+def _windows(payload: bytes, nbits: int) -> tuple:
+    """``(win, win_pad)``: the payload's 16-bit windows, and a copy
+    padded with 0xFFFF to ``max(pow2(nbits + 1 + MAX_ADV), 2048)``
+    offsets."""
+    win = bitio.bit_windows(payload)
+    n_pad = max(_pow2(nbits + 1 + kernel.MAX_ADV), kernel.ROWS * kernel.LANES)
+    win_pad = np.full(n_pad, 0xFFFF, np.int32)
+    win_pad[:win.size] = win
+    return win, win_pad
+
+
+def _params(nbits: int, dc_table: huffman.CanonicalTable,
+            ac_table: huffman.CanonicalTable) -> np.ndarray:
+    """One class's ``kernel.N_PARAMS`` scalar-prefetch row."""
+    dc_params, dc_syms = table_params(dc_table)
+    ac_params, ac_syms = table_params(ac_table)
+    return np.concatenate([np.array([nbits], np.int32), dc_params,
+                           ac_params, dc_syms, ac_syms])
+
+
+def resolve_on_device(payload: bytes, nbits: int, n_blocks: int, dc_table,
+                      ac_table, interpret: bool,
+                      classes: tuple = rle.ONE_CLASS) -> tuple:
+    """Upload, decode and fetch one payload with ``unit_words_resolve``.
+
+    Only the int16 coefficients of the block bucket and the 3-word error
+    record cross back; a chain that stops early raises what
+    ``rle.decode_payload`` raises, from the record.
+    """
+    _, win_pad = _windows(payload, nbits)
+    params = np.concatenate(
+        [np.array([n_blocks], np.int32)]
+        + [_params(nbits, d, a)
+           for d, a in zip(*rle.table_sets(dc_table, ac_table))])
+    with obs.h2d(params, win_pad):
+        params_d = jnp.asarray(params)
+        win_d = jnp.asarray(win_pad.reshape(-1, kernel.LANES))
+    bucket = -(-n_blocks // BLOCK_BUCKET) * BLOCK_BUCKET
+    coefs, err = kernel.unit_words_resolve(
+        params_d, win_d, block_rows=bucket // kernel.GROUP, classes=classes,
+        interpret=interpret)
+    obs.launched("unpack", coefs)
+    obs.count("entropy.resolve.device")
+    with obs.d2h(coefs, err):
+        coefs, err = jax.device_get((coefs, err))
+    kind, block, bit = (int(v) for v in err)
+    if kind:
+        raise ref.chain_error(kind, block, bit, nbits)
+    z = coefs[:n_blocks].astype(np.int32)
+    return z[:, 0], z[:, 1:]
 
 
 def stage(payload: bytes, nbits: int, dc_table: huffman.CanonicalTable,
@@ -183,14 +268,8 @@ def stage(payload: bytes, nbits: int, dc_table: huffman.CanonicalTable,
     windows and flat int32 arrays covering offsets ``0 .. n - 1``,
     ``n = max(pow2(nbits + 1 + MAX_ADV), 2048)``.
     """
-    win = bitio.bit_windows(payload)
-    n_pad = max(_pow2(nbits + 1 + kernel.MAX_ADV), kernel.ROWS * kernel.LANES)
-    win_pad = np.full(n_pad, 0xFFFF, np.int32)
-    win_pad[:win.size] = win
-    dc_params, dc_syms = table_params(dc_table)
-    ac_params, ac_syms = table_params(ac_table)
-    params = np.concatenate([np.array([nbits], np.int32), dc_params,
-                             ac_params, dc_syms, ac_syms])
+    win, win_pad = _windows(payload, nbits)
+    params = _params(nbits, dc_table, ac_table)
     with obs.h2d(params, win_pad):
         params_d = jnp.asarray(params)
         win_d = jnp.asarray(win_pad.reshape(-1, kernel.LANES))
@@ -229,6 +308,6 @@ def _unpack_staged(payload: bytes, nbits: int, n_blocks: int, dc_table,
         extra = (classes,)
     if tile_bits is None:
         tile_bits = n                   # one tile covers the payload
-    with obs.span("entropy.resolve", tiles=-(-(nbits + 1) // tile_bits)):
+    with obs.route("resolve", "host", tiles=-(-(nbits + 1) // tile_bits)):
         return ref.resolve(win, nbits, n_blocks, tile_bits, get_tile,
                            *extra)

@@ -18,12 +18,15 @@ word" encoding (also produced by the Pallas kernel in
    speculative *AC chain* starting at ``p`` into one outcome word via
    pointer doubling over the per-position ``next`` array (6 squarings
    cover the at-most-64 units of a block).
-2. **resolve** (host, per block) — hop block starts through the
+2. **resolve** (per block) — hop block starts through the
    precomputed outcomes: each block costs O(1) lookups (one DC unit
    word + one AC chain outcome), after which coefficient values are
    emitted tile-by-tile with a vectorized wavefront over all blocks
    that start in the tile (every block advances one unit per step, at
-   most 64 steps, regardless of block count).
+   most 64 steps, regardless of block count).  :func:`resolve` is the
+   host's form and the oracle of the device's
+   (``kernel._resolve_kernel``, the engine's route on TPU); both stop at
+   the first broken block and name it with :func:`chain_error`.
 
 Unit word layout (int64 here, int32 in the kernel)::
 
@@ -83,6 +86,23 @@ _ADV_MASK = 0x3F
 
 # outcome kinds
 _OK, _INVALID, _TRUNCATED, _OVERRUN = 0, 1, 2, 3
+
+# what stops a block chain (the device resolver's error record, too); an
+# AC kind is its outcome kind plus 2
+ERR_DC_INVALID, ERR_DC_TRUNCATED = 1, 2
+ERR_AC_INVALID, ERR_AC_TRUNCATED, ERR_OVERRUN = 3, 4, 5
+
+
+def chain_error(kind: int, block: int, bit: int, nbits: int) -> Exception:
+    """The error ``rle.decode_payload`` raises when block ``block``'s
+    chain stops with ``kind`` at bit offset ``bit``."""
+    if kind in (ERR_DC_TRUNCATED, ERR_AC_TRUNCATED):
+        return bitio.TruncatedStream(
+            f"entropy payload truncated: needed bit {bit} of {nbits}")
+    if kind == ERR_OVERRUN:
+        return ValueError(f"corrupted stream: AC run overruns block {block}")
+    which = "DC" if kind == ERR_DC_INVALID else "AC"
+    return ValueError(f"invalid {which} Huffman prefix at bit {bit}")
 
 
 def scratch_nbytes(nbits: int, tile_bits: int = TILE_BITS) -> int:
@@ -238,7 +258,10 @@ def resolve(win: np.ndarray, nbits: int, n_blocks: int, tile_bits: int,
     bit offsets ``[t * tile_bits, t * tile_bits + w)`` with
     ``w >= min(tile_bits + MARGIN_BITS, nbits + 1 - t * tile_bits)`` —
     the stage is the parallel part; this resolver is the serial O(1)
-    -per-block remainder, shared by the NumPy and Pallas backends.
+    -per-block remainder, on the host: the NumPy backend's, and the
+    device stage's where the device resolver is not taken (an explicit
+    ``tile_bits``, a stream over its guards).  It is also the oracle of
+    the device resolver (``kernel._resolve_kernel``).
     With a ``classes`` pattern of more than one class, each of the three
     is a sequence of one array per class, and block ``b`` hops through
     the arrays of class ``classes[b % len(classes)]``.
@@ -270,22 +293,15 @@ def resolve(win: np.ndarray, nbits: int, n_blocks: int, tile_bits: int,
         t0 = t * tile_bits
         x = int(dcw[k][p - t0])
         c = (x >> _CTRL_SHIFT) - 2
-        if c == -2:
-            raise bitio.TruncatedStream(
-                f"entropy payload truncated: needed bit {p} of {nbits}")
-        if c == -1:
-            raise ValueError(f"invalid DC Huffman prefix at bit {p}")
+        if c < 0:
+            raise chain_error(
+                ERR_DC_TRUNCATED if c == -2 else ERR_DC_INVALID, b, p, nbits)
         q = p + (x & _ADV_MASK)
         o = int(outc[k][q - t0])
         kind = o & 3
         v = o >> 2
-        if kind == _INVALID:
-            raise ValueError(f"invalid AC Huffman prefix at bit {v}")
-        if kind == _TRUNCATED:
-            raise bitio.TruncatedStream(
-                f"entropy payload truncated: needed bit {v} of {nbits}")
-        if kind == _OVERRUN:
-            raise ValueError(f"corrupted stream: AC run overruns block {b}")
+        if kind != _OK:     # AC invalid, truncated, overrun: in order
+            raise chain_error(kind + 2, b, v, nbits)
         dc_starts.append(p)
         ac_starts.append(q)
         block_ids.append(b)

@@ -18,15 +18,18 @@ per-block, per-tile or per-symbol loop. Names and stats (docs/serving.md,
   each image's entropy stage, on whichever thread codes it, and inside
   them ``entropy.symbolize``/``pack``/``unpack`` (``route``),
   ``entropy.tables``, ``entropy.payload``, ``entropy.frame``,
-  ``entropy.parse``, ``entropy.resolve``;
+  ``entropy.parse``, ``entropy.resolve`` (``route``: the host resolver
+  only);
 * ``xfer.h2d`` / ``xfer.d2h`` (``nbytes``) around every explicit copy
   between host and device; a ``d2h`` span also covers the host's wait
   for the device work that produces what it copies.
 
 ``count(key, n)`` adds to process-wide counters (``counts()`` returns a
 snapshot): the route each entropy stage took per image
-(``entropy.<stage>.<pallas|interpret|host>``), the device of each
-entropy-kernel launch (``entropy.<stage>.device.<id>``), the roundtrip
+(``entropy.<stage>.<pallas|interpret|host>``), where each unpacked
+stream's block chain was resolved (``entropy.resolve.<device|host>``),
+the device of each entropy-kernel launch
+(``entropy.<stage>.device.<id>``), the roundtrip
 route per call (``engine.roundtrip.<fused|staged>``) and the images
 encoded and decoded (``engine.images.{encoded,decoded}``).
 

@@ -77,12 +77,28 @@ def test_pool_thread_spans_carry_the_callers_call_id(tmp_path):
                           if name == "repro.entropy.encode_image"}
 
 
-def test_device_decode_resolves_one_tile_per_image(tmp_path):
+def test_device_decode_resolves_one_tile_per_image(tmp_path, monkeypatch):
+    # the engine's route resolves every block chain on the device: one
+    # device route per image and no host resolver span; over the device
+    # resolver's block guard the host resolves one tile per image
+    from repro.kernels.unpack_bits import ops
     blobs = eng.encode_batch(_batch(3), 50)
-    with jax.profiler.trace(str(tmp_path)):
-        eng.decode_batch(blobs, unpack_backend="pallas", workers=2)
-    assert [st["tiles"] for _, name, _, _, st in _spans(tmp_path)
-            if name == "repro.entropy.resolve"] == [1, 1, 1]
+
+    def decode(where):
+        with jax.profiler.trace(str(tmp_path / where)):
+            moved = _delta(lambda: eng.decode_batch(
+                blobs, unpack_backend="pallas", workers=2))
+        return moved, [st for _, name, _, _, st in _spans(tmp_path / where)
+                       if name == "repro.entropy.resolve"]
+
+    moved, spans = decode("device")
+    assert moved["entropy.resolve.device"] == 3
+    assert "entropy.resolve.host" not in moved and spans == []
+    monkeypatch.setattr(ops, "MAX_DEVICE_BLOCKS", 0)
+    moved, spans = decode("host")
+    assert moved["entropy.resolve.host"] == 3
+    assert "entropy.resolve.device" not in moved
+    assert [(st["tiles"], st["route"]) for st in spans] == [(1, "host")] * 3
 
 
 def test_counters_are_exact_under_a_thread_pool():

@@ -132,6 +132,28 @@ def test_unpack_bits_compiles(shape):
     assert " gather(" not in hlo
 
 
+@pytest.mark.parametrize("n_blocks,classes", [
+    ("MAX_DEVICE_BLOCKS", (0,)),
+    (9216, (0, 0, 0, 0, 1, 1)),         # a Kodak photo: 4:2:0, two classes
+])
+def test_unpack_resolve_compiles(shape, n_blocks, classes):
+    # the engine's whole-stream decode at the payload guard: unit words,
+    # the chain walk, values, and the scalar resolver over SMEM windows
+    from repro.kernels.unpack_bits import kernel, ops
+    if n_blocks == "MAX_DEVICE_BLOCKS":
+        n_blocks = ops.MAX_DEVICE_BLOCKS
+    n = ops._pow2(ops.MAX_DEVICE_BITS + 1 + kernel.MAX_ADV)
+    n_cls = max(classes) + 1
+    hlo = _compile(
+        lambda p, w: kernel.unit_words_resolve(
+            p, w, block_rows=-(-n_blocks // kernel.GROUP), classes=classes,
+            interpret=False),
+        shape((1 + n_cls * kernel.N_PARAMS,)),
+        shape((n // kernel.LANES, kernel.LANES)))
+    assert hlo.count("tpu_custom_call") >= 2
+    assert " gather(" not in hlo
+
+
 def test_engine_compress_compiles(shape):
     from repro.core import cordic
     from repro.serve import codec_engine

@@ -11,7 +11,8 @@ import jax
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core.entropy import bitio, huffman, rle
+from repro import obs
+from repro.core.entropy import bitio, container, huffman, rle
 from repro.kernels import unpack_bits
 from repro.kernels.unpack_bits import ref as unpack_ref
 
@@ -340,6 +341,9 @@ class TestUnpackOneTile:
             payload, n_blocks, dc_t, ac_t))
 
     def test_resolves_one_tile(self, monkeypatch):
+        # the engine's route resolves on the device and never calls the
+        # host resolver; over the block guard the host resolves the
+        # payload as one tile, and an explicit tile_bits in several
         from repro.kernels.unpack_bits import ops
         seen = []
         real = unpack_ref.resolve
@@ -358,11 +362,168 @@ class TestUnpackOneTile:
         got = unpack_bits.unpack_bits(payload, 80, dc_t, ac_t,
                                       backend="pallas", interpret=True)
         np.testing.assert_array_equal(got[1], want[1])
+        assert seen == []
+        monkeypatch.setattr(ops, "MAX_DEVICE_BLOCKS", 79)
+        got = unpack_bits.unpack_bits(payload, 80, dc_t, ac_t,
+                                      backend="pallas", interpret=True)
+        np.testing.assert_array_equal(got[1], want[1])
         assert seen == [0]
         seen.clear()
         unpack_bits.unpack_bits(payload, 80, dc_t, ac_t, backend="pallas",
                                 tile_bits=256, interpret=True)
         assert len(set(seen)) > 1
+
+
+COLOUR = container.COLOUR_BLOCK_CLASSES
+
+
+def _encode_classes(dc_diff, ac, classes=COLOUR, std_tables=True):
+    """Blocks of a two-class stream -> (payload, dc_tables, ac_tables)."""
+    prep = rle.prepare_stream(np.asarray(dc_diff, np.int64),
+                              np.asarray(ac, np.int64), classes=classes)
+    if std_tables:
+        dcs = tuple(huffman.DEFAULT_TABLES.get(d)
+                    for d, _ in huffman.STANDARD_IDS)
+        acs = tuple(huffman.DEFAULT_TABLES.get(a)
+                    for _, a in huffman.STANDARD_IDS)
+    else:
+        dcs = tuple(huffman.build_table(f) for f in prep.dc_freq)
+        acs = tuple(huffman.build_table(f) for f in prep.ac_freq)
+    return prep.payload(dcs, acs), dcs, acs
+
+
+def _outcome(fn):
+    try:
+        dc, ac = fn()
+        return ("ok", np.asarray(dc, np.int32).tobytes(),
+                np.asarray(ac, np.int32).tobytes())
+    except (bitio.TruncatedStream, ValueError) as e:
+        return (type(e).__name__, str(e))
+
+
+def _moved(key, fn):
+    """``(fn(), how far counter ``key`` moved)``."""
+    before = obs.counts().get(key, 0)
+    out = fn()
+    return out, obs.counts().get(key, 0) - before
+
+
+class TestResolveOnDevice:
+    """The engine's route (``tile_bits=None``): the chain resolves and
+    the values are emitted on the device, in interpret mode here, with
+    the values and the errors (class and message) of ``ref.resolve``
+    and of ``rle.decode_payload``."""
+
+    @staticmethod
+    def _check(payload, n_blocks, dc_t, ac_t, classes=rle.ONE_CLASS):
+        extra = {} if classes == rle.ONE_CLASS else {"classes": classes}
+        got, moved = _moved("entropy.resolve.device", lambda: _outcome(
+            lambda: unpack_bits.unpack_bits(payload, n_blocks, dc_t, ac_t,
+                                            backend="pallas",
+                                            interpret=True, **extra)))
+        assert moved == 1
+        assert got == _outcome(lambda: unpack_ref.unpack_bits_ref(
+            payload, n_blocks, dc_t, ac_t, **extra))
+        assert got == _outcome(lambda: rle.decode_payload(
+            payload, n_blocks, dc_t, ac_t, **extra))
+        return got
+
+    @pytest.mark.parametrize("name", sorted(STAGE_STREAMS))
+    @pytest.mark.parametrize("n_blocks", [15, 16, 17])
+    def test_named_streams(self, name, n_blocks):
+        # either side of the 16 blocks of one output tile
+        self._check(STAGE_STREAMS[name][0], n_blocks,
+                    *STAGE_STREAMS[name][1:])
+
+    @given(st.integers(0, 2**31 - 1), st.integers(-2, 1))
+    @settings(max_examples=8, deadline=None)
+    def test_random_streams(self, seed, claim):
+        # valid streams, with one or two blocks too few or one too many
+        # claimed (the padding then has to fail as the reference fails)
+        rng = np.random.default_rng(seed)
+        n = int(rng.integers(3, 150))
+        dc, ac = _random_blocks(rng, n)
+        payload, dc_t, ac_t = _encode(dc, ac, std_tables=bool(n % 2))
+        got = self._check(payload, n + claim, dc_t, ac_t)
+        assert (got[0] == "ok") == (claim <= 0)
+
+    @given(st.binary(min_size=1, max_size=200), st.integers(1, 60))
+    @settings(max_examples=8, deadline=None)
+    def test_random_bytes(self, payload, n_blocks):
+        self._check(payload, n_blocks, huffman.STANDARD_DC_LUMA,
+                    huffman.STANDARD_AC_LUMA)
+
+    @pytest.mark.parametrize("n_blocks", [1023, 1024, 1025])
+    def test_block_bucket_edge(self, n_blocks):
+        # either side of the output's 1,024-block bucket, over a payload
+        # of several SMEM windows, so block chains cross window edges
+        from repro.kernels.unpack_bits import kernel, ops
+        rng = np.random.default_rng(n_blocks)
+        dc = rng.integers(-60, 61, n_blocks)
+        ac = np.zeros((n_blocks, 63), np.int64)
+        ac[:, :3] = rng.integers(-9, 10, (n_blocks, 3))
+        payload, dc_t, ac_t = _encode(dc, ac)
+        assert len(payload) * 8 > 2 * kernel.RESOLVE_WORDS
+        assert ops.BLOCK_BUCKET == 1024
+        got = self._check(payload, n_blocks, dc_t, ac_t)
+        assert got[0] == "ok"
+
+    @given(st.integers(0, 2**31 - 1), st.integers(-1, 1))
+    @settings(max_examples=6, deadline=None)
+    def test_two_class_streams(self, seed, claim):
+        # colour payloads: blocks take the tables of class
+        # (0, 0, 0, 0, 1, 1)[b % 6]
+        rng = np.random.default_rng(seed)
+        n = int(rng.integers(6, 120))      # both classes code blocks
+        dc, ac = _random_blocks(rng, n, hi=500)
+        payload, dcs, acs = _encode_classes(dc, ac, std_tables=bool(n % 2))
+        got = self._check(payload, n + claim, dcs, acs, COLOUR)
+        assert (got[0] == "ok") == (claim <= 0)
+
+    def test_two_class_windows_and_truncation(self):
+        # a two-class payload over several windows, whole and cut short
+        from repro.kernels.unpack_bits import kernel
+        rng = np.random.default_rng(21)
+        n = 700
+        dc, ac = _random_blocks(rng, n, hi=200)
+        payload, dcs, acs = _encode_classes(dc, ac, std_tables=False)
+        assert len(payload) * 8 > kernel.RESOLVE_WORDS
+        assert self._check(payload, n, dcs, acs, COLOUR)[0] == "ok"
+        for cut in (1, len(payload) // 3, len(payload) - 1):
+            assert self._check(payload[:cut], n, dcs, acs,
+                               COLOUR)[0] != "ok"
+
+    def test_routes_counted(self, monkeypatch):
+        # one resolve route per stream: the device on the engine's
+        # route; the host for an explicit tile_bits, over the block or
+        # class guard, and on the numpy backend
+        from repro.kernels.unpack_bits import ops
+        rng = np.random.default_rng(23)
+        dc, ac = _random_blocks(rng, 30)
+        payload, dc_t, ac_t = _encode(dc, ac)
+        cpayload, dcs, acs = _encode_classes(dc, ac)
+
+        def run(**kw):
+            p, d, a = (cpayload, dcs, acs) if "classes" in kw else \
+                (payload, dc_t, ac_t)
+            kw.setdefault("backend", "pallas")
+            before = obs.counts()
+            unpack_bits.unpack_bits(p, 30, d, a, interpret=True, **kw)
+            return {k: obs.counts().get(k, 0) - before.get(k, 0)
+                    for k in ("entropy.resolve.device",
+                              "entropy.resolve.host")}
+
+        device = {"entropy.resolve.device": 1, "entropy.resolve.host": 0}
+        host = {"entropy.resolve.device": 0, "entropy.resolve.host": 1}
+        assert run() == device
+        assert run(classes=COLOUR) == device
+        assert run(tile_bits=256) == host
+        assert run(backend="numpy") == host
+        monkeypatch.setattr(ops, "MAX_DEVICE_CLASSES", 1)
+        assert run(classes=COLOUR) == host
+        assert run() == device
+        monkeypatch.setattr(ops, "MAX_DEVICE_BLOCKS", 29)
+        assert run() == host
 
 
 class TestUnpackThroughContainer:
