@@ -13,8 +13,8 @@ streams; every routed unpack-bits backend (the staged speculative
 NumPy decode and the Pallas speculative kernel, interpret mode off-TPU)
 must decode coefficients identical to ``decode_payload_reference`` and
 reject truncated streams with the LUT walk's exact errors; and every
-routed symbolize backend (the fused dense NumPy pass and the Pallas
-symbolize kernel, interpret mode off-TPU) must match the scalar
+symbolize route (the host symbolizer and the Pallas symbolize kernel,
+interpret mode off-TPU) must match the scalar
 ``symbolize_reference`` oracle element-for-element — streams,
 histograms, payload bytes, RangeError messages, and whole framed
 ``DCTZ`` v1/v2 containers under every table policy — all on random
@@ -58,8 +58,8 @@ def main():
                          "NumPy reference AND every routed unpack-bits "
                          "backend decodes (and rejects malformed "
                          "streams) identically to the scalar decode "
-                         "oracle AND every routed symbolize backend "
-                         "(fused dense NumPy + Pallas kernel) matches "
+                         "oracle AND every symbolize route "
+                         "(host symbolizer + Pallas kernel) matches "
                          "the scalar symbolize oracle — streams, "
                          "histograms, payloads and framed DCTZ v1/v2 "
                          "containers — on random + adversarial blocks")
@@ -97,22 +97,19 @@ def main():
             continue
         us = {k: v["median_us"] for k, v in r.timings_us.items()}
         print(f"encode stages {args.size}x{args.size}: "
-              f"symbolize {us['stage_symbolize']:.0f}us "
-              f"(vectorized {us['stage_symbolize_vectorized']:.0f}us, "
-              f"{r.metrics['symbolize_speedup_vs_vectorized']:.2f}x), "
+              f"symbolize {us['stage_symbolize']:.0f}us, "
               f"tables {us['stage_table_choice']:.0f}us, "
               f"codeword {us['stage_codeword']:.0f}us, "
               f"pack {us['stage_pack']:.0f}us; "
               f"transfer {r.metrics['device_transfer_bytes_per_image']:.0f}B"
               f" device vs {r.metrics['host_transfer_bytes_per_image']:.0f}B"
               f" host ({r.metrics['transfer_reduction']:.1f}x)")
-    print("batch,enc_img_per_s,enc_img_per_s_serial,dec_img_per_s,"
-          "enc_mb_per_s,speedup_vs_reference")
+    print("batch,enc_img_per_s,dec_img_per_s,enc_mb_per_s,"
+          "speedup_vs_reference")
     for r in records:
         if "batch" not in r.params:
             continue
         print(f"{r.params['batch']},{r.metrics['enc_img_per_s']:.2f},"
-              f"{r.metrics['enc_img_per_s_serial']:.2f},"
               f"{r.metrics['dec_img_per_s']:.2f},"
               f"{r.metrics['enc_mb_per_s']:.2f},"
               f"{r.metrics['speedup_vs_reference']:.2f}")

@@ -102,18 +102,11 @@ def _entropy_workload(size: int):
     fields; the unpack sweep times the payload they packed into; the
     symbolize sweep re-symbolises the raw block arrays."""
     from repro.bench import cases
-    from repro.core.entropy import bitio, rle
+    from repro.core.entropy import dense
     (_, dc_diff, ac, payload, (dc_t, ac_t),
      n_blocks) = cases._entropy_stage_inputs(size)
-    syms = rle.symbolize(dc_diff, ac)
-    captured = {}
-
-    def cap(fields, widths):
-        captured["cl"] = (np.asarray(fields), np.asarray(widths))
-        return bitio.pack_bits(fields, widths)
-
-    rle.encode_payload(*syms, dc_t, ac_t, packer=cap)
-    codes, lengths = captured["cl"]
+    codes, lengths = dense.encode_fields_dense(
+        dense.symbolize_dense(dc_diff, ac), dc_t, ac_t)
     return codes, lengths, payload, (dc_t, ac_t), n_blocks, dc_diff, ac
 
 

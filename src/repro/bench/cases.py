@@ -22,6 +22,7 @@ not GTX-480 milliseconds (see PAPER.md and docs/benchmarks.md).
 
 from __future__ import annotations
 
+import functools
 import time
 
 import jax
@@ -294,16 +295,15 @@ ENTROPY_GRID = {
 def _entropy_stage_inputs(size: int, quality: int = QUALITY):
     """(z, dc_diff, ac, payload, tables, n_blocks) for one image's
     entropy-stage legs, derived once outside the timed region."""
-    from repro.core.entropy import huffman, rle, scan
+    from repro.core.entropy import dense, huffman, scan
     img = images.lena_like(size, size)
     c = codec.compress(img, quality)
     z = np.asarray(scan.block_stream(jnp.asarray(c.qcoeffs)))
     dc_diff = np.diff(z[:, 0].astype(np.int64), prepend=np.int64(0))
     ac = z[:, 1:].astype(np.int64)
-    syms = rle.symbolize(dc_diff, ac)
-    dc_freq, ac_freq = rle.symbol_frequencies(syms[0], syms[1])
-    dc_t, ac_t = huffman.build_table(dc_freq), huffman.build_table(ac_freq)
-    payload = rle.encode_payload(*syms, dc_t, ac_t)
+    d = dense.symbolize_dense(dc_diff, ac)
+    dc_t, ac_t = huffman.build_table(d.dc_freq), huffman.build_table(d.ac_freq)
+    payload = dense.encode_payload_dense(d, dc_t, ac_t)
     return z, dc_diff, ac, payload, (dc_t, ac_t), z.shape[0]
 
 
@@ -319,13 +319,12 @@ def reference_encode_stream(dc_diff, ac) -> bytes:
 
 
 def vectorized_encode_stream(dc_diff, ac) -> bytes:
-    """The production vectorized host path over the same inputs (whole-
-    array symbolisation, uncached tables for a fair comparison)."""
-    from repro.core.entropy import huffman, rle
-    syms = rle.symbolize(dc_diff, ac)
-    dc_freq, ac_freq = rle.symbol_frequencies(syms[0], syms[1])
-    return rle.encode_payload(*syms, huffman.build_table(dc_freq),
-                              huffman.build_table(ac_freq))
+    """The production host path over the same inputs (the dense
+    symbolizer, uncached tables for a fair comparison)."""
+    from repro.core.entropy import dense, huffman
+    d = dense.symbolize_dense(dc_diff, ac)
+    return dense.encode_payload_dense(d, huffman.build_table(d.dc_freq),
+                                      huffman.build_table(d.ac_freq))
 
 
 def entropy_throughput_points(size: int, batches, warmup: int,
@@ -335,8 +334,8 @@ def entropy_throughput_points(size: int, batches, warmup: int,
     One ``entropy_stage`` record times the host entropy stage in
     isolation on a single image — vectorized vs scalar-reference, both
     directions — and one ``encode_batch_{b}`` / ``decode_batch_{b}``
-    record per batch size drives the engine's overlapped byte path
-    (pipelined vs serial), scoring ``speedup_vs_reference`` against the
+    record per batch size drives the engine's overlapped byte path,
+    scoring ``speedup_vs_reference`` against the
     single-image reference end-to-end rate (device compress + scalar
     host coding), the PR 3 code shape.
 
@@ -374,27 +373,20 @@ def entropy_throughput_points(size: int, batches, warmup: int,
                  "enc_mb_per_s": mb / (t_enc_vec.median_us / 1e6),
                  "dec_mb_per_s": mb / (t_dec_vec.median_us / 1e6)})]
 
-    # per-stage encode breakdown: the fused dense pass split into its
+    # per-stage encode breakdown: the host symbolizer split into its
     # stages (symbolize incl. histograms / table choice / codeword
-    # lookup / bit pack), scored against the PR 4 vectorized host
-    # symbolisation on the same blocks, plus the host<->device traffic
-    # each symbolize routing implies (docs/benchmarks.md)
-    from repro.core.entropy import bitio, huffman
-    from repro.kernels.symbolize import ref as sref
+    # lookup / bit pack), plus the host<->device traffic each symbolize
+    # routing implies (docs/benchmarks.md)
+    from repro.core.entropy import bitio, dense, huffman
 
-    def vectorized_symbolize():
-        syms = rle.symbolize(dc_diff, ac)
-        return rle.symbol_frequencies(syms[0], syms[1])
-
-    dense = sref.symbolize_dense(dc_diff, ac)
-    fields, widths = sref.encode_fields_dense(dense, dc_t, ac_t)
-    t_sym = measure(sref.symbolize_dense, dc_diff, ac,
+    d = dense.symbolize_dense(dc_diff, ac)
+    fields, widths = dense.encode_fields_dense(d, dc_t, ac_t)
+    t_sym = measure(dense.symbolize_dense, dc_diff, ac,
                     warmup=warmup, iters=iters)
-    t_sym_vec = measure(vectorized_symbolize, warmup=warmup, iters=iters)
-    t_tab = measure(lambda: (huffman.build_table(dense.dc_freq),
-                             huffman.build_table(dense.ac_freq)),
+    t_tab = measure(lambda: (huffman.build_table(d.dc_freq),
+                             huffman.build_table(d.ac_freq)),
                     warmup=warmup, iters=iters)
-    t_cw = measure(sref.encode_fields_dense, dense, dc_t, ac_t,
+    t_cw = measure(dense.encode_fields_dense, d, dc_t, ac_t,
                    warmup=warmup, iters=iters)
     t_pack = measure(bitio.pack_bits, fields, widths,
                      warmup=warmup, iters=iters)
@@ -409,13 +401,10 @@ def entropy_throughput_points(size: int, batches, warmup: int,
                 "quality": QUALITY, "n_blocks": n_blocks,
                 "payload_nbytes": len(payload)},
         timings_us={"stage_symbolize": t_sym.to_json(),
-                    "stage_symbolize_vectorized": t_sym_vec.to_json(),
                     "stage_table_choice": t_tab.to_json(),
                     "stage_codeword": t_cw.to_json(),
                     "stage_pack": t_pack.to_json()},
         metrics={
-            "symbolize_speedup_vs_vectorized":
-                t_sym_vec.median_us / t_sym.median_us,
             "host_transfer_bytes_per_image": float(host_xfer),
             "device_transfer_bytes_per_image": float(device_xfer),
             "transfer_reduction": host_xfer / device_xfer,
@@ -427,7 +416,7 @@ def entropy_throughput_points(size: int, batches, warmup: int,
 
     def ref_encode_e2e():
         cb = codec_engine.compress_batch(img1, QUALITY)
-        cb._image_qcoeffs()                 # forces the device->host copy
+        jax.device_get(cb.groups[0].qcoeffs)    # the device->host copy
         return reference_encode_stream(dc_diff, ac)
 
     t_ref_e2e = measure(ref_encode_e2e, warmup=min(warmup, 1),
@@ -438,38 +427,24 @@ def entropy_throughput_points(size: int, batches, warmup: int,
         imgs = np.stack([images.lena_like(size, size, seed=i)
                          for i in range(b)])
 
-        def enc(pipelined):
-            return codec_engine.encode_batch(imgs, QUALITY,
-                                             pipelined=pipelined)
-
-        t_pipe = measure(enc, True, warmup=warmup, iters=iters)
-        t_ser = measure(enc, False, warmup=min(warmup, 1),
-                        iters=max(iters // 2, 2))
-        blobs = enc(True)
+        t_enc = measure(codec_engine.encode_batch, imgs, QUALITY,
+                        warmup=warmup, iters=iters)
+        blobs = codec_engine.encode_batch(imgs, QUALITY)
         nbytes = sum(len(x) for x in blobs)
-
-        def dec(pipelined):
-            return codec_engine.decode_batch(blobs, pipelined=pipelined)
-
-        t_dpipe = measure(dec, True, warmup=warmup, iters=iters)
-        t_dser = measure(dec, False, warmup=min(warmup, 1),
-                         iters=max(iters // 2, 2))
-        pipe_img_per_s = b / (t_pipe.median_us / 1e6)
+        t_dec = measure(codec_engine.decode_batch, blobs,
+                        warmup=warmup, iters=iters)
+        enc_img_per_s = b / (t_enc.median_us / 1e6)
         records.append(BenchRecord(
             label=f"batch_{b}",
             params={"batch": b, "height": size, "width": size,
                     "quality": QUALITY, "nbytes": nbytes},
-            timings_us={"encode_pipelined": t_pipe.to_json(),
-                        "encode_serial": t_ser.to_json(),
-                        "decode_pipelined": t_dpipe.to_json(),
-                        "decode_serial": t_dser.to_json()},
+            timings_us={"encode_pipelined": t_enc.to_json(),
+                        "decode_pipelined": t_dec.to_json()},
             metrics={
-                "enc_img_per_s": pipe_img_per_s,
-                "enc_img_per_s_serial": b / (t_ser.median_us / 1e6),
-                "dec_img_per_s": b / (t_dpipe.median_us / 1e6),
-                "dec_img_per_s_serial": b / (t_dser.median_us / 1e6),
-                "enc_mb_per_s": b * mb / (t_pipe.median_us / 1e6),
-                "speedup_vs_reference": pipe_img_per_s / ref_img_per_s,
+                "enc_img_per_s": enc_img_per_s,
+                "dec_img_per_s": b / (t_dec.median_us / 1e6),
+                "enc_mb_per_s": b * mb / (t_enc.median_us / 1e6),
+                "speedup_vs_reference": enc_img_per_s / ref_img_per_s,
             }))
     return records
 
@@ -501,7 +476,7 @@ def entropy_identity_violations(seed: int = 0, trials: int = 25) -> list:
     random batches (mixed density, full amplitude range) plus the
     :func:`adversarial_blocks`.
     """
-    from repro.core.entropy import huffman, rle
+    from repro.core.entropy import dense, huffman, rle
     rng = np.random.default_rng(seed)
     cases = []
     for t in range(trials):
@@ -515,7 +490,7 @@ def entropy_identity_violations(seed: int = 0, trials: int = 25) -> list:
 
     bad = []
     for name, dc, ac in cases:
-        vec = rle.symbolize(dc, ac)
+        vec = dense.dense_to_stream(dense.symbolize_dense(dc, ac))
         ref = rle.symbolize_reference(dc, ac)
         if not all(np.array_equal(a, b) for a, b in zip(vec, ref)):
             bad.append(f"{name}: symbol stream mismatch")
@@ -567,7 +542,7 @@ def packing_identity_violations(seed: int = 0, trials: int = 25) -> list:
         fields = rng.integers(0, 1 << 16, m)
         cases.append((f"random_{t}", fields, widths))
     for i, (dc, ac) in enumerate(adversarial_blocks()):
-        syms = rle.symbolize(dc, ac)
+        syms = rle.symbolize_reference(dc, ac)
         dc_f, ac_f = rle.symbol_frequencies(syms[0], syms[1])
         fields, widths = rle.codeword_fields(
             *syms, huffman.build_table(dc_f), huffman.build_table(ac_f))
@@ -586,7 +561,7 @@ def packing_identity_violations(seed: int = 0, trials: int = 25) -> list:
     # whole-stream check: the routed packer must frame identical DCTZ
     # containers under every table policy
     c = codec.compress(images.lena_like(32, 32), QUALITY)
-    packer = pb.make_packer(backend="pallas", interpret=None)
+    packer = functools.partial(pb.pack_bits, backend="pallas")
     for tables in ("auto", "embedded", "shared"):
         want = entropy.encode_qcoeffs(c.qcoeffs, QUALITY, "exact",
                                       (32, 32), tables=tables)
@@ -645,7 +620,7 @@ def unpack_identity_violations(seed: int = 0, trials: int = 25) -> list:
     ]
     bad = []
     for name, dc, ac in cases:
-        syms = rle.symbolize(dc, ac)
+        syms = rle.symbolize_reference(dc, ac)
         dc_f, ac_f = rle.symbol_frequencies(syms[0], syms[1])
         dc_t = huffman.build_table(dc_f)
         ac_t = huffman.build_table(ac_f)
@@ -668,7 +643,7 @@ def unpack_identity_violations(seed: int = 0, trials: int = 25) -> list:
     # whole-stream check: the routed unpacker must reproduce the
     # default decode of DCTZ containers under every table policy
     c = codec.compress(images.lena_like(32, 32), QUALITY)
-    unpacker = ub.make_unpacker(backend="pallas", interpret=None)
+    unpacker = functools.partial(ub.unpack_bits, backend="pallas")
     for tables in ("auto", "embedded", "shared"):
         stream = entropy.encode_qcoeffs(c.qcoeffs, QUALITY, "exact",
                                         (32, 32), tables=tables)
@@ -685,7 +660,7 @@ def symbolize_identity_violations(seed: int = 0, trials: int = 25) -> list:
     oracle — the symbolisation third of the ``--check-identical`` CI
     gate (must return []).
 
-    Checks, per case, that the staged dense NumPy pass
+    Checks, per case, that the host symbolizer
     (:func:`repro.kernels.symbolize.ref.symbolize_ref`) and the Pallas
     kernel (interpret mode off-TPU) produce symbol streams element- and
     dtype-identical to
@@ -700,7 +675,7 @@ def symbolize_identity_violations(seed: int = 0, trials: int = 25) -> list:
     alike) are byte-identical to the default path.
     """
     from repro.core import entropy
-    from repro.core.entropy import huffman, rle
+    from repro.core.entropy import dense, huffman, rle
     from repro.kernels import symbolize as sy
     from repro.kernels.symbolize import ref as sref
     rng = np.random.default_rng(seed)
@@ -719,8 +694,8 @@ def symbolize_identity_violations(seed: int = 0, trials: int = 25) -> list:
         ("pallas", lambda d, a: sy.symbolize(d, a, backend="pallas",
                                              interpret=None)),
     ]
-    preps = [(bname, sy.make_symbolizer(bname, interpret=None))
-             for bname in ("numpy", "pallas")]
+    preps = [("numpy", dense.prepare),
+             ("pallas", functools.partial(sy.prepare, backend="pallas"))]
     bad = []
     for name, dc, ac in cases:
         want = rle.symbolize_reference(dc, ac)
@@ -1631,7 +1606,7 @@ def roofline_points(size: int, entropy_size: int, warmup: int,
 
     Shared by the registry case and ``benchmarks/roofline.py``.
     """
-    from repro.core.entropy import rle
+    from repro.core.entropy import dense
     from repro.kernels import pack_bits as pb
     from repro.kernels import unpack_bits as ub
     from repro.kernels.cordic_loeffler import ops as cl_ops
@@ -1690,16 +1665,8 @@ def roofline_points(size: int, entropy_size: int, warmup: int,
 
     (_, dc_diff, ac, payload, (dc_t, ac_t),
      n_blocks) = _entropy_stage_inputs(entropy_size)
-    syms = rle.symbolize(dc_diff, ac)
-    from repro.core.entropy import bitio
-    captured = {}
-
-    def cap(fields, widths):
-        captured["cl"] = (np.asarray(fields), np.asarray(widths))
-        return bitio.pack_bits(fields, widths)
-
-    rle.encode_payload(*syms, dc_t, ac_t, packer=cap)
-    codes, lengths = captured["cl"]
+    codes, lengths = dense.encode_fields_dense(
+        dense.symbolize_dense(dc_diff, ac), dc_t, ac_t)
     nbits = len(payload) * 8
 
     # The bit kernels are pure data movement: FLOP content ~0, byte

@@ -97,12 +97,13 @@ def _entropy_table(result) -> str:
               if r.label.startswith("encode_stages_")]
     batches = [r for r in result.records if r.label.startswith("batch_")]
     lines = ["## Entropy throughput (vectorized host coding)", "",
-             "The host entropy stage (`repro.core.entropy.rle`) measured "
-             "against the scalar per-block reference it replaced, plus "
-             "the serving engine's overlapped byte path "
+             "The host entropy stage (`repro.core.entropy.dense` to "
+             "encode, the `rle` LUT walk to decode) measured against "
+             "the scalar per-block reference it replaced, plus the "
+             "serving engine's overlapped byte path "
              "(`encode_batch`/`decode_batch`: device DCT/quant for "
              "bucket *k+1* in flight while a thread pool entropy-codes "
-             "bucket *k*).  `speedup vs ref` scores the pipelined path "
+             "bucket *k*).  `speedup vs ref` scores the engine's encode "
              "against the single-image reference end-to-end encode "
              "rate — growth with batch size is the overlap win.", ""]
     for r in stage:
@@ -124,8 +125,7 @@ def _entropy_table(result) -> str:
     for r in stages:
         lines += [
             f"Per-stage encode breakdown {_size(r)} (staged pipeline; "
-            f"`symbolize` is the fused `kernels/symbolize` pass, scored "
-            f"against the PR 4 vectorized symbolise+histogram path; "
+            f"`symbolize` is the host symbolizer's fused pass; "
             f"transfer compares the coefficient bytes the host path "
             f"pulls per image against the histograms+payload the "
             f"device-resident TPU chain ships):", "",
@@ -133,10 +133,6 @@ def _entropy_table(result) -> str:
             "|---|---|",
             f"| symbolize (fused) | "
             f"{_ms(r.timings_us['stage_symbolize'])} |",
-            f"| symbolize (PR 4 vectorized) | "
-            f"{_ms(r.timings_us['stage_symbolize_vectorized'])} "
-            f"({r.metrics['symbolize_speedup_vs_vectorized']:.2f}x "
-            f"fused win) |",
             f"| table choice | {_ms(r.timings_us['stage_table_choice'])} |",
             f"| codeword lookup | {_ms(r.timings_us['stage_codeword'])} |",
             f"| bit packing | {_ms(r.timings_us['stage_pack'])} |", "",
@@ -148,14 +144,13 @@ def _entropy_table(result) -> str:
             f"{r.metrics['transfer_reduction']:.1f}x less traffic.", ""]
     if batches:
         lines += [
-            "| batch | enc img/s (pipelined) | enc img/s (serial) "
-            "| dec img/s | enc MB/s | speedup vs ref |",
-            "|---|---|---|---|---|---|"]
+            "| batch | enc img/s | dec img/s | enc MB/s "
+            "| speedup vs ref |",
+            "|---|---|---|---|---|"]
         for r in batches:
             lines.append(
                 f"| {r.params['batch']} "
                 f"| {r.metrics['enc_img_per_s']:.1f} "
-                f"| {r.metrics['enc_img_per_s_serial']:.1f} "
                 f"| {r.metrics['dec_img_per_s']:.1f} "
                 f"| {r.metrics['enc_mb_per_s']:.1f} "
                 f"| {r.metrics['speedup_vs_reference']:.2f}x |")

@@ -11,9 +11,8 @@ Modules:
   entropy   lossless bitstream tail (jax-free at import)
 
 Submodules load lazily (PEP 562): ``from repro.core import dct`` works
-exactly as before, but ``import repro.core.entropy`` no longer drags in
-the jax array stack — which is what lets the codec engine's
-process-pool decode workers spawn with a NumPy-only import footprint.
+exactly as before, but ``import repro.core.entropy`` does not drag in
+the jax array stack: the entropy stage's host halves stay NumPy-only.
 """
 
 _SUBMODULES = ("codec", "cordic", "dct", "entropy", "images", "loeffler",

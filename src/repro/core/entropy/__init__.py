@@ -6,8 +6,10 @@ bytes, not the :func:`repro.core.quant.estimate_bits` proxy:
 
 * :mod:`scan`      — zig-zag scan + DC differential, vectorised in JAX
   (vmappable per block; this half rides the accelerator),
-* :mod:`rle`       — run-length symbolisation of the zig-zag AC tail and
-  magnitude-category coding, NumPy at the host edge,
+* :mod:`rle`       — the run-length symbol alphabet, the scalar
+  oracles and the LUT-walk decoder, NumPy at the host edge,
+* :mod:`dense`     — the host symbolizer: one fused pass over a dense
+  per-block slot layout, the container's default ``symbolizer``,
 * :mod:`huffman`   — canonical, length-limited Huffman codes built from
   per-stream symbol frequencies, plus the shared-table registry
   (well-known ITU-T T.81 Annex K tables under ids >= 1),
@@ -20,15 +22,15 @@ bytes, not the :func:`repro.core.quant.estimate_bits` proxy:
   :func:`decode_image`.
 
 The encode path is a staged pipeline — symbolize -> table choice ->
-codeword lookup -> prefix-sum offsets -> scatter-pack — whose packing
-stage routes between the NumPy reference and the Pallas kernel
-(``packer`` argument on the encoders).  The stage is exactly lossless
+codeword lookup -> prefix-sum offsets -> scatter-pack.  The encoders
+take ``symbolizer``/``packer`` (and the decoders ``unpacker``) hooks
+that :mod:`repro.kernels` fills with the Pallas route on a TPU; left
+at ``None`` they are the host route.  The stage is exactly lossless
 over the quantised levels, so ``decode_image(encode_image(img, q))``
 reproduces the quantised round-trip reconstruction bit-exactly.  The
 byte layout a third-party decoder needs is specified in
 ``docs/bitstream.md``.  This package (and the host halves
-``encode_zigzag_host`` / ``decode_zigzag_host``) imports without jax,
-which is what makes the engine's process-pool decode fallback cheap.
+``encode_zigzag_host`` / ``decode_zigzag_host``) imports without jax.
 """
 
 from repro.core.entropy.bitio import TruncatedStream

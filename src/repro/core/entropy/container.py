@@ -41,9 +41,8 @@ covers the records and ids like the tables.
 
 This module is importable without jax: the host halves
 (:func:`encode_zigzag_host` / :func:`decode_zigzag_host`) are pure
-NumPy so process-pool workers (``codec_engine.decode_batch``) don't pay
-a jax import per child; only the qcoeff/image entry points pull in the
-array stack, lazily.
+NumPy, which the engine's thread pool runs per stream; only the
+qcoeff/image entry points pull in the array stack, lazily.
 """
 
 from __future__ import annotations
@@ -54,7 +53,7 @@ import zlib
 import numpy as np
 
 from repro import obs
-from repro.core.entropy import bitio, huffman, rle
+from repro.core.entropy import bitio, dense, huffman, rle
 
 MAGIC = b"DCTZ"
 VERSION_EMBEDDED = 1        # both tables embedded (the v1 layout)
@@ -129,10 +128,12 @@ def encode_qcoeffs(qcoeffs, quality: int, transform: str,
             bytes`` callable (e.g. the routed
             :func:`repro.kernels.pack_bits.pack_bits`); None = the
             NumPy reference.
-        symbolizer: symbolisation backend override (see
-            :func:`_frame_stream`), e.g. the routed
-            :func:`repro.kernels.symbolize.make_symbolizer`; None = the
-            vectorised host pipeline.  Bytes identical either way.
+        symbolizer: symbolisation route override (see
+            :func:`_frame_stream`), e.g. what
+            :func:`repro.kernels.symbolize.make_symbolizer` returns on a
+            TPU; None = the host symbolizer
+            (:func:`repro.core.entropy.dense.prepare`).  Bytes identical
+            either way.
 
     Returns:
         The complete container as bytes.
@@ -187,8 +188,7 @@ def encode_zigzag_host(z: np.ndarray, quality: int, transform: str,
             :func:`encode_qcoeffs`.
         symbolizer: symbolisation backend override, as in
             :func:`encode_qcoeffs`.  The default keeps this function's
-            no-jax-import property; a routed symbolizer built in the
-            parent process is fine for worker *threads*.
+            no-jax-import property.
 
     Returns:
         The complete container as bytes.
@@ -279,7 +279,7 @@ def encode_colour_zigzag_host(z: np.ndarray, quality: int, transform: str,
         raise ValueError(f"zig-zag stream shape {z.shape} does not match "
                          f"the {mh}x{mw} MCU grid of a {h}x{w} image")
     classes = COLOUR_BLOCK_CLASSES
-    prep = (symbolizer or rle.prepare_stream)(
+    prep = (symbolizer or dense.prepare)(
         colour_dc_diff(z[:, 0]), z[:, 1:], packer=packer, classes=classes)
     with obs.span("entropy.tables"):
         chosen = [(_choose_table(prep.dc_freq[c], dc_sid, tables, "DC"),
@@ -345,12 +345,14 @@ def _frame_stream(dc_diff: np.ndarray, ac: np.ndarray, quality: int,
     ``symbolizer`` routes the symbolisation/payload stages: a
     ``(dc_diff, ac, packer=None) -> prepared`` callable whose result
     exposes ``dc_freq``/``ac_freq`` histograms (consumed by table
-    choice below) and ``payload(dc_table, ac_table) -> bytes`` — e.g.
-    :func:`repro.kernels.symbolize.make_symbolizer`.  ``None`` keeps
-    the vectorised host pipeline; bytes are identical either way
-    (CI-gated), so the table negotiation and framing here never change.
+    choice below) and ``payload(dc_table, ac_table) -> bytes``.
+    ``None`` is the host symbolizer, :func:`repro.core.entropy.dense.
+    prepare`; the device route (what
+    :func:`repro.kernels.symbolize.make_symbolizer` returns on a TPU)
+    gives identical bytes (CI-gated), so the table negotiation and
+    framing here never change.
     """
-    prep = (symbolizer or rle.prepare_stream)(dc_diff, ac, packer=packer)
+    prep = (symbolizer or dense.prepare)(dc_diff, ac, packer=packer)
     with obs.span("entropy.tables"):
         dc_id, dc_table = _choose_table(prep.dc_freq,
                                         huffman.STANDARD_DC_LUMA_ID,
@@ -527,9 +529,8 @@ def decode_zigzag_host(data: bytes, *, unpacker=None) -> tuple:
     The jax-free half of :func:`decode_qcoeffs`: framing validation,
     CRC, table resolution (embedded segments or shared registry ids),
     the LUT entropy decode and the (integer, bit-exact) DC integration
-    all run in NumPy, so the engine's pipelined ``decode_batch`` can
-    fan streams across threads — or processes, this module imports
-    without jax — without contending on jax dispatch; only the inverse
+    all run in NumPy, so the engine's ``decode_batch`` can fan streams
+    across threads without contending on jax dispatch; only the inverse
     zig-zag permutation is left for the device.
 
     Args:
@@ -689,7 +690,7 @@ def decode_image(data: bytes, mode: str = "standard", *, unpacker=None):
             of the stored transform, with the paper's CORDIC config).
         unpacker: optional payload-decode backend (see
             :func:`decode_zigzag_host`), e.g.
-            ``repro.kernels.unpack_bits.make_unpacker()``.
+            ``repro.kernels.unpack_bits.make_unpacker()`` on a TPU.
 
     Returns:
         (H, W) uint8 reconstruction, cropped to the stored shape; (H, W,

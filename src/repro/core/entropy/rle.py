@@ -4,16 +4,17 @@ Host-edge half of the entropy stage (NumPy): turns the fixed-shape
 arrays produced by :mod:`repro.core.entropy.scan` into the JPEG-baseline
 symbol stream that :mod:`huffman`/:mod:`bitio` serialise, and back.
 
-Both directions are **batch-vectorized**: :func:`symbolize` builds the
-(run, size) symbols, ZRL expansions, EOB markers and amplitude fields
-for every block of the stream with whole-array NumPy (no per-block
-Python loop), and :func:`decode_payload` drives a precomputed
-peek-16-bit prefix-LUT decoder whose per-bit-position symbol/advance/
-amplitude tables are built in one vectorised pass, leaving only the
-(data-dependent) walk along the symbol chain in Python.  The original
-scalar implementations survive as :func:`symbolize_reference` /
-:func:`decode_payload_reference` — the golden oracles the property
-tests and the ``entropy_throughput`` bench compare against.  A third
+The host symbolizer itself is :mod:`repro.core.entropy.dense` (one
+fused whole-array pass); this module holds the alphabet, the table-class
+helpers both directions share, and the decoders.
+:func:`decode_payload` drives a precomputed peek-16-bit prefix-LUT
+decoder whose per-bit-position symbol/advance/amplitude tables are
+built in one vectorised pass, leaving only the (data-dependent) walk
+along the symbol chain in Python.  The scalar implementations
+:func:`symbolize_reference` / :func:`decode_payload_reference` (with
+:func:`encode_payload` for the oracle's payload) are the golden oracles
+the property tests and the ``entropy_throughput`` bench compare
+against.  A third
 decode family lives in ``repro.kernels.unpack_bits`` (speculative
 per-offset decode + chain resolution, docs/decoding.md) and plugs in
 through :func:`decode_payload`'s ``unpacker`` hook; all three agree on
@@ -110,14 +111,13 @@ def _check_range(cat: np.ndarray, what: str) -> None:
             f"{MAX_CATEGORY}; levels must fit 15-bit amplitudes")
 
 
-def symbolize(dc_diff: np.ndarray, ac: np.ndarray) -> tuple:
-    """Blocks -> the interleaved (symbol, amplitude) stream, vectorised.
+def symbolize_reference(dc_diff: np.ndarray, ac: np.ndarray) -> tuple:
+    """Scalar per-block symbolisation: the encode oracle.
 
-    Every quantity — zero runs, ZRL expansions, (run, size) symbols,
-    magnitude categories, amplitude fields and the output offsets that
-    interleave them into coding order — is computed with whole-array
-    NumPy over all blocks at once; no Python loop touches a block.
-    Bit-for-bit identical to :func:`symbolize_reference`.
+    The golden reference the property tests and the
+    ``--check-identical`` bench gate compare the host symbolizer
+    (:func:`repro.core.entropy.dense.symbolize_dense`) and the Pallas
+    kernel against.  Not used on the production encode path.
 
     Args:
         dc_diff: (n,) int DC differences in block order.
@@ -131,85 +131,6 @@ def symbolize(dc_diff: np.ndarray, ac: np.ndarray) -> tuple:
 
     Raises:
         RangeError: some level needs an amplitude wider than 15 bits.
-    """
-    dc_diff = np.asarray(dc_diff, dtype=np.int64)
-    ac = np.asarray(ac, dtype=np.int64)
-    n = dc_diff.shape[0]
-    dc_cat = magnitude_category(dc_diff)
-    _check_range(dc_cat, "DC difference")
-    dc_amp = amplitude_value(dc_diff, dc_cat)
-
-    # one row per nonzero AC coefficient, already in coding order
-    # (np.nonzero is row-major: block ascending, then position ascending);
-    # categories/amplitudes only touch the nonzero entries — zeros have
-    # category 0 by definition, so the range check is unaffected
-    nz_b, nz_c = np.nonzero(ac)
-    k = nz_b.size
-    ac_nz = ac[nz_b, nz_c]
-    ac_cat_nz = magnitude_category(ac_nz)
-    _check_range(ac_cat_nz, "AC coefficient")
-    ac_amp_nz = amplitude_value(ac_nz, ac_cat_nz)
-    first = np.empty(k, dtype=bool)         # first nonzero of its block?
-    prev = np.empty(k, dtype=np.int64)      # previous nonzero position
-    if k:
-        first[0] = True
-        first[1:] = nz_b[1:] != nz_b[:-1]
-        prev[0] = -1
-        prev[1:] = nz_c[:-1]
-        prev[first] = -1
-    run = nz_c - prev - 1
-    zrl = run >> 4                          # ZRL expansions before the symbol
-    coef_sym = ((run & 15) << 4) | ac_cat_nz
-    unit = zrl + 1                          # symbols one coefficient emits
-
-    # per-block symbol budget: 1 DC + coefficient units + optional EOB
-    unit_b = np.bincount(nz_b, weights=unit, minlength=n).astype(np.int64)
-    last_c = np.full(n, -1, dtype=np.int64)
-    last_c[nz_b] = nz_c                     # row-major: last write is max pos
-    eob_b = last_c != AC_LEN - 1
-    block_total = 1 + unit_b + eob_b
-    block_off = np.concatenate(
-        [np.zeros(1, np.int64), np.cumsum(block_total)[:-1]])
-    m = int(block_total.sum())
-
-    is_dc = np.zeros(m, dtype=bool)
-    syms = np.empty(m, dtype=np.int64)
-    amp_vals = np.zeros(m, dtype=np.int64)
-    amp_lens = np.zeros(m, dtype=np.int64)
-
-    is_dc[block_off] = True
-    syms[block_off] = dc_cat
-    amp_vals[block_off] = dc_amp
-    amp_lens[block_off] = dc_cat
-    syms[(block_off + block_total - 1)[eob_b]] = EOB
-
-    if k:
-        # global start of each coefficient's unit: block start + 1 (DC)
-        # + the within-block exclusive cumsum of earlier units
-        cu = np.cumsum(unit) - unit
-        base = cu[first][np.cumsum(first) - 1]     # cu at block's first coef
-        start = block_off[nz_b] + 1 + (cu - base)
-        coded = start + zrl
-        syms[coded] = coef_sym
-        amp_vals[coded] = ac_amp_nz
-        amp_lens[coded] = ac_cat_nz
-        total_zrl = int(zrl.sum())
-        if total_zrl:
-            # expand each run's ZRL slots: start .. start+zrl-1
-            zc = np.cumsum(zrl) - zrl
-            pos = (np.repeat(start, zrl)
-                   + np.arange(total_zrl, dtype=np.int64)
-                   - np.repeat(zc, zrl))
-            syms[pos] = ZRL
-    return is_dc, syms, amp_vals, amp_lens
-
-
-def symbolize_reference(dc_diff: np.ndarray, ac: np.ndarray) -> tuple:
-    """Scalar per-block oracle for :func:`symbolize` (same contract).
-
-    The original loop implementation, kept as the golden reference the
-    property tests and ``--check-identical`` bench gate compare the
-    vectorised path against.  Not used on the production encode path.
     """
     dc_diff = np.asarray(dc_diff, dtype=np.int64)
     ac = np.asarray(ac, dtype=np.int64)
@@ -253,32 +174,20 @@ def symbolize_reference(dc_diff: np.ndarray, ac: np.ndarray) -> tuple:
             np.asarray(amp_lens, dtype=np.int64))
 
 
-def symbol_frequencies(is_dc, syms, sym_cls=None, n_classes: int = 1
-                       ) -> tuple:
-    """(dc_freqs, ac_freqs): 256-bin histograms of the two alphabets;
-    with ``sym_cls`` (each symbol's table class), (n_classes, 256)."""
-    if sym_cls is None:
-        dc = np.bincount(syms[is_dc], minlength=256)
-        ac = np.bincount(syms[~is_dc], minlength=256)
-        return dc, ac
-    key = sym_cls * 256 + syms
-    n = n_classes * 256
-    return (np.bincount(key[is_dc], minlength=n).reshape(n_classes, 256),
-            np.bincount(key[~is_dc], minlength=n).reshape(n_classes, 256))
+def symbol_frequencies(is_dc, syms) -> tuple:
+    """(dc_freqs, ac_freqs): 256-bin histograms of the two alphabets."""
+    return (np.bincount(syms[is_dc], minlength=256),
+            np.bincount(syms[~is_dc], minlength=256))
 
 
-def codeword_fields(is_dc, syms, amp_vals, amp_lens, dc_table, ac_table,
-                    sym_cls=None) -> tuple:
+def codeword_fields(is_dc, syms, amp_vals, amp_lens, dc_table,
+                    ac_table) -> tuple:
     """Codeword-lookup stage: symbol stream -> interleaved bit fields.
 
     Every symbol contributes its Huffman code, immediately followed by
     its amplitude field (when present); the interleave is realised by
     laying codes at even and amplitudes at odd slots of a (2M,) field
     array — packers drop the zero-width slots.
-
-    With ``sym_cls`` (each symbol's table class), ``dc_table`` and
-    ``ac_table`` are sequences indexed by class and each symbol takes
-    its class's codes.
 
     Returns:
         ``(fields, widths)`` int64 arrays ready for any bit packer
@@ -290,18 +199,10 @@ def codeword_fields(is_dc, syms, amp_vals, amp_lens, dc_table, ac_table,
             (possible with shared tables; the container's cost-based
             selection never picks an uncovering table).
     """
-    if sym_cls is None:
-        dc_code, dc_len = huffman.encoder_luts(dc_table)
-        ac_code, ac_len = huffman.encoder_luts(ac_table)
-        codes = np.where(is_dc, dc_code[syms], ac_code[syms])
-        lens = np.where(is_dc, dc_len[syms], ac_len[syms])
-    else:
-        dc_code, dc_len = class_luts(dc_table)
-        ac_code, ac_len = class_luts(ac_table)
-        codes = np.where(is_dc, dc_code[sym_cls, syms],
-                         ac_code[sym_cls, syms])
-        lens = np.where(is_dc, dc_len[sym_cls, syms],
-                        ac_len[sym_cls, syms])
+    dc_code, dc_len = huffman.encoder_luts(dc_table)
+    ac_code, ac_len = huffman.encoder_luts(ac_table)
+    codes = np.where(is_dc, dc_code[syms], ac_code[syms])
+    lens = np.where(is_dc, dc_len[syms], ac_len[syms])
     if bool((lens == 0).any()):
         raise ValueError("symbol stream contains a symbol absent from "
                          "the Huffman table")
@@ -313,69 +214,13 @@ def codeword_fields(is_dc, syms, amp_vals, amp_lens, dc_table, ac_table,
     return fields, widths
 
 
-def encode_payload(is_dc, syms, amp_vals, amp_lens, dc_table, ac_table,
-                   packer=None, sym_cls=None) -> bytes:
-    """Huffman-code the symbol stream and pack it into bytes.
-
-    Two explicit stages of the staged encode pipeline: codeword lookup
-    (:func:`codeword_fields`) then bit packing.  ``packer`` selects the
-    packing backend — a ``(fields, widths) -> bytes`` callable, e.g.
-    the routed :func:`repro.kernels.pack_bits.pack_bits`; ``None`` uses
-    the NumPy reference :func:`repro.core.entropy.bitio.pack_bits`.
-    Every backend is byte-identical by contract (CI-gated).
-    ``sym_cls`` picks each symbol's tables, as in :func:`codeword_fields`.
-    """
-    fields, widths = codeword_fields(is_dc, syms, amp_vals, amp_lens,
-                                     dc_table, ac_table, sym_cls)
-    if packer is not None:
-        return packer(fields, widths)
-    with obs.route("pack", "host"):
-        return bitio.pack_bits(fields, widths)
-
-
-class PreparedStream:
-    """Two-phase symbolisation: histograms first, payload on demand.
-
-    The shape the container's table negotiation needs — it must see the
-    per-alphabet histograms *before* it can pick tables, and only then
-    can codeword lookup and packing run.  This default implementation
-    wraps the vectorised host pipeline (:func:`symbolize` →
-    :func:`symbol_frequencies` → :func:`encode_payload`); the routed
-    alternatives (:func:`repro.kernels.symbolize.make_symbolizer`)
-    expose the same two attributes and method over the fused dense pass
-    or the device-resident chain, byte-identically (CI-gated).
-    """
-
-    def __init__(self, dc_diff: np.ndarray, ac: np.ndarray, packer=None,
-                 classes: tuple = ONE_CLASS):
-        with obs.route("symbolize", "host", blocks=len(dc_diff)):
-            self._stream = symbolize(dc_diff, ac)
-            self._packer = packer
-            self._sym_cls = None
-            if classes != ONE_CLASS:
-                is_dc = self._stream[0]
-                self._sym_cls = block_classes(classes, len(dc_diff))[
-                    np.cumsum(is_dc) - 1]
-            self.dc_freq, self.ac_freq = symbol_frequencies(
-                self._stream[0], self._stream[1], self._sym_cls,
-                max(classes) + 1)
-
-    def payload(self, dc_table, ac_table) -> bytes:
-        """Huffman-code + pack the prepared stream for chosen tables
-        (one per class where the stream has several)."""
-        return encode_payload(*self._stream, dc_table, ac_table,
-                              packer=self._packer, sym_cls=self._sym_cls)
-
-
-def prepare_stream(dc_diff: np.ndarray, ac: np.ndarray,
-                   packer=None, classes: tuple = ONE_CLASS
-                   ) -> PreparedStream:
-    """The default ``symbolizer=`` backend: vectorised host pipeline.
-
-    ``classes`` is the stream's table-class pattern; with more than one
-    class, ``dc_freq``/``ac_freq`` are (n_classes, 256) and ``payload``
-    takes one table per class."""
-    return PreparedStream(dc_diff, ac, packer=packer, classes=classes)
+def encode_payload(is_dc, syms, amp_vals, amp_lens, dc_table,
+                   ac_table) -> bytes:
+    """Huffman-code a symbol stream and pack it into bytes: the scalar
+    oracle's payload stage (:func:`codeword_fields`, then
+    :func:`repro.core.entropy.bitio.pack_bits`)."""
+    return bitio.pack_bits(*codeword_fields(is_dc, syms, amp_vals,
+                                            amp_lens, dc_table, ac_table))
 
 
 _PAST_END = 32     # sentinel slots appended past the last window position
@@ -544,7 +389,7 @@ def decode_payload(payload: bytes, n_blocks: int, dc_table, ac_table, *,
 
     Returns:
         ``(dc_diff, ac)`` — (n,) int32 DC differences and (n, 63) int32
-        AC tails, exactly inverting :func:`symbolize`.
+        AC tails, exactly inverting :func:`symbolize_reference`.
 
     Raises:
         bitio.TruncatedStream: the payload ends mid-block.

@@ -1,11 +1,11 @@
 """Routed public wrappers for the pack_bits kernel.
 
-``pack_bits`` is the packing backend the staged entropy encode pipeline
-(:func:`repro.core.entropy.rle.encode_payload`) routes through: the
-Pallas kernel on TPU, the staged NumPy reference everywhere else — the
-same backend-selection shape as ``fused_codec`` (compiled kernel on
-TPU, bit-exact fallback elsewhere), and byte-identical output either
-way (CI-gated by ``bench_entropy_throughput --check-identical``).
+``pack_bits`` is the packing stage of the entropy encoders' ``packer``
+hook: the Pallas kernel on TPU, the staged NumPy reference everywhere
+else — the same backend-selection shape as ``fused_codec`` (compiled
+kernel on TPU, bit-exact fallback elsewhere), and byte-identical output
+either way (CI-gated by ``bench_entropy_throughput --check-identical``).
+:func:`make_packer` is where the engine's encode picks the route.
 """
 
 from __future__ import annotations
@@ -82,19 +82,16 @@ def pack_bits(codes, lengths, *, backend: str = "auto",
     return _pack_bits_device(codes, lengths, interpret, tile_bits)
 
 
-def make_packer(backend: str = "auto", interpret: bool | None = None,
-                tile_bits: int | None = None):
-    """Packing callable for the entropy encoders' ``packer`` argument.
+def make_packer():
+    """The encode's packing route, chosen from the platform.
 
-    Returns ``None`` when the resolved backend is "numpy" — callers
-    then keep their zero-indirection default
-    (:func:`repro.core.entropy.bitio.pack_bits`) — and a routed
-    device-packing callable for "pallas".
+    ``None`` off the TPU — callers then keep their zero-indirection
+    default (:func:`repro.core.entropy.bitio.pack_bits`) — and the
+    routed device scatter-pack on a TPU.
     """
-    if select_backend(backend) == "numpy":
+    if select_backend() == "numpy":
         return None
-    return functools.partial(pack_bits, backend="pallas",
-                             tile_bits=tile_bits, interpret=interpret)
+    return functools.partial(pack_bits, backend="pallas")
 
 
 def _pow2(n: int) -> int:

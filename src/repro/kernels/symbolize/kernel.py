@@ -1,9 +1,9 @@
 """Pallas TPU kernel: RLE-symbolise zig-zagged blocks on device.
 
 Device-resident realisation of the entropy encoder's first host stage
-(:func:`repro.core.entropy.rle.symbolize`): the grid tiles the block
-axis, and each program turns its ``tile_blocks`` zig-zag rows into the
-dense per-block symbol layout of :mod:`repro.kernels.symbolize.ref` —
+(:func:`repro.core.entropy.dense.symbolize_dense`): the grid tiles the
+block axis, and each program turns its ``tile_blocks`` zig-zag rows into
+the dense per-block symbol layout of :mod:`repro.core.entropy.dense` —
 (run, size) symbols, amplitude fields and per-block symbol counts —
 plus the two 256-bin alphabet histograms the host needs for Huffman
 table negotiation.  Everything per-row is fixed-shape arithmetic:
@@ -24,7 +24,7 @@ table negotiation.  Everything per-row is fixed-shape arithmetic:
 
 Row validity (the block count is rarely a tile multiple) comes in via
 scalar prefetch; padded rows contribute nothing to histograms and get
-``total == 0``.  Element-exact against ``ref.symbolize_dense`` by the
+``total == 0``.  Element-exact against ``dense.symbolize_dense`` by the
 tile-invariance and ``--check-identical`` gates.
 """
 

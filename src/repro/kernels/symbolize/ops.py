@@ -1,44 +1,44 @@
 """Routed public wrappers for the symbolize kernel.
 
-``symbolize`` is a drop-in for :func:`repro.core.entropy.rle.symbolize`
-with the stage routed per backend — the Pallas kernel on TPU, the
-staged dense NumPy reference everywhere else — element-identical either
-way (CI-gated by ``bench_entropy_throughput --check-identical``).
+``symbolize`` / ``symbolize_dense`` route one batch of blocks per
+backend — the Pallas kernel on TPU, the host symbolizer
+(:mod:`repro.core.entropy.dense`) everywhere else — element-identical
+either way (CI-gated by ``bench_entropy_throughput --check-identical``).
 
-:func:`make_symbolizer` builds the object the container encoders thread
-through (``symbolizer=``): a two-phase *prepared stream* exposing the
-device-computed alphabet histograms first (all the host needs for
-Huffman table negotiation) and producing the payload bytes once tables
-are chosen.  On the Pallas backend that second phase chains entirely on
-device — dense codeword gather, stable zero-width compaction,
-prefix-sum offsets, then the ``pack_bits`` scatter-pack kernel — so the
-host transfers two 1 KiB histograms, one scalar bit count and the
-finished payload instead of the full coefficient tensor.  On the NumPy
-backend it is the fused dense pass of :mod:`.ref` (one symbolize +
-histogram sweep, codeword lookup on the dense slots, one packer call).
+:func:`prepare` is the two-phase *prepared stream* the container
+encoders thread through (``symbolizer=``): the alphabet histograms
+first (all the host needs for Huffman table negotiation), the payload
+bytes once tables are chosen.  On the Pallas route that second phase
+chains entirely on device — dense codeword gather, stable zero-width
+compaction, prefix-sum offsets, then the ``pack_bits`` scatter-pack
+kernel — so the host transfers two 1 KiB histograms, one scalar bit
+count and the finished payload instead of the full coefficient tensor.
+Streams the device guards reject take the host symbolizer.
+:func:`make_symbolizer` is where the engine's encode picks the route.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.core.entropy import huffman, rle
+from repro.core.entropy import dense, huffman, rle
 from repro.kernels import tuning
 from repro.kernels.pack_bits import ops as pack_ops
-from repro.kernels.symbolize import kernel, ref
+from repro.kernels.symbolize import kernel
 
 TILE_BLOCKS = 64                    # default blocks per kernel program
 
-# Above this many blocks the stream falls back to the staged NumPy
-# reference: the chained payload stage packs the (2 * 64 * n_pad,)
-# dense field slots through pack_bits, so its MAX_DEVICE_FIELDS cap
-# divided by the 128 fields a block can emit caps the device-resident
-# block count (2048 blocks: 256x256 images stay on device, 512x512 do
-# not — ROADMAP A5).
-MAX_DEVICE_BLOCKS = pack_ops.MAX_DEVICE_FIELDS // (2 * ref.SLOTS)
+# Above this many blocks the stream takes the host symbolizer: the
+# chained payload stage packs the (2 * 64 * n_pad,) dense field slots
+# through pack_bits, so its MAX_DEVICE_FIELDS cap divided by the 128
+# fields a block can emit caps the device-resident block count (2048
+# blocks: 256x256 images stay on device, 512x512 do not — ROADMAP A3).
+MAX_DEVICE_BLOCKS = pack_ops.MAX_DEVICE_FIELDS // (2 * dense.SLOTS)
 
 # The kernel computes magnitude categories as 15 threshold compares in
 # int32, so levels must already fit 15-bit amplitudes; anything larger
@@ -84,7 +84,7 @@ def _run_kernel(dc_diff: np.ndarray, ac: np.ndarray, tile_blocks: int,
     n_pad = max(_pow2(n), tile_blocks)
     dc = np.zeros((n_pad, 1), np.int32)
     dc[:n, 0] = dc_diff
-    acp = np.zeros((n_pad, ref.AC_LEN), np.int32)
+    acp = np.zeros((n_pad, dense.AC_LEN), np.int32)
     acp[:n] = ac
     nrows = np.array([n], np.int32)
     host = [dc, acp, nrows]
@@ -103,7 +103,7 @@ def _run_kernel(dc_diff: np.ndarray, ac: np.ndarray, tile_blocks: int,
 def symbolize_dense(dc_diff, ac, *, backend: str = "auto",
                     tile_blocks: int | None = None,
                     interpret: bool | None = None,
-                    classes: tuple = rle.ONE_CLASS) -> ref.DenseSymbols:
+                    classes: tuple = rle.ONE_CLASS) -> dense.DenseSymbols:
     """Routed fused pass: dense slots + histograms on the host.
 
     Args:
@@ -120,7 +120,7 @@ def symbolize_dense(dc_diff, ac, *, backend: str = "auto",
             class the histograms are (n_classes, 256).
 
     Returns:
-        A :class:`repro.kernels.symbolize.ref.DenseSymbols`, identical
+        A :class:`repro.core.entropy.dense.DenseSymbols`, identical
         across backends and every ``tile_blocks``.
 
     Raises:
@@ -132,7 +132,7 @@ def symbolize_dense(dc_diff, ac, *, backend: str = "auto",
     n = dc_diff.shape[0]
     if select_backend(backend) == "numpy" or not _device_ok(dc_diff, ac):
         with obs.route("symbolize", "host", blocks=n):
-            return ref.symbolize_dense(dc_diff, ac, classes)
+            return dense.symbolize_dense(dc_diff, ac, classes)
     from repro.kernels import common
     if interpret is None:
         interpret = common.interpret_default()
@@ -142,7 +142,7 @@ def symbolize_dense(dc_diff, ac, *, backend: str = "auto",
         out = _run_kernel(dc_diff, ac, tile_blocks, interpret, classes)
         with obs.d2h(*out):
             syms, amps, lens, total, dc_h, ac_h = jax.device_get(out)
-    return ref.DenseSymbols(
+    return dense.DenseSymbols(
         syms=np.asarray(syms[:n], np.int16),
         amp_vals=np.asarray(amps[:n], np.int16),
         amp_lens=np.asarray(lens[:n], np.int16),
@@ -161,13 +161,12 @@ def _hist(h, classes: tuple) -> np.ndarray:
 def symbolize(dc_diff, ac, *, backend: str = "auto",
               tile_blocks: int | None = None,
               interpret: bool | None = None) -> tuple:
-    """Routed drop-in for :func:`repro.core.entropy.rle.symbolize`.
-
-    Same contract and return dtypes (``(is_dc, syms, amp_vals,
-    amp_lens)``), element-identical to the scalar oracle across every
-    backend and tile (CI-gated).
+    """Routed symbol stream: the contract and return dtypes of
+    :func:`repro.core.entropy.rle.symbolize_reference` (``(is_dc, syms,
+    amp_vals, amp_lens)``), element-identical to that oracle across
+    every backend and tile (CI-gated).
     """
-    return ref.dense_to_stream(symbolize_dense(
+    return dense.dense_to_stream(symbolize_dense(
         dc_diff, ac, backend=backend, tile_blocks=tile_blocks,
         interpret=interpret))
 
@@ -175,21 +174,6 @@ def symbolize(dc_diff, ac, *, backend: str = "auto",
 # ---------------------------------------------------------------------------
 # Prepared streams: the container's symbolizer= protocol
 # ---------------------------------------------------------------------------
-
-class _NumpyPrepared:
-    """Fused host preparation: dense pass now, one packer call later."""
-
-    def __init__(self, dense: ref.DenseSymbols, packer):
-        self._dense = dense
-        self._packer = packer
-        self.dc_freq = dense.dc_freq
-        self.ac_freq = dense.ac_freq
-
-    def payload(self, dc_table: huffman.CanonicalTable,
-                ac_table: huffman.CanonicalTable) -> bytes:
-        return ref.encode_payload_dense(self._dense, dc_table, ac_table,
-                                        packer=self._packer)
-
 
 @jax.jit
 def _fields_device(syms, amps, lens, total, dc_code, dc_len,
@@ -203,7 +187,7 @@ def _fields_device(syms, amps, lens, total, dc_code, dc_len,
     block) the code tables are (n_classes, 256) and each block takes
     its class's row.
     """
-    slot = jnp.arange(ref.SLOTS, dtype=jnp.int32)[None, :]
+    slot = jnp.arange(dense.SLOTS, dtype=jnp.int32)[None, :]
     valid = slot < total                                    # (n_pad, 64)
     isdc = slot == 0
     if cls is None:
@@ -291,41 +275,46 @@ class _PallasPrepared:
                                            self._interpret)
 
 
-def make_symbolizer(backend: str = "auto", *,
-                    tile_blocks: int | None = None,
-                    interpret: bool | None = None):
-    """Symbolizer callable for the container encoders' ``symbolizer=``.
+def prepare(dc_diff, ac, packer=None, classes: tuple = rle.ONE_CLASS, *,
+            backend: str = "auto", tile_blocks: int | None = None,
+            interpret: bool | None = None):
+    """Routed ``symbolizer=`` for the container encoders.
 
-    The returned callable maps ``(dc_diff, ac, packer=None)`` to a
-    prepared stream with ``dc_freq`` / ``ac_freq`` histogram attributes
-    and a ``payload(dc_table, ac_table) -> bytes`` method — the
-    two-phase shape :func:`repro.core.entropy.container._frame_stream`
-    needs for table negotiation.  Bytes are identical across backends
-    and to the default (``symbolizer=None``) path (CI-gated).
+    Maps ``(dc_diff, ac, packer=None, classes=...)`` to a prepared
+    stream with ``dc_freq`` / ``ac_freq`` histogram attributes and a
+    ``payload(dc_table, ac_table) -> bytes`` method — the two-phase
+    shape :func:`repro.core.entropy.container._frame_stream` needs for
+    table negotiation.  Bytes are identical across backends and to the
+    default (``symbolizer=None``) host route (CI-gated).
 
-    On "pallas", ``packer`` only applies to streams the device guards
-    reject (size/range fallbacks run the staged NumPy pass): accepted
-    streams pack through the chained device scatter-pack.  A
-    ``classes=`` pattern with more than one table class gives
-    (n_classes, 256) histograms and a ``payload`` that takes one table
-    per class, on either route.
+    On "pallas", a stream the device guards accept packs through the
+    chained device scatter-pack; any other stream takes the host
+    symbolizer (:func:`repro.core.entropy.dense.prepare`) and packs
+    with ``packer``.  A ``classes=`` pattern with more than one table
+    class gives (n_classes, 256) histograms and a ``payload`` that takes
+    one table per class, on either route.
     """
-    resolved = select_backend(backend)
+    dc_diff = np.asarray(dc_diff, dtype=np.int64)
+    ac = np.asarray(ac, dtype=np.int64)
+    if select_backend(backend) == "numpy" or not _device_ok(dc_diff, ac):
+        return dense.prepare(dc_diff, ac, packer, classes)
+    from repro.kernels import common
+    if interpret is None:
+        interpret = common.interpret_default()
+    if tile_blocks is None:
+        tile_blocks = tuning.tile_for("symbolize", dc_diff.shape[0])
+    with obs.device_route("symbolize", interpret, blocks=dc_diff.shape[0]):
+        return _PallasPrepared(dc_diff, ac, tile_blocks, interpret, classes)
 
-    def prepare(dc_diff, ac, packer=None, classes=rle.ONE_CLASS):
-        dc_diff = np.asarray(dc_diff, dtype=np.int64)
-        ac = np.asarray(ac, dtype=np.int64)
-        if resolved == "pallas" and _device_ok(dc_diff, ac):
-            from repro.kernels import common
-            interp = (common.interpret_default()
-                      if interpret is None else interpret)
-            tiles = (tuning.tile_for("symbolize", dc_diff.shape[0])
-                     if tile_blocks is None else tile_blocks)
-            with obs.device_route("symbolize", interp,
-                                  blocks=dc_diff.shape[0]):
-                return _PallasPrepared(dc_diff, ac, tiles, interp, classes)
-        with obs.route("symbolize", "host", blocks=dc_diff.shape[0]):
-            return _NumpyPrepared(
-                ref.symbolize_dense(dc_diff, ac, classes), packer)
 
-    return prepare
+def make_symbolizer():
+    """The encode's symbolize route, chosen from the platform.
+
+    ``None`` off the TPU — the container encoders then keep their
+    default, the host symbolizer — and :func:`prepare` on the Pallas
+    route on a TPU, where its device guards still send oversized or
+    out-of-range streams to the host.
+    """
+    if select_backend() == "numpy":
+        return None
+    return functools.partial(prepare, backend="pallas")

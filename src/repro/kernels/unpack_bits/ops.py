@@ -4,10 +4,11 @@
 via ``rle.decode_payload(unpacker=)``: on TPU one device program decodes
 the whole payload — unit words, chain walk, block-chain resolution and
 coefficient emission — and only ``(dc_diff, ac)`` comes back; the staged
-NumPy reference decodes everywhere else, and on the device route's
-guards.  The same backend-selection shape as
-:mod:`repro.kernels.pack_bits` on the encode side, and
-coefficient-identical output either way (CI-gated by
+NumPy reference (``backend="numpy"``) decodes the streams outside the
+device route's guards.  :func:`make_unpacker` is where the engine's
+decode picks the route: this device decode on a TPU, the LUT walk of
+:func:`repro.core.entropy.rle.decode_payload` elsewhere.
+Coefficient-identical output on every route (CI-gated by
 ``bench_entropy_throughput --check-identical``).
 """
 
@@ -120,21 +121,18 @@ def _host_route(n_blocks: int, classes: tuple):
                      table_classes=max(classes) + 1)
 
 
-def make_unpacker(backend: str = "auto", interpret: bool | None = None,
-                  tile_bits: int | None = None):
-    """Unpacking callable for the entropy decoders' ``unpacker`` argument.
+def make_unpacker():
+    """The decode's unpacking route, chosen from the platform.
 
-    Returns ``None`` when the resolved backend is "numpy" — callers
-    then keep their zero-indirection default (the LUT walk inside
-    :func:`repro.core.entropy.rle.decode_payload`) — and a routed
-    device-unpacking callable for "pallas".  ``decode_batch`` refuses
-    to ship a device unpacker to its process pool: a spawned worker
-    cannot open the chip its parent holds.
+    ``None`` off the TPU — callers then keep their zero-indirection
+    default (the LUT walk inside
+    :func:`repro.core.entropy.rle.decode_payload`) — and the routed
+    device decode on a TPU, which resolves each stream's block chain on
+    the device within its guards.
     """
-    if select_backend(backend) == "numpy":
+    if select_backend() == "numpy":
         return None
-    return functools.partial(unpack_bits, backend="pallas",
-                             tile_bits=tile_bits, interpret=interpret)
+    return functools.partial(unpack_bits, backend="pallas")
 
 
 def _pow2(n: int) -> int:

@@ -34,7 +34,7 @@ route per call (``engine.roundtrip.<fused|staged>``) and the images
 encoded and decoded (``engine.images.{encoded,decoded}``).
 
 This module imports nothing outside the standard library: where jax is
-not imported yet (a jax-free entropy decode, a process-pool worker),
+not imported yet (a jax-free entropy encode or decode),
 ``span`` returns a shared null context and never imports it.
 """
 

@@ -1,7 +1,7 @@
 """Serving layer: batched codec engine + async front end.
 
 * :mod:`repro.serve.codec_engine` — batched/multi-device encode and
-  decode over the core codec (shape buckets, pipelined entropy edge,
+  decode over the core codec (shape buckets, overlapped entropy edge,
   device-routed pack/unpack).
 * :mod:`repro.serve.service` — asyncio :class:`~repro.serve.service.
   CodecService` with deadline-aware adaptive batching, bounded-queue

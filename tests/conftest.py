@@ -7,6 +7,8 @@ device_count=8 so the ``multidevice`` tests run emulated).
 import numpy as np
 import pytest
 
+from helpers.routes import pallas_route  # noqa: F401  (shared fixture)
+
 
 def pytest_configure(config):
     config.addinivalue_line(

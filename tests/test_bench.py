@@ -225,21 +225,16 @@ def test_render_golden_snippet_entropy_table():
                 "nbytes": 22288},
         timings_us={"encode_pipelined": {"median_us": 20000.0,
                                          "best_us": 19000.0, "iters": 5},
-                    "encode_serial": {"median_us": 30000.0,
-                                      "best_us": 29000.0, "iters": 2},
                     "decode_pipelined": {"median_us": 50000.0,
-                                         "best_us": 49000.0, "iters": 5},
-                    "decode_serial": {"median_us": 45000.0,
-                                      "best_us": 44000.0, "iters": 2}},
-        metrics={"enc_img_per_s": 400.0, "enc_img_per_s_serial": 266.7,
-                 "dec_img_per_s": 160.0, "dec_img_per_s_serial": 177.8,
+                                         "best_us": 49000.0, "iters": 5}},
+        metrics={"enc_img_per_s": 400.0, "dec_img_per_s": 160.0,
                  "enc_mb_per_s": 26.2, "speedup_vs_reference": 7.5})
     md = report.render([schema.BenchResult(
         name="entropy_throughput", suite="paper", records=[stage, batch],
         environment={})])
     assert "## Entropy throughput (vectorized host coding)" in md
     assert "| encode | 2.000 | 18.000 | 9.0x | 32.8 |" in md
-    assert "| 8 | 400.0 | 266.7 | 160.0 | 26.2 | 7.50x |" in md
+    assert "| 8 | 400.0 | 160.0 | 26.2 | 7.50x |" in md
 
 
 def test_entropy_identity_gate_and_adversarial_blocks():

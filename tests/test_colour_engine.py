@@ -27,7 +27,7 @@ from perfbench import colour as bench_colour  # noqa: E402
 from perfbench import reference_colour as rc  # noqa: E402
 from repro import obs  # noqa: E402
 from repro.core import colour, entropy, images  # noqa: E402
-from repro.core.entropy import container, rle  # noqa: E402
+from repro.core.entropy import container, dense, rle  # noqa: E402
 from repro.kernels import symbolize, unpack_bits  # noqa: E402
 from repro.serve import codec_engine as eng  # noqa: E402
 
@@ -75,26 +75,28 @@ def test_engine_matches_plain_reference(shape):
 @pytest.mark.parametrize("unpack", ["numpy", "pallas"])
 @pytest.mark.parametrize("pack", ["numpy", "pallas"])
 @pytest.mark.parametrize("sym", ["numpy", "pallas"])
-def test_streams_identical_across_backends(monkeypatch, sym, pack, unpack,
-                                           guard):
+def test_streams_identical_across_backends(monkeypatch, pallas_route, sym,
+                                           pack, unpack, guard):
     imgs = [_img(s, seed=i) for i, s in enumerate(SIZES)]
-    want = eng.encode_batch(imgs, 75, pack_backend="numpy",
-                            symbolize_backend="numpy")
-    want_rec = eng.decode_batch(want, unpack_backend="numpy")
+    want = eng.encode_batch(imgs, 75)
+    want_rec = eng.decode_batch(want)
+    pallas_route(*[stage for stage, backend in (("symbolize", sym),
+                                                 ("pack", pack),
+                                                 ("unpack", unpack))
+                   if backend == "pallas"])
     if guard == "host":
         # the largest test image (240 blocks) stays under the device
         # guard; shrink the guard so these streams take the host route
         monkeypatch.setattr(symbolize.ops, "MAX_DEVICE_BLOCKS", 100)
     before = obs.counts()
-    got = eng.encode_batch(imgs, 75, pack_backend=pack,
-                           symbolize_backend=sym)
+    got = eng.encode_batch(imgs, 75)
     assert got == want
     routes = {k: v - before.get(k, 0) for k, v in obs.counts().items()}
     if sym == "pallas":
         n_dev = sum(colour.mcu_grid(*s)[0] * colour.mcu_grid(*s)[1] * 6
                     <= (100 if guard == "host" else 2048) for s in SIZES)
         assert routes.get("entropy.symbolize.interpret", 0) == n_dev
-    rec = eng.decode_batch(got, unpack_backend=unpack)
+    rec = eng.decode_batch(got)
     for a, b in zip(rec, want_rec):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     if unpack == "pallas":
@@ -123,12 +125,15 @@ def test_scalar_oracles_agree_on_two_classes():
     z = colour.compress(img, 75)
     dc_diff = container.colour_dc_diff(z[:, 0])
     classes = colour.TABLE_CLASSES
-    prep = rle.prepare_stream(dc_diff, z[:, 1:], classes=classes)
+    prep = dense.prepare(dc_diff, z[:, 1:], classes=classes)
     assert prep.dc_freq.shape == (2, 256)
-    dense = symbolize.symbolize_dense(dc_diff, z[:, 1:], backend="numpy",
-                                      classes=classes)
-    np.testing.assert_array_equal(dense.dc_freq, prep.dc_freq)
-    np.testing.assert_array_equal(dense.ac_freq, prep.ac_freq)
+    # the per-class histograms of the scalar oracle's symbol stream
+    is_dc, syms, _, _ = rle.symbolize_reference(dc_diff, z[:, 1:])
+    cls = rle.block_classes(classes, len(dc_diff))[np.cumsum(is_dc) - 1]
+    for freq, mask in ((prep.dc_freq, is_dc), (prep.ac_freq, ~is_dc)):
+        want = np.bincount(cls[mask] * 256 + syms[mask],
+                           minlength=512).reshape(2, 256)
+        np.testing.assert_array_equal(freq, want)
     dcs = tuple(entropy.huffman.DEFAULT_TABLES.get(d) for d, _ in
                 entropy.huffman.STANDARD_IDS)
     acs = tuple(entropy.huffman.DEFAULT_TABLES.get(a) for _, a in
@@ -148,12 +153,15 @@ def test_scalar_oracles_agree_on_two_classes():
 def test_single_image_api_and_stacked_batch_agree_with_engine():
     imgs = np.stack([_img((48, 64), seed=s) for s in range(3)])
     stacked = eng.encode_batch(imgs, 75)
-    serial = eng.encode_batch(imgs, 75, pipelined=False)
-    single = [entropy.encode_image(im, 75) for im in imgs]
-    assert stacked == serial == single
+    assert stacked == [entropy.encode_image(im, 75) for im in imgs]
     for blob, rec in zip(stacked, eng.decode_batch(stacked)):
         np.testing.assert_array_equal(np.asarray(rec),
                                       np.asarray(entropy.decode_image(blob)))
+    # a ragged batch over several MCU buckets, under every table policy
+    rag = [_img(s, seed=i) for i, s in enumerate(SIZES)]
+    for tables in ("auto", "embedded", "shared"):
+        assert eng.encode_batch(rag, 75, tables=tables) == [
+            entropy.encode_image(im, 75, tables=tables) for im in rag]
 
 
 def test_mixed_grayscale_and_colour_batch():

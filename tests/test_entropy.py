@@ -2,39 +2,47 @@
 round-trips (random + adversarial blocks), vectorized-vs-reference
 identity (the wire-format lock for the fast path), golden ``.dctz``
 fixtures from the PR 3 encoder, container framing errors, bit-exactness
-against the quantised array path, and the engine's (pipelined) batch
-byte path."""
+against the quantised array path, and the engine's batch byte path."""
 
 import pathlib
+import re
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro import obs
 from repro.core import codec, images
 from repro.core.entropy import (BitstreamError, decode_image, decode_qcoeffs,
                                 decode_zigzag_host, encode_image,
                                 encode_qcoeffs, encode_zigzag_host,
                                 read_header, verify_crc)
-from repro.core.entropy import bitio, huffman, rle, scan
+from repro.core.entropy import bitio, dense, huffman, rle, scan
 
 DATA_DIR = pathlib.Path(__file__).parent / "data"
+
+
+def _symbolize(dc_diff, ac):
+    """The host symbolizer's coding-order symbol stream."""
+    return dense.dense_to_stream(dense.symbolize_dense(dc_diff, ac))
 
 
 def _roundtrip_blocks(dc_diff, ac):
     """symbolize -> tables -> payload -> decode, for (n,)+(n,63) arrays.
 
-    Also asserts, on every use, that the vectorized path matches the
-    scalar reference at all three levels: symbol stream, payload bytes,
-    and decoded blocks."""
-    is_dc, syms, amp_vals, amp_lens = rle.symbolize(dc_diff, ac)
+    Also asserts, on every use, that the host symbolizer and LUT walk
+    match the scalar references at all three levels: symbol stream,
+    payload bytes, and decoded blocks."""
+    is_dc, syms, amp_vals, amp_lens = _symbolize(dc_diff, ac)
     ref = rle.symbolize_reference(dc_diff, ac)
     for got, want in zip((is_dc, syms, amp_vals, amp_lens), ref):
         np.testing.assert_array_equal(got, want)
     dc_freq, ac_freq = rle.symbol_frequencies(is_dc, syms)
     dc_t, ac_t = huffman.build_table(dc_freq), huffman.build_table(ac_freq)
     payload = rle.encode_payload(is_dc, syms, amp_vals, amp_lens, dc_t, ac_t)
+    assert dense.encode_payload_dense(dense.symbolize_dense(dc_diff, ac),
+                                      dc_t, ac_t) == payload
     out = rle.decode_payload(payload, len(dc_diff), dc_t, ac_t)
     ref_out = rle.decode_payload_reference(payload, len(dc_diff), dc_t, ac_t)
     np.testing.assert_array_equal(out[0], ref_out[0])
@@ -104,11 +112,15 @@ class TestRLEHuffman:
         np.testing.assert_array_equal(dec_ac, ac, err_msg=name)
 
     def test_amplitude_range_rejected(self):
-        with pytest.raises(rle.RangeError):
-            rle.symbolize(np.array([2**16]), np.zeros((1, 63), int))
-        with pytest.raises(rle.RangeError):
-            rle.symbolize(np.array([0]),
-                          np.full((1, 63), 40000, dtype=np.int64))
+        # the host symbolizer raises the scalar oracle's exact message
+        for dc, ac in [(np.array([2**16]), np.zeros((1, 63), int)),
+                       (np.array([0]),
+                        np.full((1, 63), 40000, dtype=np.int64))]:
+            with pytest.raises(rle.RangeError) as want:
+                rle.symbolize_reference(dc, ac)
+            with pytest.raises(rle.RangeError,
+                               match=re.escape(str(want.value))):
+                dense.symbolize_dense(dc, ac)
 
     def test_pack_bits_msb_first_and_one_padded(self):
         out = bitio.pack_bits(np.array([0b101, 0b1]),
@@ -260,7 +272,7 @@ class TestVectorizedVsReference:
         ac = rng.integers(-32767, 32768, (n, 63))
         ac[rng.random((n, 63)) < rng.uniform(0.2, 0.995)] = 0
         dc_diff = rng.integers(-32767, 32768, (n,))
-        got = rle.symbolize(dc_diff, ac)
+        got = _symbolize(dc_diff, ac)
         want = rle.symbolize_reference(dc_diff, ac)
         for g, w in zip(got, want):
             np.testing.assert_array_equal(g, w)
@@ -269,7 +281,7 @@ class TestVectorizedVsReference:
         dc = np.arange(-8, 8)
         ac = np.zeros((16, 63), np.int64)
         ac[:, ::7] = np.arange(1, 17)[:, None]
-        is_dc, syms, av, al = rle.symbolize(dc, ac)
+        is_dc, syms, av, al = _symbolize(dc, ac)
         dc_f, ac_f = rle.symbol_frequencies(is_dc, syms)
         dc_t, ac_t = huffman.build_table(dc_f), huffman.build_table(ac_f)
         payload = rle.encode_payload(is_dc, syms, av, al, dc_t, ac_t)
@@ -298,7 +310,7 @@ class TestVectorizedVsReference:
         dc = np.zeros(4, np.int64)
         ac = np.zeros((4, 63), np.int64)
         ac[:, 60] = 3
-        is_dc, syms, av, al = rle.symbolize(dc, ac)
+        is_dc, syms, av, al = _symbolize(dc, ac)
         dc_f, ac_f = rle.symbol_frequencies(is_dc, syms)
         dc_t, ac_t = huffman.build_table(dc_f), huffman.build_table(ac_f)
         payload = rle.encode_payload(is_dc, syms, av, al, dc_t, ac_t)
@@ -497,7 +509,7 @@ class TestSharedTables:
 
 class TestHostHalves:
     """encode_zigzag_host / decode_zigzag_host — the jax-free halves the
-    pipelined engine fans across threads — agree with the full-path
+    engine fans across threads — agree with the full-path
     container functions."""
 
     def test_encode_zigzag_host_matches_encode_qcoeffs(self):
@@ -558,36 +570,46 @@ class TestEngineBytePath:
         assert blobs == [codec.compress(im, 70).to_bytes() for im in rag]
 
     def test_pipelined_and_serial_encode_bytes_identical(self):
+        # the engine's one encode path (device zig-zag, then the thread
+        # pool) writes the single-image encoders' bytes across a ragged
+        # batch of several shape buckets, under every table policy
         from repro.serve import codec_engine
         rag = [images.lena_like(64, 72), images.cablecar_like(40, 40),
-               images.lena_like(100, 90, seed=3)]
-        pipelined = codec_engine.encode_batch(rag, 50, pipelined=True)
-        serial = codec_engine.encode_batch(rag, 50, pipelined=False)
-        assert pipelined == serial
+               images.lena_like(100, 90, seed=3),
+               images.cablecar_like(64, 72, seed=4)]
+        for tables in ("auto", "embedded", "shared"):
+            got = codec_engine.encode_batch(rag, 50, tables=tables)
+            assert got == [codec.compress(im, 50).to_bytes(tables=tables)
+                           for im in rag]
+            assert got == [encode_image(np.asarray(im), 50, tables=tables)
+                           for im in rag]
 
     def test_decode_batch_bit_exact_mixed_streams(self):
         from repro.serve import codec_engine
         blobs = [encode_image(images.lena_like(64, 72), 50),
                  encode_image(images.cablecar_like(40, 40), 30),
                  encode_image(images.lena_like(64, 72, seed=2), 50)]
-        for pipelined in (True, False):
-            recs = codec_engine.decode_batch(blobs, pipelined=pipelined)
-            for blob, rec in zip(blobs, recs):
-                np.testing.assert_array_equal(
-                    np.asarray(rec), np.asarray(decode_image(blob)))
+        # the pool decodes a batch; a single stream decodes serially
+        pooled = codec_engine.decode_batch(blobs)
+        single = [codec_engine.decode_batch([b])[0] for b in blobs]
+        for blob, rec, one in zip(blobs, pooled, single):
+            np.testing.assert_array_equal(
+                np.asarray(rec), np.asarray(decode_image(blob)))
+            np.testing.assert_array_equal(np.asarray(one), np.asarray(rec))
         with pytest.raises(ValueError):
             codec_engine.decode_batch([])
 
-    def test_pack_backend_routing_is_byte_identical(self):
+    def test_pack_backend_routing_is_byte_identical(self, pallas_route):
         from repro.serve import codec_engine
         rag = [images.lena_like(64, 72), images.cablecar_like(40, 40)]
         default = codec_engine.encode_batch(rag, 50)
-        # the routed Pallas backend (interpret mode off-TPU) must frame
+        # the Pallas packing route (interpret mode off-TPU) must frame
         # identical streams through the whole engine path
+        pallas_route("pack")
         cb = codec_engine.compress_batch(rag, 50)
-        assert cb.to_bytes_list(pack_backend="pallas") == default
-        with pytest.raises(ValueError, match="backend"):
-            codec_engine.encode_batch(rag, 50, pack_backend="cuda")
+        before = obs.counts().get("entropy.pack.interpret", 0)
+        assert cb.to_bytes_list() == default
+        assert obs.counts()["entropy.pack.interpret"] - before == 2
 
     def test_tables_policy_re_keys_the_stream_cache(self):
         from repro.serve import codec_engine
@@ -600,57 +622,26 @@ class TestEngineBytePath:
         assert emb != auto                  # policy changes the bytes
         assert cb.to_bytes_list() == auto   # and the cache re-keys
 
-    def test_decode_batch_process_pool_matches_thread(self):
-        from repro.serve import codec_engine
-        blobs = [encode_image(images.lena_like(48, 56, seed=i), 50)
-                 for i in range(3)]
-        thread = codec_engine.decode_batch(blobs)
-        proc = codec_engine.decode_batch(blobs, executor="process",
-                                         workers=2)
-        for a, b in zip(thread, proc):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        with pytest.raises(ValueError, match="executor"):
-            codec_engine.decode_batch(blobs, executor="fibers")
-
-    def test_decode_batch_process_pool_refuses_device_unpacker(
-            self, monkeypatch):
-        # workers of a process pool cannot open the chip the parent
-        # holds: a device unpacker is refused before anything spawns
-        import multiprocessing
-
-        from repro.serve import codec_engine
-
-        def no_spawn(*a, **kw):
-            raise AssertionError("a process pool was started")
-
-        monkeypatch.setattr(multiprocessing, "get_context", no_spawn)
-        blobs = [encode_image(images.lena_like(24, 24, seed=i), 50)
-                 for i in range(2)]
-        with pytest.raises(ValueError, match="device unpacker"):
-            codec_engine.decode_batch(blobs, executor="process",
-                                      unpack_backend="pallas")
-
-    def test_unpack_backend_routing_is_bit_identical(self):
+    def test_unpack_backend_routing_is_bit_identical(self, pallas_route):
         from repro.serve import codec_engine
         blobs = [encode_image(images.lena_like(48, 56, seed=i), 50)
                  for i in range(3)]
         default = codec_engine.decode_batch(blobs)
-        # the routed Pallas backend (interpret mode off-TPU) must
-        # reconstruct identical images through the whole engine path
-        routed = codec_engine.decode_batch(blobs, unpack_backend="pallas")
-        serial = codec_engine.decode_batch(blobs, pipelined=False,
-                                           unpack_backend="pallas")
+        # the Pallas decode route (interpret mode off-TPU) must
+        # reconstruct identical images through the whole engine path,
+        # on the pool and on the single-stream path
+        pallas_route("unpack")
+        before = obs.counts().get("entropy.unpack.interpret", 0)
+        routed = codec_engine.decode_batch(blobs)
+        serial = [codec_engine.decode_batch([b])[0] for b in blobs]
+        assert obs.counts()["entropy.unpack.interpret"] - before == 6
         for a, b, c in zip(default, routed, serial):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
             np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
-        with pytest.raises(ValueError, match="backend"):
-            codec_engine.decode_batch(blobs, unpack_backend="cuda")
 
     def test_process_pool_decodes_runtime_registered_tables(self):
-        # regression: spawned workers re-import the huffman registry,
-        # so a v2 stream referencing a table id registered at runtime
-        # used to fail in executor="process" — decode_batch now ships
-        # the parent registry to each worker on init
+        # a v2 stream referencing a table id registered at runtime must
+        # decode on decode_batch's worker threads as on the caller's
         import struct
         import zlib
 
@@ -663,7 +654,7 @@ class TestEngineBytePath:
         img = np.asarray(images.lena_like(40, 40))
         z, _ = decode_zigzag_host(encode_image(img, quality=50))
         dc_diff = np.diff(z[:, 0].astype(np.int64), prepend=0)
-        syms = rle.symbolize(dc_diff, z[:, 1:].astype(np.int64))
+        syms = _symbolize(dc_diff, z[:, 1:].astype(np.int64))
         payload = rle.encode_payload(*syms, huffman.STANDARD_DC_LUMA,
                                      huffman.STANDARD_AC_LUMA)
         h, w = img.shape
@@ -672,8 +663,7 @@ class TestEngineBytePath:
         crc = zlib.crc32(header[4:24] + payload) & 0xFFFFFFFF
         blob = header[:24] + struct.pack("<I", crc) + payload
         want = np.asarray(decode_image(blob))
-        out = codec_engine.decode_batch([blob, blob], executor="process",
-                                        workers=2)
+        out = codec_engine.decode_batch([blob, blob])
         for rec in out:
             np.testing.assert_array_equal(np.asarray(rec), want)
 
@@ -706,7 +696,7 @@ class TestBigPayloadDecodeRouting:
         dc = rng.integers(-1024, 1025, (n_blocks,))
         ac = rng.integers(-amplitude, amplitude + 1, (n_blocks, 63))
         ac[rng.random((n_blocks, 63)) > density] = 0
-        is_dc, syms, av, al = rle.symbolize(dc, ac)
+        is_dc, syms, av, al = _symbolize(dc, ac)
         dc_f, ac_f = rle.symbol_frequencies(is_dc, syms)
         dc_t, ac_t = huffman.build_table(dc_f), huffman.build_table(ac_f)
         payload = rle.encode_payload(is_dc, syms, av, al, dc_t, ac_t)

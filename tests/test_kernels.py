@@ -193,15 +193,24 @@ class TestPackBitsKernel:
         codes = rng.integers(0, 1 << 16, 300)
         self._both(codes, lengths)
 
-    def test_backend_selection(self):
-        # off-TPU "auto" resolves to the NumPy reference
+    def test_backend_selection(self, pallas_route):
+        # off-TPU "auto" resolves to the NumPy reference, and the encode
+        # keeps its default packer; pinned, it is the device pack
         assert pack_bits.select_backend("auto") in pack_bits.BACKENDS
         if jax.default_backend() != "tpu":
             assert pack_bits.select_backend("auto") == "numpy"
-            assert pack_bits.make_packer("auto") is None
-        assert pack_bits.make_packer("pallas") is not None
-        with pytest.raises(ValueError, match="backend"):
-            pack_bits.select_backend("cuda")
+            assert pack_bits.make_packer() is None
+        pallas_route("pack")
+        assert pack_bits.make_packer() is not None
+
+
+@pytest.mark.parametrize("kernel", ["pack_bits", "symbolize",
+                                    "unpack_bits"])
+def test_select_backend_rejects_unknown_name(kernel):
+    import importlib
+    ops = importlib.import_module(f"repro.kernels.{kernel}.ops")
+    with pytest.raises(ValueError, match=f"unknown {kernel} backend"):
+        ops.select_backend("cuda")
 
 
 class TestGradDctKernel:

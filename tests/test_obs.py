@@ -56,10 +56,12 @@ def test_spans_with_stats_and_nesting_reach_the_trace(tmp_path):
     assert cline == line and s <= cs <= ce <= e
 
 
-def test_pool_thread_spans_carry_the_callers_call_id(tmp_path):
+def test_pool_thread_spans_carry_the_callers_call_id(tmp_path,
+                                                     monkeypatch):
+    monkeypatch.setattr(eng, "_n_workers", lambda: 2)
     with jax.profiler.trace(str(tmp_path)):
-        blobs = eng.encode_batch(_batch(4), 50, workers=2)
-        eng.decode_batch(blobs, workers=2)
+        blobs = eng.encode_batch(_batch(4), 50)
+        eng.decode_batch(blobs)
     spans = _spans(tmp_path)
     calls = {name: st["call"] for _, name, _, _, st in spans
              if name in ("repro.engine.encode", "repro.engine.decode")}
@@ -77,17 +79,19 @@ def test_pool_thread_spans_carry_the_callers_call_id(tmp_path):
                           if name == "repro.entropy.encode_image"}
 
 
-def test_device_decode_resolves_one_tile_per_image(tmp_path, monkeypatch):
+def test_device_decode_resolves_one_tile_per_image(tmp_path, monkeypatch,
+                                                   pallas_route):
     # the engine's route resolves every block chain on the device: one
     # device route per image and no host resolver span; over the device
     # resolver's block guard the host resolves one tile per image
     from repro.kernels.unpack_bits import ops
     blobs = eng.encode_batch(_batch(3), 50)
+    pallas_route("unpack")
+    monkeypatch.setattr(eng, "_n_workers", lambda: 2)
 
     def decode(where):
         with jax.profiler.trace(str(tmp_path / where)):
-            moved = _delta(lambda: eng.decode_batch(
-                blobs, unpack_backend="pallas", workers=2))
+            moved = _delta(lambda: eng.decode_batch(blobs))
         return moved, [st for _, name, _, _, st in _spans(tmp_path / where)
                        if name == "repro.entropy.resolve"]
 
@@ -142,12 +146,14 @@ print("jax" in sys.modules)
 
 @pytest.mark.parametrize("backend, route", [("auto", "host"),
                                             ("pallas", "interpret")])
-def test_engine_counts_the_route_of_every_image(backend, route):
+def test_engine_counts_the_route_of_every_image(pallas_route, backend,
+                                                route):
+    if backend == "pallas":
+        pallas_route()
     imgs = _batch(3)
     blobs = []
-    enc = _delta(lambda: blobs.extend(eng.encode_batch(
-        imgs, 50, pack_backend=backend, symbolize_backend=backend)))
-    dec = _delta(lambda: eng.decode_batch(blobs, unpack_backend=backend))
+    enc = _delta(lambda: blobs.extend(eng.encode_batch(imgs, 50)))
+    dec = _delta(lambda: eng.decode_batch(blobs))
     assert enc["engine.images.encoded"] == dec["engine.images.decoded"] == 3
     assert enc[f"entropy.symbolize.{route}"] == 3
     assert enc[f"entropy.pack.{route}"] == 3

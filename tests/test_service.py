@@ -334,10 +334,15 @@ def test_hopeless_deadline_rejected_at_admission():
 
 def test_slow_engine_marks_deadline_missed_not_dropped():
     async def go():
-        engine = FlakyEngine(EchoEngine(), latency_s=0.05)
+        # the deadline leaves the dispatcher 100 ms to hand the request
+        # to the engine (a loaded test host can stall the event loop
+        # for tens of ms, and a request still queued at its deadline is
+        # swept as a reject); the engine then takes 300 ms, so the
+        # dispatched request must finish late and still be served
+        engine = FlakyEngine(EchoEngine(), latency_s=0.3)
         cfg = fast_config(initial_step_s=1e-4)
         async with CodecService(cfg, engine=engine) as svc:
-            r = await svc.submit(make_images(1)[0], deadline_s=0.01)
+            r = await svc.submit(make_images(1)[0], deadline_s=0.1)
         assert isinstance(r, Response)
         assert r.deadline_missed
         assert svc.stats.deadline_missed == 1

@@ -8,9 +8,11 @@ histograms **bit-for-bit** as int64 arrays, therefore
 histogram bytes — returns the *identical* memoised table object,
 therefore ``tables="auto"`` negotiates the same table ids and the
 framed ``DCTZ`` streams come out byte-identical.  That chain is what
-lets the engine swap symbolize backends per request without ever
-changing the wire format.
+lets the engine's two symbolize routes (the device on a TPU within
+its guards, the host symbolizer elsewhere) share one wire format.
 """
+
+import functools
 
 import numpy as np
 import pytest
@@ -201,7 +203,8 @@ class TestTableNegotiationChain:
                                             tables="auto")
         hdr = container.read_header(want)
         for backend in BACKENDS:
-            symbolizer = ops.make_symbolizer(backend, interpret=True)
+            symbolizer = functools.partial(ops.prepare, backend=backend,
+                                           interpret=True)
             got = container.encode_zigzag_host(z, 50, "exact", shape,
                                                tables="auto",
                                                symbolizer=symbolizer)
@@ -222,7 +225,7 @@ class TestTableNegotiationChain:
         dc_t = huffman.DEFAULT_TABLES.get(huffman.STANDARD_DC_LUMA_ID)
         ac_t = huffman.DEFAULT_TABLES.get(huffman.STANDARD_AC_LUMA_ID)
         want = rle.encode_payload(*stream, dc_t, ac_t)
-        prep = ops.make_symbolizer(backend, interpret=True)(dc_diff, ac)
+        prep = ops.prepare(dc_diff, ac, backend=backend, interpret=True)
         assert prep.payload(dc_t, ac_t) == want
 
     @pytest.mark.parametrize("backend", BACKENDS)
@@ -236,7 +239,7 @@ class TestTableNegotiationChain:
         stream = rle.symbolize_reference(dc_diff, ac)
         with pytest.raises(ValueError) as oracle:
             rle.encode_payload(*stream, tiny, tiny)
-        prep = ops.make_symbolizer(backend, interpret=True)(dc_diff, ac)
+        prep = ops.prepare(dc_diff, ac, backend=backend, interpret=True)
         with pytest.raises(ValueError) as routed:
             prep.payload(tiny, tiny)
         assert str(routed.value) == str(oracle.value)

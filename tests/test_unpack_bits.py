@@ -12,7 +12,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro import obs
-from repro.core.entropy import bitio, container, huffman, rle
+from repro.core.entropy import bitio, container, dense, huffman, rle
 from repro.kernels import unpack_bits
 from repro.kernels.unpack_bits import ref as unpack_ref
 
@@ -21,8 +21,8 @@ DATA_DIR = pathlib.Path(__file__).parent / "data"
 
 def _encode(dc_diff, ac, std_tables=True):
     """Blocks -> (payload, dc_table, ac_table)."""
-    syms = rle.symbolize(np.asarray(dc_diff, np.int64),
-                         np.asarray(ac, np.int64))
+    syms = dense.dense_to_stream(dense.symbolize_dense(
+        np.asarray(dc_diff, np.int64), np.asarray(ac, np.int64)))
     if std_tables:
         dc_t, ac_t = huffman.STANDARD_DC_LUMA, huffman.STANDARD_AC_LUMA
     else:
@@ -176,15 +176,15 @@ class TestUnpackBitsKernel:
         np.testing.assert_array_equal(got[0], want[0])
         np.testing.assert_array_equal(got[1], want[1])
 
-    def test_backend_selection(self):
-        # off-TPU "auto" resolves to the NumPy reference
+    def test_backend_selection(self, pallas_route):
+        # off-TPU "auto" resolves to the NumPy reference, and the decode
+        # route keeps the LUT walk; pinned, it is the device decode
         assert unpack_bits.select_backend("auto") in unpack_bits.BACKENDS
         if jax.default_backend() != "tpu":
             assert unpack_bits.select_backend("auto") == "numpy"
-            assert unpack_bits.make_unpacker("auto") is None
-        assert unpack_bits.make_unpacker("pallas") is not None
-        with pytest.raises(ValueError, match="backend"):
-            unpack_bits.select_backend("cuda")
+            assert unpack_bits.make_unpacker() is None
+        pallas_route("unpack")
+        assert unpack_bits.make_unpacker() is not None
 
     def test_scratch_is_bounded_by_tile_not_payload(self):
         # the staged decoder's memory claim: scratch saturates at one
@@ -379,8 +379,8 @@ COLOUR = container.COLOUR_BLOCK_CLASSES
 
 def _encode_classes(dc_diff, ac, classes=COLOUR, std_tables=True):
     """Blocks of a two-class stream -> (payload, dc_tables, ac_tables)."""
-    prep = rle.prepare_stream(np.asarray(dc_diff, np.int64),
-                              np.asarray(ac, np.int64), classes=classes)
+    prep = dense.prepare(np.asarray(dc_diff, np.int64),
+                         np.asarray(ac, np.int64), classes=classes)
     if std_tables:
         dcs = tuple(huffman.DEFAULT_TABLES.get(d)
                     for d, _ in huffman.STANDARD_IDS)
@@ -532,7 +532,8 @@ class TestUnpackThroughContainer:
         for f in sorted(DATA_DIR.glob("*.dctz")):
             data = f.read_bytes()
             z0, h0 = entropy.decode_zigzag_host(data)
-            for up in (unpack_bits.make_unpacker("pallas", interpret=True),
+            for up in (lambda *a: unpack_bits.unpack_bits(
+                           *a, backend="pallas", interpret=True),
                        lambda *a: unpack_bits.unpack_bits(
                            *a, backend="numpy")):
                 z1, h1 = entropy.decode_zigzag_host(data, unpacker=up)
@@ -545,6 +546,6 @@ class TestUnpackThroughContainer:
         blob = entropy.encode_image(img, quality=50)
         base = np.asarray(entropy.decode_image(blob))
         routed = np.asarray(entropy.decode_image(
-            blob, unpacker=unpack_bits.make_unpacker("pallas",
-                                                     interpret=True)))
+            blob, unpacker=lambda *a: unpack_bits.unpack_bits(
+                *a, backend="pallas", interpret=True)))
         np.testing.assert_array_equal(base, routed)
