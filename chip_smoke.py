@@ -8,7 +8,8 @@ Phases (one process, in order; any failure raises and exits non-zero):
 0. device check — the first device must be a TPU;
 1. ``codec_engine.roundtrip_batch`` at the paper's Table 1-4 sizes on
    the fused Pallas kernel, against the staged ``core.codec`` path run
-   on the host CPU in this process (PSNR and quantised levels);
+   on the host CPU in this process (PSNR and quantised levels), each
+   stacked batch returned as the fused program's output;
 2. ``codec_engine.encode_batch`` / ``decode_batch`` bytes: every stream
    equals the host NumPy entropy coder on the same chip-computed levels
    and decodes back to them exactly; the route every entropy stage took
@@ -151,6 +152,10 @@ def phase_roundtrip() -> None:
         dt = time.perf_counter() - t0
         check(delta.get("engine.roundtrip.fused", 0) > 0,
               f"{name}: roundtrip_batch did not call the fused kernel")
+        check(delta.get("engine.reassemble.direct", 0) == 1
+              and "engine.reassemble.per_image" not in delta,
+              f"{name}: the stacked roundtrip was not returned as the "
+              f"fused program's output")
         _, qc = fused_codec(imgs, quality=QUALITY, transform=transform)
         qc = np.asarray(qc)
         ref_levels, ref_rec, ref_psnr = cpu_reference(imgs, transform,

@@ -30,7 +30,9 @@ snapshot): the route each entropy stage took per image
 stream's block chain was resolved (``entropy.resolve.<device|host>``),
 the device of each entropy-kernel launch
 (``entropy.<stage>.device.<id>``), the roundtrip
-route per call (``engine.roundtrip.<fused|staged>``) and the images
+route per call (``engine.roundtrip.<fused|staged>``), how each group's
+output left the engine
+(``engine.reassemble.<direct|program|per_image>``) and the images
 encoded and decoded (``engine.images.{encoded,decoded}``).
 
 This module imports nothing outside the standard library: where jax is

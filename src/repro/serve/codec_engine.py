@@ -14,6 +14,10 @@ independent 8x8 blocks; this engine is the serving-side realisation:
   handles roundtrips; everywhere else (and for compress/decompress halves)
   the batch-first :mod:`repro.core.codec` path runs, so CPU results are
   bit-identical to the single-image API,
+* each group's output goes back as the program's own result (a stacked
+  group at its own shape), one crop (a stacked group padded to the
+  block; a decode bucket of one image size) or one slice per image (a
+  ragged list; a decode bucket of mixed sizes), never restacked,
 * ``encode_batch`` / ``decode_batch`` extend the same pipeline to real
   entropy-coded bytes: the array half stays sharded, the entropy stage
   (:mod:`repro.core.entropy`) runs per image at the host edge,
@@ -299,14 +303,24 @@ def _fused_roundtrip_sharded(imgs, transform, quality, cordic_config, n_dev):
 
 
 def _run_batched(fn, arr: jnp.ndarray) -> jnp.ndarray:
-    """Pad the leading axis to the batch bucket, run sharded, crop back."""
+    """Pad the leading axis to the batch bucket, run sharded, and crop
+    the padding rows back off; unpadded, the program's output is
+    returned as it is."""
     n = arr.shape[0]
     n_dev = _n_devices()
     padded_n = _pad_rows(n, n_dev)
-    if padded_n != n:
-        arr = jnp.concatenate(
-            [arr, jnp.zeros((padded_n - n, *arr.shape[1:]), arr.dtype)])
+    if padded_n == n:
+        return fn(arr, n_dev)
+    arr = jnp.concatenate(
+        [arr, jnp.zeros((padded_n - n, *arr.shape[1:]), arr.dtype)])
     return fn(arr, n_dev)[:n]
+
+
+@functools.partial(jax.jit, static_argnames=("h", "w"))
+def _crop_each(rec: jnp.ndarray, h: int, w: int) -> tuple:
+    """Every image of ``rec`` cropped to (h, w), one array each, from one
+    program."""
+    return tuple(rec[j, :h, :w] for j in range(rec.shape[0]))
 
 
 # ---------------------------------------------------------------------------
@@ -378,15 +392,29 @@ def _group_inputs(imgs):
 
 
 def _reassemble(per_group: list, groups: list, n: int, stacked: bool):
-    """Scatter per-group outputs back to original input order."""
+    """Per-group outputs back in input order at their original sizes.
+
+    A stacked input is one group in input order: its output is the
+    answer when padding to the block added nothing, else one crop of
+    the whole group.  A ragged list comes back as a list, one slice per
+    image.  Each group counts its route as
+    ``engine.reassemble.<direct|program|per_image>``.
+    """
     with obs.span("engine.reassemble"):
+        if stacked:
+            (_, _, orig_shapes, *_), imgs_out = groups[0], per_group[0]
+            h, w = orig_shapes[0]
+            if imgs_out.shape[1:3] == (h, w):
+                obs.count("engine.reassemble.direct")
+                return imgs_out
+            obs.count("engine.reassemble.program")
+            return imgs_out[:, :h, :w]
         out = [None] * n
         for imgs_out, (_, indices, orig_shapes, *_) in zip(per_group,
                                                           groups):
+            obs.count("engine.reassemble.per_image")
             for j, (idx, (h, w)) in enumerate(zip(indices, orig_shapes)):
                 out[idx] = imgs_out[j, :h, :w]
-        if stacked:
-            return jnp.stack(out)
         return out
 
 
@@ -673,9 +701,18 @@ def _decode(blobs, decode_one, call, mode):
                 transform=dec_transform, quality=quality,
                 cordic_config=cordic.PAPER_CONFIG)
             rec = _run_batched(lambda a, nd: fn(a, n_dev=nd), stackq)
-            for j, i in enumerate(members):
-                hdr = decoded[i][1]
-                out[i] = rec[j, :hdr["height"], :hdr["width"]]
+            sizes = [(decoded[i][1]["height"], decoded[i][1]["width"])
+                     for i in members]
+            with obs.span("engine.reassemble"):
+                if len(set(sizes)) == 1:
+                    obs.count("engine.reassemble.program")
+                    crops = _crop_each(rec, *sizes[0])
+                else:
+                    obs.count("engine.reassemble.per_image")
+                    crops = [rec[j, :h, :w] for j, (h, w) in
+                             enumerate(sizes)]
+            for i, im in zip(members, crops):
+                out[i] = im
     if n_colour:
         obs.count("engine.images.colour.decoded", n_colour)
     return out

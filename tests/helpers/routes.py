@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import pytest
 
+from repro import obs
 from repro.kernels.pack_bits import ops as pack_ops
 from repro.kernels.symbolize import ops as symbolize_ops
 from repro.kernels.unpack_bits import ops as unpack_ops
@@ -34,3 +35,14 @@ def pallas_route(monkeypatch):
                 return "pallas" if backend == "auto" else _real(backend)
             monkeypatch.setattr(ops, "select_backend", select)
     return pin
+
+
+def reassemble_routes(fn) -> tuple:
+    """``(fn(), {route: groups})`` of the ``engine.reassemble.<route>``
+    counters ``fn`` moved."""
+    head = "engine.reassemble."
+    before = obs.counts()
+    out = fn()
+    return out, {k[len(head):]: v - before.get(k, 0)
+                 for k, v in obs.counts().items()
+                 if k.startswith(head) and v != before.get(k, 0)}

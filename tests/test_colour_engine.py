@@ -23,6 +23,7 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
+from helpers.routes import reassemble_routes  # noqa: E402
 from perfbench import colour as bench_colour  # noqa: E402
 from perfbench import reference_colour as rc  # noqa: E402
 from repro import obs  # noqa: E402
@@ -162,6 +163,37 @@ def test_single_image_api_and_stacked_batch_agree_with_engine():
     for tables in ("auto", "embedded", "shared"):
         assert eng.encode_batch(rag, 75, tables=tables) == [
             entropy.encode_image(im, 75, tables=tables) for im in rag]
+
+
+def test_colour_decode_crops_each_bucket_once():
+    # 40x56 and 48x64 share the 3x4 MCU grid; two 80x48 photos are a
+    # bucket of one size
+    imgs = [_img((40, 56), seed=0), _img((80, 48), seed=1),
+            _img((48, 64), seed=2), _img((80, 48), seed=3)]
+    blobs = eng.encode_batch(imgs, 75)
+    recs, routes = reassemble_routes(lambda: eng.decode_batch(blobs))
+    assert routes == {"program": 1, "per_image": 1}
+    for im, blob, rec in zip(imgs, blobs, recs):
+        assert rec.shape == im.shape
+        np.testing.assert_array_equal(
+            np.asarray(rec), np.asarray(entropy.decode_image(blob)))
+
+
+@pytest.mark.parametrize("shape, route", [((48, 64), "direct"),
+                                          ((40, 56), "program")])
+def test_stacked_colour_roundtrip_returns_its_group(shape, route):
+    import jax.numpy as jnp
+    imgs = np.stack([_img(shape, seed=s) for s in range(3)])
+    (rec, psnr), routes = reassemble_routes(
+        lambda: eng.roundtrip_batch(imgs, 75))
+    assert routes == {route: 1}
+    assert rec.shape == imgs.shape
+    refs = [colour.decompress(colour.compress(im, 75), *shape, 75)
+            for im in imgs]
+    for r, ref in zip(rec, refs):
+        np.testing.assert_array_equal(np.asarray(r), np.asarray(ref))
+    np.testing.assert_array_equal(
+        psnr, np.asarray(eng._psnr_vec(jnp.asarray(imgs), jnp.stack(refs))))
 
 
 def test_mixed_grayscale_and_colour_batch():

@@ -168,5 +168,43 @@ def test_engine_counts_the_route_of_every_image(pallas_route, backend,
 
 
 def test_roundtrip_counts_its_route():
+    # the stacked 32x32 group comes back as the program's own output
     delta = _delta(lambda: eng.roundtrip_batch(_batch(2), 50))
-    assert delta == {"engine.roundtrip.staged": 1}
+    assert delta == {"engine.roundtrip.staged": 1,
+                     "engine.reassemble.direct": 1}
+
+
+def _reassemble_spans(trace_dir, fn) -> tuple:
+    """(counter delta, ``engine.reassemble`` spans) of ``fn()`` under a
+    profiler session."""
+    with jax.profiler.trace(str(trace_dir)):
+        moved = _delta(fn)
+    return moved, [name for _, name, *_ in _spans(trace_dir)
+                   if name == "repro.engine.reassemble"]
+
+
+@pytest.mark.parametrize("size, route", [(32, "direct"), (30, "program")])
+def test_stacked_roundtrip_reassembles_once_per_call(tmp_path, size, route):
+    # aligned or not, a stacked call is one group: one span, one route,
+    # and no per-image slice
+    imgs = _batch(3, size)
+    moved, spans = _reassemble_spans(
+        tmp_path, lambda: [eng.roundtrip_batch(imgs, 50) for _ in range(2)])
+    assert {k: v for k, v in moved.items()
+            if k.startswith("engine.reassemble.")} == {
+        f"engine.reassemble.{route}": 2}
+    assert len(spans) == 2
+
+
+def test_decode_reassembles_once_per_bucket(tmp_path):
+    # a uniform bucket and a bucket of two sizes in one 8-block grid
+    blobs = eng.encode_batch([images.lena_like(32, 32, seed=0),
+                              images.lena_like(16, 24, seed=1),
+                              images.lena_like(30, 32, seed=2),
+                              images.lena_like(16, 24, seed=3)], 50)
+    moved, spans = _reassemble_spans(tmp_path,
+                                     lambda: eng.decode_batch(blobs))
+    assert moved["engine.reassemble.program"] == 1
+    assert moved["engine.reassemble.per_image"] == 1
+    assert "engine.reassemble.direct" not in moved
+    assert len(spans) == 2
